@@ -2,19 +2,25 @@
 
 The reference's hot runtime paths are C++ (plasma allocator, raylet);
 here the allocator core is C++ too, compiled on demand with the
-system toolchain and cached next to the source. Everything has a pure
-Python fallback, so a missing compiler degrades gracefully (first-fit
-semantics are identical and parity-tested).
+system toolchain into the checkout's cache directory
+(_private/cache_dir.py). A binary is named after the CONTENT of the
+source it was built from, so one built from another ``.cc`` is never
+loaded. Everything has a pure Python fallback, so a missing compiler
+degrades gracefully (first-fit semantics are identical and
+parity-tested); ``build_status()`` says which one is in use.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
 import threading
-from typing import Optional
+from typing import Dict, Optional
+
+from ray_tpu._private.cache_dir import checkout_cache_dir
 
 logger = logging.getLogger(__name__)
 
@@ -23,16 +29,29 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _libs: dict = {}
 _lib_lock = threading.Lock()
 _load_failed: set = set()
+# name -> "built" (compiled by this process) | "found" (a binary of this
+# exact source was already in the cache) | "unavailable: <why>"
+_status: Dict[str, str] = {}
 
 
-def _build(name: str) -> bool:
-    """g++ <name>.cc into _<name>.so if missing or stale."""
+def build_status() -> Dict[str, str]:
+    """How each native library asked for so far was obtained."""
+    with _lib_lock:
+        return dict(_status)
+
+
+def _build(name: str) -> Optional[str]:
+    """g++ <name>.cc into the cache, unless a binary built from exactly
+    this source is there already. Returns the .so path, or None."""
     src = os.path.join(_DIR, f"{name}.cc")
-    so = os.path.join(_DIR, f"_{name}.so")
     try:
-        if os.path.exists(so) and \
-                os.path.getmtime(so) >= os.path.getmtime(src):
-            return True
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        so = os.path.join(checkout_cache_dir("native"),
+                          f"_{name}.{digest}.so")
+        if os.path.exists(so):
+            _status[name] = "found"
+            return so
         # per-pid temp: concurrent builders (two drivers, parallel
         # pytest) must not install each other's half-written output
         tmp = f"{so}.{os.getpid()}.tmp"
@@ -42,7 +61,8 @@ def _build(name: str) -> bool:
             subprocess.run(cmd, check=True, capture_output=True,
                            timeout=120)
             os.replace(tmp, so)
-            return True
+            _status[name] = "built"
+            return so
         finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
@@ -51,9 +71,10 @@ def _build(name: str) -> bool:
         stderr = getattr(e, "stderr", None)
         if stderr:
             detail = ": " + stderr.decode(errors="replace").strip()[:500]
+        _status[name] = f"unavailable: {e}{detail}"
         logger.warning("native %s build failed (%s%s); using the "
                        "Python fallback", name, e, detail)
-        return False
+        return None
 
 
 def load_native_lib(name: str) -> Optional[ctypes.CDLL]:
@@ -63,12 +84,14 @@ def load_native_lib(name: str) -> Optional[ctypes.CDLL]:
             return _libs[name]
         if name in _load_failed:
             return None
-        if not _build(name):
+        so = _build(name)
+        if so is None:
             _load_failed.add(name)
             return None
         try:
-            lib = ctypes.CDLL(os.path.join(_DIR, f"_{name}.so"))
+            lib = ctypes.CDLL(so)
         except OSError as e:
+            _status[name] = f"unavailable: {e}"
             logger.warning("native %s load failed (%s)", name, e)
             _load_failed.add(name)
             return None

@@ -168,8 +168,10 @@ _d("client_reconnect_timeout_s", float, 30.0,
    "driver blocked in get() across a head restart resolves late; "
    "0 = fail pending ops immediately (pre-failover behavior)")
 _d("worker_tpu_access", bool, False,
-   "give process workers the TPU plugin bootstrap (default: the head "
-   "owns the chip; workers run CPU jax, starting seconds faster)")
+   "let a process worker own the chip instead of the head (default: "
+   "the head owns it; workers run CPU jax, starting seconds faster). "
+   "A chip belongs to one process: valid only for ONE worker under a "
+   "head started with JAX_PLATFORMS=cpu, refused otherwise")
 _d("worker_pipeline_depth", int, 0,
    "max tasks in flight per process-worker pipe (lease pipelining, "
    "reference: max_tasks_in_flight_per_worker); 0 = auto from the "

@@ -739,7 +739,7 @@ def _jit_drive(threshold: float, max_ticks: int, donate: bool = True):
     """Whole-DAG drive fused into ONE device program: lax.while_loop over
     the instant-completion tick. One dispatch + one host sync for the
     entire graph — this is the north-star measurement path (per-tick host
-    round-trips would otherwise dominate on a tunneled/remote chip)."""
+    round-trips would otherwise be part of what is timed)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -782,10 +782,9 @@ def _jit_bench(threshold: float, max_ticks: int, k_reps: int):
     false after a completed drive, but XLA cannot prove that), so the
     compiler can neither CSE the repetitions nor hoist them out of the
     loop, and the executions serialize. Fetching the returned tick-count
-    scalar forces genuine completion of all K drives — the only reliable
-    completion signal on transports whose block_until_ready acks early.
-    Cost model: T(K) = round_trip + K * drive_time; run at two K values
-    and difference to cancel the round trip.
+    scalar ends the timed region: all K drives are complete when it
+    arrives. Cost model: T(K) = dispatch_and_fetch + K * drive_time;
+    run at two K values and difference to cancel the fixed part.
     """
     import jax
     import jax.numpy as jnp

@@ -122,10 +122,9 @@ def moe_ffn_sharded(tokens, w_router, w_in, w_out, mesh,
     fn = functools.partial(moe_ffn_local,
                            capacity_factor=capacity_factor,
                            axis_name=axis_name)
-    sm = shard_map_norep()
-    return sm(fn, mesh=mesh,
-              in_specs=(P(axis_name, None), P(None, None),
-                        P(axis_name, None, None),
-                        P(axis_name, None, None)),
-              out_specs=(P(axis_name, None), P()))(
-                  tokens, w_router, w_in, w_out)
+    return shard_map_norep(
+        fn, mesh=mesh,
+        in_specs=(P(axis_name, None), P(None, None),
+                  P(axis_name, None, None), P(axis_name, None, None)),
+        out_specs=(P(axis_name, None), P()))(
+            tokens, w_router, w_in, w_out)

@@ -12,7 +12,7 @@ paged attention on GPU); here it is a Pallas TPU kernel.
 Two implementations, parity-tested:
 
   - ``paged_attention_reference``: pure-XLA gather over the page table
-    (always available; the fallback path and the numerics oracle);
+    (the short-context path and the numerics oracle);
   - ``paged_attention``: Pallas flash-decoding kernel. Grid =
     (batch, kv_heads, pages); the page table rides scalar prefetch and
     the K/V BlockSpec index_maps select each sequence's physical page,
@@ -38,7 +38,7 @@ NEG_INF = -1e30
 
 
 # ----------------------------------------------------------------------
-# reference implementation (XLA gather; numerics oracle + fallback)
+# reference implementation (XLA gather; numerics oracle + short contexts)
 # ----------------------------------------------------------------------
 
 def paged_attention_reference(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -128,8 +128,8 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                     v_pages: jnp.ndarray, page_table: jnp.ndarray,
                     seq_lens: jnp.ndarray, *,
                     interpret: bool = False) -> jnp.ndarray:
-    """Pallas flash-decoding over paged KV (see module docstring).
-    Falls back to interpret mode off-TPU for testing."""
+    """Pallas flash-decoding over paged KV (see module docstring);
+    interpret=True runs the kernel body off-TPU for testing."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -178,18 +178,16 @@ def paged_attention_auto(q, k_pages, v_pages, page_table, seq_lens):
     max contexts (it reads only the pages each sequence owns); at short
     contexts the XLA gather reference is faster (the kernel's per-cell
     fixed cost dominates tiny reads). Off-TPU the kernel runs in
-    interpret mode so tests exercise the real kernel logic."""
+    interpret mode so tests exercise the real kernel logic. A kernel
+    that fails to lower raises: the gather is a choice, never a
+    fallback."""
     MP, page = page_table.shape[1], k_pages.shape[2]
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu and MP * page < 2048:
         return paged_attention_reference(q, k_pages, v_pages, page_table,
                                          seq_lens)
-    try:
-        return paged_attention(q, k_pages, v_pages, page_table, seq_lens,
-                               interpret=not on_tpu)
-    except Exception:  # pragma: no cover - kernel unavailable: fallback
-        return paged_attention_reference(q, k_pages, v_pages, page_table,
-                                         seq_lens)
+    return paged_attention(q, k_pages, v_pages, page_table, seq_lens,
+                           interpret=not on_tpu)
 
 
 # ----------------------------------------------------------------------

@@ -80,7 +80,6 @@ def pipeline_forward(stage_fn: Callable, stage_params, microbatches,
 
     fn = functools.partial(pipeline_local, stage_fn=stage_fn,
                            axis_name=axis_name)
-    sm = shard_map_norep()
     param_specs = jax.tree_util.tree_map(
         lambda _: P(axis_name), stage_params)
 
@@ -90,6 +89,6 @@ def pipeline_forward(stage_fn: Callable, stage_params, microbatches,
         params = jax.tree_util.tree_map(lambda x: x[0], params)
         return fn(params, mb)
 
-    return sm(body, mesh=mesh,
-              in_specs=(param_specs, P()),
-              out_specs=P())(stage_params, microbatches)
+    return shard_map_norep(body, mesh=mesh,
+                           in_specs=(param_specs, P()),
+                           out_specs=P())(stage_params, microbatches)

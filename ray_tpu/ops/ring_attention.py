@@ -91,9 +91,11 @@ def _block_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref,
     l_ref[0, 0] = l[:, None]
 
 
-def _block_attention_pallas(q, k, v, q_offset, k_offset, causal: bool,
-                            interpret: bool = False):
-    """Pallas path; same contract as _block_attention_xla."""
+def _block_pallas_forward(q, k, v, q_offset, k_offset, causal: bool,
+                          interpret: bool):
+    """The bare kernel launch; same contract as _block_attention_xla.
+    Not differentiable (pallas_call has no JVP for a kernel that reads
+    program_id) — callers go through _block_attention_pallas."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -135,12 +137,48 @@ def _block_attention_pallas(q, k, v, q_offset, k_offset, causal: bool,
     return o, m[..., 0], l[..., 0]
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _block_attention_pallas(q, k, v, q_offset, k_offset, causal: bool,
+                            interpret: bool = False):
+    """Pallas path; same contract as _block_attention_xla.
+
+    Forward is the kernel. BACKWARD is the VJP of _block_attention_xla
+    at the same inputs: the two compute the same (o, m, l), so the XLA
+    block's cotangents are the kernel's, at the price of materializing
+    one [B, H, Tq, Tk] f32 score block per ring step in the backward
+    pass. A train step under a seq mesh on TPU therefore runs the
+    kernel forward and the XLA block backward."""
+    return _block_pallas_forward(q, k, v, q_offset, k_offset, causal,
+                                 interpret)
+
+
+def _block_pallas_fwd(q, k, v, q_offset, k_offset, causal, interpret):
+    out = _block_pallas_forward(q, k, v, q_offset, k_offset, causal,
+                                interpret)
+    return out, (q, k, v, q_offset, k_offset)
+
+
+def _block_pallas_bwd(causal, interpret, res, cts):
+    q, k, v, q_offset, k_offset = res
+    _, vjp = jax.vjp(
+        lambda q, k, v: _block_attention_xla(q, k, v, q_offset, k_offset,
+                                             causal), q, k, v)
+    # the integer offsets carry no cotangent
+    return (*vjp(cts), None, None)
+
+
+_block_attention_pallas.defvjp(_block_pallas_fwd, _block_pallas_bwd)
+
+
 def block_attention(q, k, v, q_offset=0, k_offset=0, causal: bool = True,
                     impl: str = "auto", interpret: bool = False):
     """One blockwise attention step. q [B,H,T,D], k/v [B,Hkv,Tk,D] (Hkv
     divides H: GQA); offsets are the GLOBAL sequence positions of the
     first row/col (causality across ring steps). Returns
-    (o_unnormalized f32, m, l)."""
+    (o_unnormalized f32, m, l). impl="auto" is the Pallas kernel on a
+    TPU backend and the XLA block elsewhere; both are differentiable
+    (the kernel through the XLA block's VJP, see
+    _block_attention_pallas)."""
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "pallas":
@@ -230,9 +268,9 @@ def ring_attention_sharded(q, k, v, mesh, axis_name: str = "seq",
                            causal=causal, impl=impl, interpret=interpret)
     from ray_tpu.parallel.collectives import shard_map_norep
 
-    sm = shard_map_norep()
-    return sm(fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
-              out_specs=q_spec)(q, k, v)
+    return shard_map_norep(fn, mesh=mesh,
+                           in_specs=(q_spec, kv_spec, kv_spec),
+                           out_specs=q_spec)(q, k, v)
 
 
 def attention_reference(q, k, v, causal: bool = True):

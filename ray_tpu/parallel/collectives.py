@@ -98,24 +98,11 @@ def send_recv(x, group: "CollectiveGroup | str", shift: int = 1):
     return lax.ppermute(x, name, perm)
 
 
-import functools as _functools
-
-
-@_functools.lru_cache(maxsize=1)
-def shard_map_norep():
-    """shard_map with replication checking disabled, across jax
-    versions (the manual-collective ops — ring attention, MoE dispatch,
-    pipelining — all need it)."""
-    import functools
-    import inspect
-
+def shard_map_norep(f, *, mesh, in_specs, out_specs):
+    """jax.shard_map with replication (varying-manual-axes) checking
+    off: the manual-collective ops — ring attention, MoE dispatch,
+    pipelining — produce outputs the checker cannot type."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        params = inspect.signature(jax.shard_map).parameters
-        if "check_vma" in params:
-            return functools.partial(jax.shard_map, check_vma=False)
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-
-    return functools.partial(shard_map, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

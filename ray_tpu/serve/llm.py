@@ -32,6 +32,7 @@ zero double-delivered tokens.
 
 from __future__ import annotations
 
+import logging
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -40,6 +41,8 @@ from ray_tpu import exceptions as rex
 from ray_tpu.models.inference import InferenceConfig, InferenceEngine
 from ray_tpu.serve import core
 from ray_tpu.serve.core import Application, AutoscalingConfig, deployment
+
+logger = logging.getLogger(__name__)
 
 
 @deployment(name="llm")
@@ -397,7 +400,7 @@ def disagg_stream_frames(prompt: Sequence[int],
                     yield r
                     if r.get("done"):
                         return
-            except (rex.RayTpuError, rex.ActorError):
+            except (rex.RayTpuError, rex.ActorError) as e:
                 # mid-stream replica loss: resume via re-prefill of
                 # prompt + delivered (PR-9 session resumption — greedy
                 # determinism continues bit-identically, so the client
@@ -405,6 +408,12 @@ def disagg_stream_frames(prompt: Sequence[int],
                 resumes += 1
                 if resumes > max_resumes:
                     raise
+                # say what was lost: a resume that hides its cause
+                # turns a failing replica into a slow stream
+                logger.warning(
+                    "stream (session %s) lost its decode replica after "
+                    "%d delivered tokens, resume %d/%d: %r", session_id,
+                    len(delivered), resumes, max_resumes, e)
                 core.metrics.count("resumed")
                 if token is not None:
                     dec_state.end_sticky(token)

@@ -57,6 +57,72 @@ class TestGraphBuilders:
 
 
 # ---------------------------------------------------------------------------
+# chip-or-fail: no device metric from the CPU, no failure carried past
+# ---------------------------------------------------------------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load_bench():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "bench", os.path.join(REPO, "bench.py"))
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    return bench
+
+
+def test_bench_without_smoke_refuses_to_run_off_the_chip():
+    """A missing TPU is an error before any section (exit 2), not a
+    quiet CPU run under device metric names."""
+    import subprocess
+    import sys
+
+    from ray_tpu._private import spawn_env
+
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
+        env=spawn_env.child_env(extra={"JAX_PLATFORMS": "cpu"}),
+        capture_output=True, text=True, timeout=300)
+    assert out.returncode == 2
+    assert "no TPU" in out.stderr
+    assert out.stdout.strip() == ""   # no record at all
+
+
+def test_bench_section_that_raises_is_recorded_and_fails_the_run(
+        capsys, tmp_path, monkeypatch):
+    bench = _load_bench()
+    monkeypatch.setattr(bench, "PARTIAL_PATH",
+                        str(tmp_path / "partial.json"))
+    try:
+        raise ValueError("section blew up")
+    except Exception:
+        bench._failed("mfu")
+    assert bench.FAILED == {"mfu": "ValueError: section blew up"}
+    bench._emit(to_stdout=True)
+    captured = capsys.readouterr()
+    import json
+    line = json.loads(captured.out.strip().splitlines()[-1])
+    assert line["sections_failed"] == {"mfu": "ValueError: section blew up"}
+    assert "ValueError" in captured.err   # the traceback is shown
+
+
+def test_device_peaks_are_keyed_by_exact_kind_and_sourced():
+    from ray_tpu._private import perf
+
+    v5e = perf.device_peaks("TPU v5 lite")
+    assert v5e["bf16_flops"] == 197e12
+    assert v5e["hbm_bytes_per_sec"] == 819e9
+    assert v5e["hbm_bytes"] == 16e9
+    assert "TPU v5e" in v5e["source"]
+    # no substring matching, no default: an unknown kind is an error
+    for kind in ("TPU v5", "tpu v5 lite", "TPU v5 lite pod", "cpu"):
+        with pytest.raises(KeyError, match="DEVICE_PEAKS"):
+            perf.device_peaks(kind)
+
+
+# ---------------------------------------------------------------------------
 # control ring: ring-on must never be slower than ring-off
 # ---------------------------------------------------------------------------
 

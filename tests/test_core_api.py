@@ -25,6 +25,50 @@ def test_put_objectref_rejected(ray_start_regular):
         ray_tpu.put(ref)
 
 
+def test_puts_from_threads_without_a_task_never_share_an_id(
+        ray_start_regular):
+    """Threads that run no task (user threads, actor methods in thread
+    mode) put under the driver's task id. Each used to count its puts
+    from 1, so the n-th put of two threads minted ONE ObjectID and each
+    read the other's value — found on the chip, where concurrent
+    prefill() calls of one serve replica swapped their KV handoffs."""
+    import threading
+
+    refs = {}
+    barrier = threading.Barrier(8)
+
+    def put_own_value(i):
+        barrier.wait(timeout=30)
+        refs[i] = [ray_tpu.put((i, n)) for n in range(20)]
+
+    threads = [threading.Thread(target=put_own_value, args=(i,))
+               for i in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+        assert not t.is_alive()
+    ids = [r.object_id() for rs in refs.values() for r in rs]
+    assert len(set(ids)) == 8 * 20
+    for i, rs in refs.items():
+        assert ray_tpu.get(rs) == [(i, n) for n in range(20)]
+
+
+def test_concurrent_actor_methods_put_distinct_objects(ray_start_regular):
+    @ray_tpu.remote
+    class Exporter:
+        def export(self, i):
+            import time
+            ref = ray_tpu.put({"owner": i})
+            time.sleep(0.05)   # let the other calls put too
+            return ref
+
+    a = Exporter.options(max_concurrency=8).remote()
+    refs = ray_tpu.get([a.export.remote(i) for i in range(8)])
+    assert len({r.object_id() for r in refs}) == 8
+    assert [ray_tpu.get(r)["owner"] for r in refs] == list(range(8))
+
+
 def test_simple_task(ray_start_regular):
     @ray_tpu.remote
     def f(x):

@@ -54,6 +54,21 @@ class TestPagedAttention:
         ker = pa.paged_attention(q, kp, vp, table, lens, interpret=True)
         np.testing.assert_allclose(ref, ker, atol=1e-5)
 
+    def test_auto_raises_when_the_kernel_raises(self, monkeypatch):
+        """paged_attention_auto chooses a path; it never swaps a
+        failing kernel for the XLA gather (on the chip that recorded a
+        kernel that did not lower as a slow pass)."""
+        def boom(*a, **kw):
+            raise RuntimeError("kernel failed to lower")
+
+        monkeypatch.setattr(pa, "paged_attention", boom)
+        q = jnp.ones((2, 4, 16))
+        pages = jnp.ones((8, 2, 4, 16))
+        with pytest.raises(RuntimeError, match="failed to lower"):
+            pa.paged_attention_auto(q, pages, pages,
+                                    jnp.zeros((2, 2), jnp.int32),
+                                    jnp.asarray([1, 3], jnp.int32))
+
     def test_zero_length_sequence(self):
         B, H, KV, D, page, P, MP = 2, 4, 2, 16, 4, 8, 2
         q = jnp.ones((B, H, D))

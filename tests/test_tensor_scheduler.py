@@ -397,6 +397,68 @@ class TestTensorSchedulerMultiNode:
             sched.shutdown()
 
 
+class TestTicksByBackend:
+    """stats() says which backend served the assignment passes, so a
+    caller can tell a device run from a numpy one (chip_smoke.py holds
+    phase A to device ticks > 0 and numpy ticks == 0)."""
+
+    @pytest.mark.parametrize("backend,other", [("jax", "numpy"),
+                                               ("numpy", "jax")])
+    def test_public_api_ticks_counted_under_the_chosen_backend(
+            self, backend, other):
+        ray_tpu.shutdown()
+        ray_tpu.init(num_workers=2, scheduler="tensor",
+                     _system_config={"sched_backend": backend})
+        try:
+            @ray_tpu.remote
+            def f(i):
+                return i + 1
+
+            assert ray_tpu.get(f.map_remote([(i,) for i in range(64)]),
+                               timeout=120) == list(range(1, 65))
+            from ray_tpu._private.worker import global_worker
+            stats = global_worker.scheduler.stats()
+        finally:
+            ray_tpu.shutdown()
+        assert stats["ticks_by_backend"][backend] > 0
+        assert stats["ticks_by_backend"][other] == 0
+        assert stats["assign_failures"] == 0
+        assert stats["ticks"] >= stats["ticks_by_backend"][backend]
+
+    def test_failing_device_tick_raises_and_is_counted(self, monkeypatch):
+        """Under the device backend a failing tick is NOT served by
+        numpy: the pass raises (the tick loop logs it and counts it)
+        and the task stays queued."""
+        from ray_tpu._private.config import GLOBAL_CONFIG
+        from ray_tpu._private.scheduler.base import PendingTask
+
+        def boom(*a, **kw):
+            raise RuntimeError("device tick failed")
+
+        monkeypatch.setattr(kernels, "jax_assign", boom)
+        GLOBAL_CONFIG.unfreeze()
+        GLOBAL_CONFIG.apply_system_config({"sched_backend": "jax"})
+        mk = TestTensorSchedulerMultiNode()
+        sched, dispatched, lock = mk._mk([(2.0, 0, 1e18, 1e18)])
+        try:
+            sched.submit(PendingTask(spec=mk._spec(0), deps=[],
+                                     execute=lambda t, n: None))
+            deadline = time.time() + 5
+            while time.time() < deadline:
+                if sched.stats()["assign_failures"]:
+                    break
+                time.sleep(0.005)
+            stats = sched.stats()
+        finally:
+            sched.shutdown()
+            GLOBAL_CONFIG.reset()
+        assert stats["assign_failures"] >= 1
+        assert stats["ticks_by_backend"] == {"jax": 0, "numpy": 0}
+        assert stats["ready_queue"] == 1
+        with lock:
+            assert dispatched == []
+
+
 class TestManyClasses:
     """The class axis is scanned (class as data), so large class counts
     must run the jax path without per-class recompiles and must match the
