@@ -1222,19 +1222,6 @@ def main() -> int:
             OUT["rl_rollout"] = None
         _emit()
 
-    # top device-op time sinks of one train step (profiler-derived) —
-    # least load-bearing section, so it runs last
-    if section("model_time_sinks", 20 if smoke else 45):
-        try:
-            OUT["model_time_sinks"] = perf.model_time_sinks(
-                smoke=smoke)
-            print(f"  time sinks: {OUT['model_time_sinks']}",
-                  file=sys.stderr)
-        except Exception:
-            _failed("model_time_sinks")
-            OUT["model_time_sinks"] = None
-        _emit()
-
     _emit(to_stdout=True)
     return 1 if FAILED else 0
 

@@ -870,83 +870,6 @@ def model_mfu(d_model: int = 2048, n_layers: int = 8, n_heads: int = 16,
     }
 
 
-def model_time_sinks(top_k: int = 5, smoke: bool = False) -> list:
-    """Top device-op time sinks of one flagship train step, from a
-    jax.profiler trace (SURVEY §5 tracing note: xplane device
-    timelines). Returns [{op, pct_of_device_time}] sorted descending —
-    fusion.N names are XLA's own fusion labels."""
-    import collections
-    import glob
-    import gzip
-    import json
-    import tempfile
-
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models import train_step as ts
-    from ray_tpu.models.transformer import Transformer, TransformerConfig
-
-    if smoke:
-        cfg = TransformerConfig.tiny()
-        batch, seq = 2, 128
-    else:
-        cfg = TransformerConfig(vocab_size=32_768, d_model=2048, n_layers=8,
-                                n_heads=16, n_kv_heads=8, d_ff=5632,
-                                max_seq_len=2048, remat=True,
-                                remat_policy="dots")
-        batch, seq = 8, 2048
-    model = Transformer(cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,
-                                cfg.vocab_size, dtype=jnp.int32)
-    params = jax.jit(lambda rng: model.init(rng, tokens)["params"])(
-        jax.random.PRNGKey(0))
-    optimizer = ts.make_optimizer()
-    opt_state = jax.jit(optimizer.init)(params)
-    step = jax.jit(ts.make_train_step(model, optimizer),
-                   donate_argnums=(0, 1))
-    compiled = step.lower(params, opt_state, {"tokens": tokens}).compile()
-    params, opt_state, m = compiled(params, opt_state, {"tokens": tokens})
-    float(jax.device_get(m["loss"]))
-    n_steps = 2
-    with tempfile.TemporaryDirectory() as td:
-        with jax.profiler.trace(td):
-            for _ in range(n_steps):
-                params, opt_state, m = compiled(params, opt_state,
-                                                {"tokens": tokens})
-            float(jax.device_get(m["loss"]))
-        traces = sorted(glob.glob(f"{td}/**/*.trace.json.gz",
-                                  recursive=True))
-        if not traces:
-            return []
-        events = json.loads(gzip.open(traces[-1]).read())["traceEvents"]
-    # restrict to DEVICE lanes via process metadata (host runtime spans
-    # like ExecuteCompiled would otherwise pollute ranking + total)
-    device_pids = set()
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            pname = (e.get("args") or {}).get("name", "")
-            if "TPU" in pname or "device" in pname.lower():
-                device_pids.add(e.get("pid"))
-    dur: collections.Counter = collections.Counter()
-    for e in events:
-        if e.get("ph") != "X" or "dur" not in e:
-            continue
-        if device_pids and e.get("pid") not in device_pids:
-            continue
-        name = e.get("name", "")
-        # belt & braces when no metadata exists: python-host spans carry
-        # $file:line names, jit_* is the whole program, ints are steps
-        if name.startswith(("$", "jit_", "np.")) or name.isdigit():
-            continue
-        dur[name] += e["dur"]
-    # report each op's SHARE of summed device time (the ranking and
-    # proportions are what this reduction is for)
-    total = sum(dur.values()) or 1
-    return [{"op": name, "pct_of_device_time": round(100.0 * d / total, 1)}
-            for name, d in dur.most_common(top_k)]
-
-
 def llm_decode_throughput(smoke: bool = False,
                           batch_slots: Optional[int] = None) -> dict:
     """Paged-attention decode tokens/s on the attached device

@@ -22,9 +22,10 @@ parity test pins this functional forward to the flax module's output.
 from __future__ import annotations
 
 import dataclasses
-import functools
+import itertools
 import queue
 import threading
+import time
 from concurrent.futures import Future
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -32,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private import spans, trace_plane
 from ray_tpu.models.transformer import TransformerConfig, _rope
 from ray_tpu.ops.paged_attention import (append_token_kv,
                                          paged_attention_auto,
@@ -80,24 +82,28 @@ def _prefill_layer(p, cfg: TransformerConfig, x, positions):
     """Full-attention prefill for one layer over [N,S,Dm]; returns
     (x_out, k [N,S,KV,D], v [N,S,KV,D])."""
     a = p["Attention_0"]
-    h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(cfg.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, a["wk"].astype(cfg.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, a["wv"].astype(cfg.dtype))
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    rep = cfg.n_heads // cfg.n_kv_heads
-    kr = jnp.repeat(k, rep, axis=2)
-    vr = jnp.repeat(v, rep, axis=2)
-    s = x.shape[1]
-    mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
-    scores = jnp.einsum("bshk,bthk->bhst", q, kr) / jnp.sqrt(cfg.head_dim)
-    scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-    attn = jnp.einsum("bhst,bthk->bshk", probs, vr)
-    x = x + jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(cfg.dtype))
-    x = x + _mlp(p["MLP_0"], _rms(x, p["RMSNorm_1"]["scale"],
-                                  cfg.norm_eps), cfg.dtype)
+    with jax.named_scope("attn"):
+        h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
+        q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(cfg.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", h, a["wk"].astype(cfg.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", h, a["wv"].astype(cfg.dtype))
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+        rep = cfg.n_heads // cfg.n_kv_heads
+        kr = jnp.repeat(k, rep, axis=2)
+        vr = jnp.repeat(v, rep, axis=2)
+        s = x.shape[1]
+        mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
+        scores = (jnp.einsum("bshk,bthk->bhst", q, kr)
+                  / jnp.sqrt(cfg.head_dim))
+        scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+        attn = jnp.einsum("bhst,bthk->bshk", probs, vr)
+        x = x + jnp.einsum("bshk,hkd->bsd", attn,
+                           a["wo"].astype(cfg.dtype))
+    with jax.named_scope("mlp"):
+        x = x + _mlp(p["MLP_0"], _rms(x, p["RMSNorm_1"]["scale"],
+                                      cfg.norm_eps), cfg.dtype)
     return x, k, v
 
 
@@ -107,21 +113,25 @@ def _decode_layer(p, cfg: TransformerConfig, x, positions, k_pages,
     cache; appends this token's K/V. seq_lens = cache length BEFORE the
     token. Returns (x_out, k_pages, v_pages)."""
     a = p["Attention_0"]
-    h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-    q = jnp.einsum("bd,dhk->bhk", h, a["wq"].astype(cfg.dtype))
-    k = jnp.einsum("bd,dhk->bhk", h, a["wk"].astype(cfg.dtype))
-    v = jnp.einsum("bd,dhk->bhk", h, a["wv"].astype(cfg.dtype))
-    # rope over a length-1 "sequence" per slot
-    q = _rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
-    k = _rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
-    k_pages, v_pages = append_token_kv(k_pages, v_pages, k, v,
-                                       page_table, seq_lens)
-    out = paged_attention_auto(q, k_pages, v_pages, page_table,
-                               seq_lens + 1)
-    x = x + jnp.einsum("bhk,hkd->bd", out.astype(cfg.dtype),
-                       a["wo"].astype(cfg.dtype))
-    x = x + _mlp(p["MLP_0"], _rms(x, p["RMSNorm_1"]["scale"],
-                                  cfg.norm_eps), cfg.dtype)
+    with jax.named_scope("attn"):
+        h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
+        q = jnp.einsum("bd,dhk->bhk", h, a["wq"].astype(cfg.dtype))
+        k = jnp.einsum("bd,dhk->bhk", h, a["wk"].astype(cfg.dtype))
+        v = jnp.einsum("bd,dhk->bhk", h, a["wv"].astype(cfg.dtype))
+        # rope over a length-1 "sequence" per slot
+        q = _rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+        k = _rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+    with jax.named_scope("kv_append"):
+        k_pages, v_pages = append_token_kv(k_pages, v_pages, k, v,
+                                           page_table, seq_lens)
+    with jax.named_scope("attn"):
+        out = paged_attention_auto(q, k_pages, v_pages, page_table,
+                                   seq_lens + 1)
+        x = x + jnp.einsum("bhk,hkd->bd", out.astype(cfg.dtype),
+                           a["wo"].astype(cfg.dtype))
+    with jax.named_scope("mlp"):
+        x = x + _mlp(p["MLP_0"], _rms(x, p["RMSNorm_1"]["scale"],
+                                      cfg.norm_eps), cfg.dtype)
     return x, k_pages, v_pages
 
 
@@ -130,7 +140,8 @@ def prefill_batch(params: Dict[str, Any], cfg: TransformerConfig,
     """tokens [N,S] (padded to a bucket) -> (logits [N,S,V] f32,
     k_seq/v_seq [L,N,S,KV,D]) — N prompts prefill in one program."""
     embed = params["embedding"]
-    x = embed.astype(cfg.dtype)[tokens]
+    with jax.named_scope("embed"):
+        x = embed.astype(cfg.dtype)[tokens]
     s = tokens.shape[1]
     positions = jnp.arange(s)[None, :]
     ks, vs = [], []
@@ -138,9 +149,11 @@ def prefill_batch(params: Dict[str, Any], cfg: TransformerConfig,
         x, k, v = _prefill_layer(params[f"layer_{i}"], cfg, x, positions)
         ks.append(k)
         vs.append(v)
-    x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(cfg.dtype))
-    return (logits.astype(jnp.float32), jnp.stack(ks), jnp.stack(vs))
+    with jax.named_scope("head"):
+        x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(cfg.dtype))
+        logits = logits.astype(jnp.float32)
+    return (logits, jnp.stack(ks), jnp.stack(vs))
 
 
 def prefill(params: Dict[str, Any], cfg: TransformerConfig,
@@ -162,7 +175,8 @@ def decode_step(params: Dict[str, Any], cfg: TransformerConfig,
     stacking into one [L,...] array would copy the whole cache every
     step). Returns (next_logits [B,V] f32, k_pages, v_pages)."""
     embed = params["embedding"]
-    x = embed.astype(cfg.dtype)[tokens]          # [B, Dm]
+    with jax.named_scope("embed"):
+        x = embed.astype(cfg.dtype)[tokens]      # [B, Dm]
     positions = seq_lens                          # this token's position
     new_k, new_v = [], []
     for i in range(cfg.n_layers):
@@ -171,9 +185,11 @@ def decode_step(params: Dict[str, Any], cfg: TransformerConfig,
                                   seq_lens)
         new_k.append(kp)
         new_v.append(vp)
-    x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps)
-    logits = jnp.einsum("bd,vd->bv", x, embed.astype(cfg.dtype))
-    return (logits.astype(jnp.float32), tuple(new_k), tuple(new_v))
+    with jax.named_scope("head"):
+        x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps)
+        logits = jnp.einsum("bd,vd->bv", x, embed.astype(cfg.dtype))
+        logits = logits.astype(jnp.float32)
+    return (logits, tuple(new_k), tuple(new_v))
 
 
 def decode_chunk(params: Dict[str, Any], cfg: TransformerConfig,
@@ -190,7 +206,8 @@ def decode_chunk(params: Dict[str, Any], cfg: TransformerConfig,
         toks, kp, vp, lens = carry
         logits, kp, vp = decode_step(params, cfg, toks, kp, vp,
                                      page_table, lens)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (nxt, kp, vp, lens + 1), nxt
 
     carry, outs = jax.lax.scan(body,
@@ -203,6 +220,20 @@ def decode_chunk(params: Dict[str, Any], cfg: TransformerConfig,
 # ----------------------------------------------------------------------
 # the engine
 # ----------------------------------------------------------------------
+
+def _program(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn)`` under a name of its own. A profile lists each
+    execution as ``jit_<name>`` and jax's compile events say
+    ``jit(<name>)``; a lambda or a ``functools.partial`` would read
+    ``jit__lambda_`` or ``jit__unknown`` there. The bucket or step
+    count a program was specialised for goes INTO its name, so a reader
+    of the trace needs nothing else to tell the programs apart."""
+    def call(*args):
+        return fn(*args)
+
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call, **jit_kwargs)
+
 
 _STREAM_END = object()
 
@@ -230,11 +261,19 @@ class TokenStream:
 
 class _Request:
     __slots__ = ("prompt", "max_new", "future", "out", "emitted", "stream",
-                 "streamed", "kv")
+                 "streamed", "kv", "ident", "parent", "t_mark", "got_first")
 
-    def __init__(self, prompt: List[int], max_new: int):
+    def __init__(self, prompt: List[int], max_new: int, ident: int):
         self.prompt = prompt
         self.max_new = max_new
+        # for the request's three spans (engine.queue, .first_token,
+        # .decode): its number, the trace plane's context of the call
+        # that brought it, when the running span began, and whether the
+        # first token has been handed out
+        self.ident = ident
+        self.parent = trace_plane.current_parent()
+        self.t_mark = time.perf_counter()
+        self.got_first = False
         self.future: Future = Future()
         self.out: List[int] = []   # tokens synced to host
         self.emitted = 0           # tokens produced on device (>= len(out))
@@ -244,6 +283,13 @@ class _Request:
         # arrays from a prefill replica's export; admission imports the
         # pages instead of running the prompt pass
         self.kv: Optional[Tuple[Any, Any, int]] = None
+
+    def end_span(self, name: str) -> None:
+        """The request's running span ends now, under ``name``, and the
+        next begins."""
+        now = time.perf_counter()
+        spans.record(name, self.t_mark, now, self.ident, self.parent)
+        self.t_mark = now
 
 
 class _Slot:
@@ -285,14 +331,24 @@ class InferenceEngine:
         self.mode = mode
         L = model_cfg.n_layers
         KV, D = model_cfg.n_kv_heads, model_cfg.head_dim
+        self._idents = itertools.count()
+        # cumulative counts behind stats(): decode steps dispatched,
+        # decode tokens that stayed in a request's output, bursts, and
+        # by prefill bucket [launches, useful rows, prompt tokens]
+        self._decode_steps = 0
+        self._decode_tokens_kept = 0
+        self._bursts = 0
+        self._prefill_counts: Dict[int, List[int]] = {}
+        # single-prompt bucketed prompt pass for prefill_export;
+        # compiles lazily per bucket on first use
+        mcfg = self.mcfg
+        self._export_jits = {
+            b: _program(f"engine_prefill_export_b{b}",
+                        lambda p, t: prefill(p, mcfg, t))
+            for b in cfg.prefill_buckets
+        }
         if mode == "prefill":
-            # single-prompt bucketed prompt pass; compiles lazily per
-            # bucket on first use. Everything decode-shaped is absent.
-            mcfg = self.mcfg
-            self._export_jits = {
-                b: jax.jit(lambda p, t: prefill(p, mcfg, t))
-                for b in cfg.prefill_buckets
-            }
+            # everything decode-shaped is absent
             self._slots = []
             self._free_pages = []
             self._queue = queue.Queue()
@@ -325,7 +381,6 @@ class InferenceEngine:
         # constants (a closure would bake every weight into the HLO as a
         # literal — catastrophic compile times at real model sizes).
         # The cache is donated: each step updates it in place on device.
-        mcfg = self.mcfg
         # chunked decode programs (1, 2, 4, ... decode_chunk steps per
         # dispatch); the loop picks the largest chunk no active slot's
         # remaining budget forbids
@@ -336,19 +391,19 @@ class InferenceEngine:
             n *= 2
         self._decode_chunks = {}
         for steps in self._chunk_sizes:
-            fn = jax.jit(
+            self._decode_chunks[steps] = _program(
+                f"engine_decode_n{steps}",
                 lambda p, toks, kp, vp, table, lens, _n=steps:
                 decode_chunk(p, mcfg, toks, kp, vp, table, lens,
                              n_steps=_n),
                 donate_argnums=(2, 3))
-            self._decode_chunks[steps] = \
-                (lambda *a, _f=fn: _f(self.params, *a))
         # burst state rides ONE packed upload [B, 1 + max_pages]
         # (column 0 = seq_lens, rest = page table — one transfer
         # instead of two); lens then EVOLVES
         # on device across the burst's chained chunks while the table
         # stays fixed
-        self._split_packed = jax.jit(
+        self._split_packed = _program(
+            "engine_split_packed",
             lambda packed: (packed[:, 1:], packed[:, 0]))
 
         # BATCHED prefill: N admissions in one program behind ONE packed
@@ -357,7 +412,7 @@ class InferenceEngine:
         # rows carry slot_idx == batch_size, whose scatter is dropped
         # (out-of-bounds scatters drop) and whose pages point at the
         # parking page. jit re-specializes per (N, bucket) shape.
-        def prefill_write_many(p, packed, kp, vp, toks_vec, *, bucket):
+        def prefill_write_many(p, packed, kp, vp, toks_vec, bucket):
             n_prog = -(-bucket // cfg.page_size)
             slots = packed[:, 0]
             plens = packed[:, 1]
@@ -366,20 +421,25 @@ class InferenceEngine:
             logits, k_seq, v_seq = prefill_batch(p, mcfg, toks)
             new_k, new_v = list(kp), list(vp)
             n = packed.shape[0]
-            for i in range(mcfg.n_layers):
-                ki, vi = new_k[i], new_v[i]
-                for r in range(n):
-                    ki, vi = write_prefill_kv(ki, vi, k_seq[i, r],
-                                              v_seq[i, r], pages[r])
-                new_k[i], new_v[i] = ki, vi
-            row_logits = logits[jnp.arange(n), plens - 1]       # [N,V]
-            nxt = jnp.argmax(row_logits, axis=-1).astype(jnp.int32)
-            toks_vec = toks_vec.at[slots].set(nxt)
+            with jax.named_scope("kv_append"):
+                for i in range(mcfg.n_layers):
+                    ki, vi = new_k[i], new_v[i]
+                    for r in range(n):
+                        ki, vi = write_prefill_kv(ki, vi, k_seq[i, r],
+                                                  v_seq[i, r], pages[r])
+                    new_k[i], new_v[i] = ki, vi
+            with jax.named_scope("head"):
+                row_logits = logits[jnp.arange(n), plens - 1]   # [N,V]
+                nxt = jnp.argmax(row_logits, axis=-1).astype(jnp.int32)
+                toks_vec = toks_vec.at[slots].set(nxt)
             return nxt, toks_vec, tuple(new_k), tuple(new_v)
 
         self._prefill_many = ({} if mode == "decode" else {
-            b: jax.jit(functools.partial(prefill_write_many, bucket=b),
-                       donate_argnums=(2, 3, 4))
+            b: _program(
+                f"engine_prefill_b{b}",
+                lambda p, packed, kp, vp, toks_vec, _b=b:
+                prefill_write_many(p, packed, kp, vp, toks_vec, _b),
+                donate_argnums=(2, 3, 4))
             for b in cfg.prefill_buckets
         })
 
@@ -392,14 +452,16 @@ class InferenceEngine:
         def kv_import_one(kp, vp, toks_vec, k_seq, v_seq, pages,
                           slot_first):
             new_k, new_v = list(kp), list(vp)
-            for i in range(mcfg.n_layers):
-                new_k[i], new_v[i] = write_prefill_kv(
-                    new_k[i], new_v[i], k_seq[i], v_seq[i], pages)
+            with jax.named_scope("kv_append"):
+                for i in range(mcfg.n_layers):
+                    new_k[i], new_v[i] = write_prefill_kv(
+                        new_k[i], new_v[i], k_seq[i], v_seq[i], pages)
             toks_vec = toks_vec.at[slot_first[0]].set(slot_first[1])
             return toks_vec, tuple(new_k), tuple(new_v)
 
         # one jit, respecialized per padded bucket shape
-        self._kv_import = jax.jit(kv_import_one, donate_argnums=(0, 1, 2))
+        self._kv_import = _program("engine_kv_import", kv_import_one,
+                                   donate_argnums=(0, 1, 2))
         # persistent device-resident feedback state: admission scatters
         # the prefill's next-token in WITHOUT a host read (a sync
         # stalls the dispatch pipeline; a dispatch does not)
@@ -445,7 +507,7 @@ class InferenceEngine:
                 f"the monolithic engine (prefill_export / "
                 f"submit_stream_from_kv are the split-pool entry points)")
         max_new = self._validate(prompt, max_new_tokens)
-        req = _Request(list(prompt), max_new)
+        req = _Request(list(prompt), max_new, next(self._idents))
         self._queue.put(req)
         self._wake.set()
         return req.future
@@ -460,7 +522,7 @@ class InferenceEngine:
                 f"engine is in {self.mode!r} mode; plain submit_stream "
                 f"needs the monolithic engine")
         max_new = self._validate(prompt, max_new_tokens)
-        req = _Request(list(prompt), max_new)
+        req = _Request(list(prompt), max_new, next(self._idents))
         stream = TokenStream(req.future)
         req.stream = stream
         self._queue.put(req)
@@ -488,19 +550,8 @@ class InferenceEngine:
                       if b >= plen)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :plen] = list(prompt)
-        jit = (self._export_jits[bucket] if self.mode == "prefill"
-               else None)
-        if jit is None:
-            # "both"-mode engines export through the same functional
-            # prefill, jitted lazily per bucket
-            jits = getattr(self, "_export_jits", None)
-            if jits is None:
-                mcfg = self.mcfg
-                jits = self._export_jits = {
-                    b: jax.jit(lambda p, t: prefill(p, mcfg, t))
-                    for b in self.cfg.prefill_buckets}
-            jit = jits[bucket]
-        logits, k_seq, v_seq = jit(self.params, jnp.asarray(toks))
+        logits, k_seq, v_seq = self._export_jits[bucket](
+            self.params, jnp.asarray(toks))
         first = int(jnp.argmax(logits[plen - 1]))
         k = np.asarray(k_seq[:, :plen])
         v = np.asarray(v_seq[:, :plen])
@@ -522,7 +573,7 @@ class InferenceEngine:
         max_new = self._validate(
             prompt, kv.get("max_new") if max_new_tokens is None
             else max_new_tokens)
-        req = _Request(prompt, max_new)
+        req = _Request(prompt, max_new, next(self._idents))
         req.kv = (kv["k"], kv["v"], int(kv["first_token"]))
         stream = TokenStream(req.future)
         req.stream = stream
@@ -538,7 +589,30 @@ class InferenceEngine:
         return self.submit(prompt, max_new_tokens).result(timeout)
 
     def stats(self) -> Dict[str, Any]:
+        """The engine's state now, and its work so far as cumulative
+        counts. ``num_steps`` counts chunk DISPATCHES (a chunk runs 1 to
+        ``decode_chunk`` steps); ``decode_steps`` counts the steps, each
+        of which runs all ``batch_size`` slots (``decode_slot_steps``),
+        of whose tokens ``decode_tokens_kept`` reached a request's
+        output: the rest fell to idle slots and to steps past a
+        request's ``max_new``. A burst is one round of the loop that
+        dispatched something and fetched once. A prefill launch runs
+        ``batch_size`` rows of its bucket (``prefill_rows``,
+        ``prefill_positions`` = rows x bucket) for the
+        ``prefill_useful_rows`` requests admitted in it and their
+        ``prefill_prompt_tokens``; ``prefill_by_bucket`` has the same
+        five by bucket."""
+        rows = self.cfg.batch_size
         with self._lock:
+            by_bucket = {
+                b: {"launches": n, "rows": n * rows, "useful_rows": useful,
+                    "positions": n * rows * b, "prompt_tokens": tokens}
+                for b, (n, useful, tokens) in sorted(
+                    self._prefill_counts.items())}
+            prefill = {
+                "prefill_" + k: sum(c[k] for c in by_bucket.values())
+                for k in ("launches", "rows", "useful_rows", "positions",
+                          "prompt_tokens")}
             return {
                 "mode": self.mode,
                 "num_steps": self.num_steps,
@@ -546,6 +620,12 @@ class InferenceEngine:
                 "free_pages": len(self._free_pages),
                 "active": sum(s.req is not None for s in self._slots),
                 "queued": self._queue.qsize(),
+                "bursts": self._bursts,
+                "decode_steps": self._decode_steps,
+                "decode_slot_steps": self._decode_steps * rows,
+                "decode_tokens_kept": self._decode_tokens_kept,
+                **prefill,
+                "prefill_by_bucket": by_bucket,
             }
 
     def shutdown(self) -> None:
@@ -611,6 +691,7 @@ class InferenceEngine:
             free_slot.pages = pages
             free_slot.seq_len = plen
             req.emitted = 1
+            req.end_span("engine.queue")
             (imports if req.kv is not None else admits).append(
                 (free_slot, req, pages))
         for slot, req, pages in imports:
@@ -657,10 +738,19 @@ class InferenceEngine:
             page_list = (pages + [self._parking_page] * n_prog)[:n_prog]
             packed[r, 2 + bucket:] = page_list
             rows.append((slot, r))
-        nxt, self._dev_toks, self._k_pages, self._v_pages = \
-            self._prefill_many[bucket](
-                self.params, jnp.asarray(packed), self._k_pages,
-                self._v_pages, self._dev_toks)
+        prompt_tokens = sum(len(req.prompt) for _, req, _ in group)
+        with self._lock:
+            counts = self._prefill_counts.setdefault(bucket, [0, 0, 0])
+            counts[0] += 1
+            counts[1] += len(group)
+            counts[2] += prompt_tokens
+        with spans.span("engine.prefill_launch", bucket=bucket, rows=n,
+                        useful_rows=len(group),
+                        prompt_tokens=prompt_tokens):
+            nxt, self._dev_toks, self._k_pages, self._v_pages = \
+                self._prefill_many[bucket](
+                    self.params, jnp.asarray(packed), self._k_pages,
+                    self._v_pages, self._dev_toks)
         self._pending_firsts.append((nxt, rows))
 
     def _import_group(self, slot: _Slot, req: _Request,
@@ -692,6 +782,11 @@ class InferenceEngine:
             jnp.asarray(np.asarray([slot_idx, first], np.int32)))
         req.out = [first]
         self._maybe_finish(slot)  # max_new == 1 finishes at admission
+        self._hand_out(req)
+
+    def _hand_out(self, req: _Request) -> None:
+        """Push what ``req.out`` has gained onto its stream, and close
+        the request's spans as it passes their ends."""
         if req.stream is not None:
             new = req.out[req.streamed:]
             if new:
@@ -699,6 +794,11 @@ class InferenceEngine:
             req.streamed += len(new)
             if req.future.done():
                 req.stream._q.put(_STREAM_END)
+        if not req.got_first and req.out:
+            req.got_first = True
+            req.end_span("engine.first_token")
+        if req.future.done():
+            req.end_span("engine.decode")
 
     def _maybe_finish(self, slot: _Slot) -> None:
         req = slot.req
@@ -737,109 +837,140 @@ class InferenceEngine:
                 self._fail_outstanding(e)
 
     def _loop_once(self) -> None:
-            self._try_admit()
-            active = [s for s in self._slots if s.req is not None]
-            if not active:
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
-                return
-            self.max_concurrent = max(self.max_concurrent, len(active))
-            # ONE packed upload per burst carries lens + page table
-            # (host bookkeeping is authoritative for both); the TOKEN
-            # feedback vector lives on device across bursts — prefill
-            # results scatter in without ever being read to host first
-            packed = np.zeros(
-                (self.cfg.batch_size, 1 + self.cfg.max_pages_per_seq),
-                np.int32)
-            # idle slots decode dummy tokens whose K/V appends land in
-            # the reserved parking page; their outputs are discarded.
-            # UNALLOCATED table entries also point at the parking page:
-            # budget-overrun appends (chunk overshoot, finished slots
-            # decoding out a burst) land there instead of page 0.
-            packed[:, 1:] = self._parking_page
-            for i, s in enumerate(self._slots):
-                if s.req is not None:
-                    packed[i, 0] = s.seq_len
-                    for j, p in enumerate(s.pages):
-                        packed[i, 1 + j] = p
-            dev_toks = self._dev_toks
-            dev_table, dev_lens = self._split_packed(jnp.asarray(packed))
+        """One burst: admit, dispatch, fetch, deliver. Each of the four
+        is a span on this thread, so a burst that dispatched something
+        leaves one set of them (and nothing is recorded while the
+        engine idles)."""
+        if not (self._queue.empty()
+                and all(s.req is None for s in self._slots)):
+            with spans.span("engine.admit"):
+                self._try_admit()
+        active = [s for s in self._slots if s.req is not None]
+        if not active:
+            self._wake.wait(timeout=0.05)
+            self._wake.clear()
+            return
+        self.max_concurrent = max(self.max_concurrent, len(active))
+        with spans.span("engine.dispatch", live_slots=len(active),
+                        live_ctx_tokens=sum(s.seq_len for s in active)
+                        ) as burst:
+            pending = self._dispatch_burst(active)
+            steps = sum(chunk for _, chunk in pending)
+            burst.fields.update(steps=steps, chunks=len(pending))
 
-            # async burst: dispatch chunks back-to-back WITHOUT reading
-            # results (jax dispatch is async).
-            # The host materializes tokens ONCE per burst in a single
-            # combined fetch — or per-chunk when EOS detection is
-            # configured (early exit needs the values).
-            inflight = {id(s): 0 for s in active}
-            pending: List[Tuple[Any, int]] = []
-            while True:
-                remaining = min(
-                    s.req.max_new - s.req.emitted - inflight[id(s)]
-                    for s in active)
-                if remaining <= 0 or len(pending) >= 4:
-                    break
-                # smallest chunk COVERING the remaining budget when one
-                # exists: a 63-token budget runs one 64-step program
-                # (the 1-token overrun trims at finish; its KV appends
-                # land in parking-paged table slots) instead of
-                # 32+16+8+4+2+1 separate dispatches
-                covering = [c for c in self._chunk_sizes
-                            if c >= remaining]
-                chunk = (min(covering) if covering
-                         else self._chunk_sizes[-1])
-                (outs, dev_toks, dev_lens, self._k_pages,
-                 self._v_pages) = self._decode_chunks[chunk](
-                     dev_toks, self._k_pages, self._v_pages, dev_table,
-                     dev_lens)
-                self.num_steps += 1
-                pending.append((outs, chunk))
-                for s in active:
-                    inflight[id(s)] += chunk
-                    s.seq_len += chunk
-                if self.cfg.eos_id is not None:
-                    break  # EOS needs the values: one chunk per burst
-            self._dev_toks = dev_toks
-
-            # ONE fetch per burst: chunk outputs + any pending prefill
-            # first-tokens, concatenated on device, read together
-            firsts, self._pending_firsts = self._pending_firsts, []
-            parts = [outs.reshape(-1) for outs, _ in pending]
-            parts.extend(arr for arr, _rows in firsts)
-            if not parts:
-                return
+        # ONE fetch per burst: chunk outputs + any pending prefill
+        # first-tokens, concatenated on device, read together
+        firsts, self._pending_firsts = self._pending_firsts, []
+        parts = [outs.reshape(-1) for outs, _ in pending]
+        parts.extend(arr for arr, _rows in firsts)
+        if not parts:
+            return
+        with spans.span("engine.fetch"):
             flat = np.asarray(jnp.concatenate(parts)
                               if len(parts) > 1 else parts[0])
-            # distribute: first-tokens sit after this burst's chunk rows
-            off = sum(c * self.cfg.batch_size for _, c in pending)
-            for arr, rows in firsts:
-                for slot, r in rows:
-                    if slot.req is not None:
-                        slot.req.out.insert(0, int(flat[off + r]))
-                off += len(arr)
-            pos = 0
-            for outs, chunk in pending:
-                arr = flat[pos:pos + chunk * self.cfg.batch_size].reshape(
-                    chunk, self.cfg.batch_size)
-                pos += chunk * self.cfg.batch_size
-                for i, s in enumerate(self._slots):
-                    if s.req is None or id(s) not in inflight:
-                        continue
-                    s.req.out.extend(int(t) for t in arr[:, i])
+        with spans.span("engine.deliver") as deliver:
+            kept = self._deliver(active, pending, firsts, flat)
+            deliver.fields["kept_tokens"] = kept
+        with self._lock:      # a burst's counts land together
+            self._bursts += 1
+            self._decode_steps += steps
+            self._decode_tokens_kept += kept
+
+    def _dispatch_burst(self, active: List[_Slot]) -> List[Tuple[Any, int]]:
+        """Upload the burst's lens + page table and dispatch its decode
+        chunks back-to-back; returns [(chunk's tokens on the device,
+        its steps)]."""
+        # ONE packed upload per burst carries lens + page table
+        # (host bookkeeping is authoritative for both); the TOKEN
+        # feedback vector lives on device across bursts — prefill
+        # results scatter in without ever being read to host first
+        packed = np.zeros(
+            (self.cfg.batch_size, 1 + self.cfg.max_pages_per_seq),
+            np.int32)
+        # idle slots decode dummy tokens whose K/V appends land in
+        # the reserved parking page; their outputs are discarded.
+        # UNALLOCATED table entries also point at the parking page:
+        # budget-overrun appends (chunk overshoot, finished slots
+        # decoding out a burst) land there instead of page 0.
+        packed[:, 1:] = self._parking_page
+        for i, s in enumerate(self._slots):
+            if s.req is not None:
+                packed[i, 0] = s.seq_len
+                for j, p in enumerate(s.pages):
+                    packed[i, 1 + j] = p
+        dev_toks = self._dev_toks
+        dev_table, dev_lens = self._split_packed(jnp.asarray(packed))
+
+        # async burst: dispatch chunks back-to-back WITHOUT reading
+        # results (jax dispatch is async).
+        # The host materializes tokens ONCE per burst in a single
+        # combined fetch — or per-chunk when EOS detection is
+        # configured (early exit needs the values).
+        inflight = 0
+        pending: List[Tuple[Any, int]] = []
+        while True:
+            remaining = min(s.req.max_new - s.req.emitted
+                            for s in active) - inflight
+            if remaining <= 0 or len(pending) >= 4:
+                break
+            # smallest chunk COVERING the remaining budget when one
+            # exists: a 63-token budget runs one 64-step program
+            # (the 1-token overrun trims at finish; its KV appends
+            # land in parking-paged table slots) instead of
+            # 32+16+8+4+2+1 separate dispatches
+            covering = [c for c in self._chunk_sizes
+                        if c >= remaining]
+            chunk = (min(covering) if covering
+                     else self._chunk_sizes[-1])
+            (outs, dev_toks, dev_lens, self._k_pages,
+             self._v_pages) = self._decode_chunks[chunk](
+                 self.params, dev_toks, self._k_pages, self._v_pages,
+                 dev_table, dev_lens)
+            self.num_steps += 1
+            pending.append((outs, chunk))
+            inflight += chunk
             for s in active:
-                if s.req is not None:
-                    s.req.emitted = len(s.req.out)
-            for s in active:
-                req = s.req
-                if req is None:
+                s.seq_len += chunk
+            if self.cfg.eos_id is not None:
+                break  # EOS needs the values: one chunk per burst
+        self._dev_toks = dev_toks
+        return pending
+
+    def _deliver(self, active: List[_Slot], pending: List[Tuple[Any, int]],
+                 firsts: List[Tuple[Any, List[Tuple[_Slot, int]]]],
+                 flat: np.ndarray) -> int:
+        """Distribute a burst's fetched tokens to its requests, finish
+        those that are done and feed the streams. Returns how many of
+        the burst's decode tokens stayed in a request's output."""
+        # first-tokens sit after this burst's chunk rows
+        off = sum(c * self.cfg.batch_size for _, c in pending)
+        for arr, rows in firsts:
+            for slot, r in rows:
+                if slot.req is not None:
+                    slot.req.out.insert(0, int(flat[off + r]))
+            off += len(arr)
+        live = {id(s) for s in active}
+        kept = -sum(len(s.req.out) for s in active if s.req is not None)
+        pos = 0
+        for outs, chunk in pending:
+            arr = flat[pos:pos + chunk * self.cfg.batch_size].reshape(
+                chunk, self.cfg.batch_size)
+            pos += chunk * self.cfg.batch_size
+            for i, s in enumerate(self._slots):
+                if s.req is None or id(s) not in live:
                     continue
-                self._maybe_finish(s)   # may trim EOS overrun + finish
-                if req.stream is not None:
-                    new = req.out[req.streamed:]
-                    if new:
-                        req.stream._q.put(new)
-                    req.streamed += len(new)
-                    if req.future.done():
-                        req.stream._q.put(_STREAM_END)
+                s.req.out.extend(int(t) for t in arr[:, i])
+        for s in active:
+            if s.req is not None:
+                s.req.emitted = len(s.req.out)
+        for s in active:
+            req = s.req
+            if req is None:
+                continue
+            self._maybe_finish(s)   # may trim EOS overrun + finish
+            kept += len(req.out)
+            self._hand_out(req)
+        return kept
 
     @property
     def _parking_page(self) -> int:

@@ -88,7 +88,8 @@ def make_train_step(model: Transformer,
             logits, mods = model.apply({"params": params},
                                        tokens[:, :-1],
                                        mutable=["intermediates"])
-        loss = cross_entropy_loss(logits, tokens[:, 1:])
+        with jax.named_scope("loss"):
+            loss = cross_entropy_loss(logits, tokens[:, 1:])
         # MoE load balancing: consume every sown moe_aux term (a sown-
         # but-unconsumed aux would let the router collapse all tokens
         # onto one expert). Zero-cost for dense models (no leaves).
@@ -102,8 +103,9 @@ def make_train_step(model: Transformer,
 
     def train_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch["tokens"])
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         if param_shardings is not None:
             params = jax.lax.with_sharding_constraint(params,
                                                       param_shardings)

@@ -1,0 +1,322 @@
+"""What the inference engine records of itself: cumulative counters in
+``stats()``, burst and request spans in ``_private/spans.py``'s ring,
+a name for every jitted program and scopes inside the forward.
+
+No wall-clock assertion: spans are compared with each other only. A
+test reads the ring after ``engine.shutdown()``, which joins the loop
+thread, so every span of the run is in it.
+"""
+
+import collections
+import time
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from ray_tpu._private import spans, trace_plane  # noqa: E402
+from ray_tpu.models import train_step as ts  # noqa: E402
+from ray_tpu.models.inference import (InferenceConfig,  # noqa: E402
+                                      InferenceEngine, decode_step,
+                                      prefill_batch)
+from ray_tpu.models.transformer import (Transformer,  # noqa: E402
+                                        TransformerConfig)
+
+BURST = ("engine.admit", "engine.dispatch", "engine.fetch",
+         "engine.deliver")
+REQUEST = ("engine.queue", "engine.first_token", "engine.decode")
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_layers=2,
+                            n_heads=4, n_kv_heads=2, d_ff=64,
+                            max_seq_len=256, dtype=jnp.float32)
+    model = Transformer(cfg)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))
+    return cfg, model, variables["params"]
+
+
+ICFG = InferenceConfig(batch_size=3, page_size=4, max_pages_per_seq=16,
+                       num_pages=64, prefill_buckets=(8, 16),
+                       decode_chunk=4)
+PROMPTS = [[7], [1, 2, 3, 4, 5, 6, 7, 8, 9], [9, 9, 9], [5] * 16, [3, 4],
+           [2] * 11, [8, 1]]
+MAX_NEW = [5, 9, 1, 17, 6, 3, 12]
+
+
+def serve(tiny_model, prompts=PROMPTS, max_new=MAX_NEW, icfg=ICFG,
+          parent=None):
+    """Run the requests through a fresh engine; (stats, outputs, the
+    ring's spans of this run)."""
+    cfg, _model, params = tiny_model
+    t0 = time.perf_counter()
+    engine = InferenceEngine(params, cfg, icfg)
+    try:
+        with trace_plane.parent_scope(parent):
+            futs = [engine.submit(p, max_new_tokens=n)
+                    for p, n in zip(prompts, max_new)]
+        outs = [f.result(timeout=300) for f in futs]
+    finally:
+        engine.shutdown()
+    return engine.stats(), outs, spans.since(t0)
+
+
+@pytest.fixture(scope="module")
+def run(tiny_model):
+    return serve(tiny_model, parent=("trace-1", "span-1", None, True))
+
+
+def named(records, name):
+    return [r for r in records if r[0] == name]
+
+
+def test_stats_keeps_its_six_keys(run):
+    stats, outs, records = run
+    assert list(stats)[:6] == ["mode", "num_steps", "max_concurrent",
+                               "free_pages", "active", "queued"]
+    assert stats["mode"] == "both" and stats["active"] == 0
+    assert stats["queued"] == 0
+    assert stats["free_pages"] == ICFG.num_pages - 1
+    assert 1 <= stats["max_concurrent"] <= ICFG.batch_size
+    # num_steps stays the count of chunk DISPATCHES
+    assert stats["num_steps"] == sum(
+        r[5]["chunks"] for r in named(records, "engine.dispatch"))
+    assert [len(o) for o in outs] == MAX_NEW
+
+
+def test_counters_conserve(run):
+    stats, outs, _ = run
+    rows = ICFG.batch_size
+    assert stats["prefill_useful_rows"] == len(PROMPTS)
+    assert stats["prefill_prompt_tokens"] == sum(map(len, PROMPTS))
+    assert stats["prefill_rows"] == stats["prefill_launches"] * rows
+    by_bucket = stats["prefill_by_bucket"]
+    assert set(by_bucket) <= set(ICFG.prefill_buckets)
+    for bucket, c in by_bucket.items():
+        assert c["rows"] == c["launches"] * rows
+        assert c["positions"] == c["launches"] * rows * bucket
+        assert 1 <= c["useful_rows"] <= c["rows"]
+    for key in ("launches", "rows", "useful_rows", "positions",
+                "prompt_tokens"):
+        assert stats["prefill_" + key] == sum(
+            c[key] for c in by_bucket.values())
+    assert by_bucket[16]["useful_rows"] == sum(len(p) > 8 for p in PROMPTS)
+    # a request's first token comes from its prefill, the rest from
+    # decode steps; every step runs every slot
+    assert stats["decode_tokens_kept"] == sum(len(o) - 1 for o in outs)
+    assert stats["decode_slot_steps"] == rows * stats["decode_steps"]
+    assert stats["decode_tokens_kept"] <= stats["decode_slot_steps"]
+
+
+def test_ring_and_counters_agree_to_the_unit(run):
+    """The engine was fresh, so its counters are the differences over
+    the run, and the ring's records of the run sum to them."""
+    stats, _, records = run
+    launches = [r[5] for r in named(records, "engine.prefill_launch")]
+    assert len(launches) == stats["prefill_launches"]
+    for key in ("rows", "useful_rows", "prompt_tokens"):
+        assert sum(f[key] for f in launches) == stats["prefill_" + key]
+    assert sum(f["rows"] * f["bucket"] for f in launches) == \
+        stats["prefill_positions"]
+    for bucket, c in stats["prefill_by_bucket"].items():
+        assert c["launches"] == sum(f["bucket"] == bucket for f in launches)
+    dispatches = [r[5] for r in named(records, "engine.dispatch")]
+    assert sum(f["steps"] for f in dispatches) == stats["decode_steps"]
+    delivers = named(records, "engine.deliver")
+    assert len(delivers) == stats["bursts"]
+    assert sum(r[5]["kept_tokens"] for r in delivers) == \
+        stats["decode_tokens_kept"]
+    for f in dispatches:
+        assert 1 <= f["live_slots"] <= ICFG.batch_size
+        assert f["live_ctx_tokens"] >= f["live_slots"]
+        assert f["chunks"] <= 4 and f["steps"] <= 4 * ICFG.decode_chunk
+
+
+def test_request_spans_share_an_ident_and_are_ordered(run):
+    _, _, records = run
+    by_ident = collections.defaultdict(dict)
+    for r in records:
+        if r[0] in REQUEST:
+            assert r[0] not in by_ident[r[3]]
+            by_ident[r[3]][r[0]] = r
+    assert sorted(by_ident) == list(range(len(PROMPTS)))
+    for got in by_ident.values():
+        queue, first, decode = (got[n] for n in REQUEST)
+        # submit <= admit <= first token <= finish, each span beginning
+        # where the one before it ended
+        assert queue[1] <= queue[2] == first[1] <= first[2] == decode[1] \
+            <= decode[2]
+        # the trace plane's context of the submitting call
+        assert {r[4] for r in got.values()} == {("trace-1", "span-1",
+                                                 None, True)}
+    assert all(r[3] is None and r[4] is None for r in records
+               if r[0] in BURST)
+
+
+def test_no_parent_outside_a_traced_call(tiny_model):
+    _, _, records = serve(tiny_model, PROMPTS[:2], [2, 2])
+    assert [r[4] for r in records if r[0] in REQUEST] == [None] * 6
+
+
+def test_burst_spans_of_the_loop_do_not_overlap(run):
+    _, _, records = run
+    loop = sorted((r for r in records if r[0] in BURST), key=lambda r: r[1])
+    for a, b in zip(loop, loop[1:]):
+        assert a[2] <= b[1], (a, b)
+    # one set a round, in the loop's order
+    assert [r[0] for r in loop] == list(BURST) * (len(loop) // 4)
+    admits = named(records, "engine.admit")
+    for launch in named(records, "engine.prefill_launch"):
+        assert any(a[1] <= launch[1] and launch[2] <= a[2] for a in admits)
+
+
+def test_ring_grows_with_bursts_launches_and_requests_not_with_tokens(
+        tiny_model):
+    """Three requests of 8 and of 100 tokens: four spans a burst, one a
+    launch, three a request and nothing else, and a request takes at
+    most two bursts however long its answer (up to 4 x decode_chunk
+    steps go into one)."""
+    icfg = InferenceConfig(batch_size=3, page_size=4, max_pages_per_seq=32,
+                           num_pages=128, prefill_buckets=(8,),
+                           decode_chunk=32)
+    prompts = [[1, 2], [3], [4, 5, 6]]
+    for max_new in (8, 100):
+        stats, outs, records = serve(tiny_model, prompts, [max_new] * 3,
+                                     icfg)
+        assert [len(o) for o in outs] == [max_new] * 3
+        assert stats["decode_steps"] >= max_new - 1
+        assert stats["bursts"] <= 2 * len(prompts)
+        assert len(records) == (4 * stats["bursts"]
+                                + stats["prefill_launches"]
+                                + 3 * len(prompts))
+
+
+def test_a_full_ring_drops_the_oldest(monkeypatch):
+    monkeypatch.setattr(spans, "_RING", collections.deque(maxlen=3))
+    for i in range(5):
+        spans.record("r", float(i), float(i) + 0.5, ident=i, n=i)
+    with spans.span("s", ident=9, a=1) as sp:
+        sp.fields["b"] = 2
+    got = spans.since(float("-inf"))
+    assert [(r[0], r[3]) for r in got] == [("r", 3), ("r", 4), ("s", 9)]
+    assert got[-1][5] == {"a": 1, "b": 2} and got[-1][1] <= got[-1][2]
+    assert spans.since(4.2) == got[1:]
+    assert spans._RING.maxlen == 3
+
+
+def test_the_process_ring_is_bounded():
+    assert spans._RING.maxlen == 65536
+
+
+def engine_programs(engine):
+    """(expected module name, lowered program) of every jitted program
+    of a "both"-mode engine."""
+    cfg, params, rows = engine.cfg, engine.params, engine.cfg.batch_size
+    table = jnp.zeros((rows, cfg.max_pages_per_seq), jnp.int32)
+    lens = jnp.zeros((rows,), jnp.int32)
+    kv_shape = (engine.mcfg.n_layers, 8, engine.mcfg.n_kv_heads,
+                engine.mcfg.head_dim)
+    yield "jit_engine_split_packed", engine._split_packed.lower(
+        jnp.zeros((rows, 1 + cfg.max_pages_per_seq), jnp.int32))
+    yield "jit_engine_kv_import", engine._kv_import.lower(
+        engine._k_pages, engine._v_pages, engine._dev_toks,
+        jnp.zeros(kv_shape), jnp.zeros(kv_shape),
+        jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32))
+    for steps, fn in engine._decode_chunks.items():
+        yield f"jit_engine_decode_n{steps}", fn.lower(
+            params, engine._dev_toks, engine._k_pages, engine._v_pages,
+            table, lens)
+    for bucket, fn in engine._prefill_many.items():
+        packed = jnp.zeros(
+            (rows, 2 + bucket + -(-bucket // cfg.page_size)), jnp.int32)
+        yield f"jit_engine_prefill_b{bucket}", fn.lower(
+            params, packed, engine._k_pages, engine._v_pages,
+            engine._dev_toks)
+    for bucket, fn in engine._export_jits.items():
+        yield f"jit_engine_prefill_export_b{bucket}", fn.lower(
+            params, jnp.zeros((1, bucket), jnp.int32))
+
+
+def test_every_engine_program_has_a_name_of_its_own(tiny_model):
+    from jax.extend.core import Primitive, primitives
+
+    cfg, _model, params = tiny_model
+    engine = InferenceEngine(params, cfg, ICFG)
+    try:
+        got = [(want, lowered.as_text().split("\n", 1)[0])
+               for want, lowered in engine_programs(engine)]
+    finally:
+        engine.shutdown()
+    assert len(got) == 2 + 3 + 2 + 2      # chunks of 1, 2 and 4 steps
+    primitive_names = {p.name for p in vars(primitives).values()
+                       if isinstance(p, Primitive)}
+    for want, first_line in got:
+        assert first_line.startswith(f"module @{want} "), (want, first_line)
+        # what jax calls the compiled program is jit(<name>): a name that
+        # is a primitive's would read as an eager dispatch
+        assert want[len("jit_"):] not in primitive_names
+    assert len({want for want, _ in got}) == len(got)
+
+
+def scopes_in(lowered):
+    """Under jax.grad a scope reads jvp(<scope>) and
+    transpose(jvp(<scope>))."""
+    text = lowered.as_text(debug_info=True)
+    return {s for s in ("embed", "attn", "kv_append", "mlp", "head",
+                        "loss", "optimizer")
+            if f"/{s}/" in text or f"/jvp({s})/" in text}
+
+
+def test_the_forward_carries_its_scopes(tiny_model):
+    cfg, _model, params = tiny_model
+    engine = InferenceEngine(params, cfg, ICFG)
+    try:
+        rows = ICFG.batch_size
+        step = jax.jit(lambda p, t, k, v, table, lens: decode_step(
+            p, cfg, t, k, v, table, lens)).lower(
+                params, engine._dev_toks, engine._k_pages, engine._v_pages,
+                jnp.zeros((rows, ICFG.max_pages_per_seq), jnp.int32),
+                jnp.zeros((rows,), jnp.int32))
+        assert scopes_in(step) == {"embed", "attn", "kv_append", "mlp",
+                                   "head"}
+        batch = jax.jit(lambda p, t: prefill_batch(p, cfg, t)).lower(
+            params, jnp.zeros((rows, 8), jnp.int32))
+        assert scopes_in(batch) == {"embed", "attn", "mlp", "head"}
+        # the prefill program writes the prompt's K/V into the pages
+        lowered = dict(engine_programs(engine))
+        assert "kv_append" in scopes_in(lowered["jit_engine_prefill_b8"])
+        assert "kv_append" in scopes_in(lowered["jit_engine_kv_import"])
+    finally:
+        engine.shutdown()
+
+
+def test_the_train_step_carries_loss_and_optimizer(tiny_model):
+    _cfg, model, params = tiny_model
+    optimizer = ts.make_optimizer()
+    step = jax.jit(ts.make_train_step(model, optimizer)).lower(
+        params, optimizer.init(params),
+        {"tokens": jnp.zeros((2, 9), jnp.int32)})
+    assert {"loss", "optimizer"} <= scopes_in(step)
+
+
+def test_a_profile_holds_the_engines_annotations(tiny_model, tmp_path):
+    """Under jax.profiler the loop's spans are annotations on the
+    profiler's clock, in the host's plane."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        stats, _, _ = serve(tiny_model, PROMPTS[:3], MAX_NEW[:3])
+    finally:
+        jax.profiler.stop_trace()
+    found = sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    assert found
+    data = jax.profiler.ProfileData.from_file(str(found[-1]))
+    names = collections.Counter(
+        e.name for plane in data.planes if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events
+        if e.name.startswith("engine."))
+    assert names["engine.fetch"] == stats["bursts"]
+    assert names["engine.dispatch"] == stats["bursts"]
+    assert names["engine.prefill_launch"] == stats["prefill_launches"]
