@@ -334,7 +334,7 @@ class InferenceEngine:
         self._idents = itertools.count()
         # cumulative counts behind stats(): decode steps dispatched,
         # decode tokens that stayed in a request's output, bursts, and
-        # by prefill bucket [launches, useful rows, prompt tokens]
+        # by prefill bucket [launches, rows, useful rows, prompt tokens]
         self._decode_steps = 0
         self._decode_tokens_kept = 0
         self._bursts = 0
@@ -411,7 +411,8 @@ class InferenceEngine:
         # [slot_idx, plen, tokens(bucket), pages(n_prog)]; dummy pad
         # rows carry slot_idx == batch_size, whose scatter is dropped
         # (out-of-bounds scatters drop) and whose pages point at the
-        # parking page. jit re-specializes per (N, bucket) shape.
+        # parking page. N is _prefill_rows[bucket], so jit specializes
+        # once per bucket.
         def prefill_write_many(p, packed, kp, vp, toks_vec, bucket):
             n_prog = -(-bucket // cfg.page_size)
             slots = packed[:, 0]
@@ -434,6 +435,13 @@ class InferenceEngine:
                 toks_vec = toks_vec.at[slots].set(nxt)
             return nxt, toks_vec, tuple(new_k), tuple(new_v)
 
+        # every launch computes one budget of positions, that of a
+        # single prompt in the largest bucket: a bucket b runs
+        # largest // b rows (at most batch_size, at least 1)
+        largest = max(cfg.prefill_buckets)
+        self._prefill_rows = {
+            b: max(1, min(cfg.batch_size, largest // b))
+            for b in cfg.prefill_buckets}
         self._prefill_many = ({} if mode == "decode" else {
             b: _program(
                 f"engine_prefill_b{b}",
@@ -596,18 +604,20 @@ class InferenceEngine:
         of whose tokens ``decode_tokens_kept`` reached a request's
         output: the rest fell to idle slots and to steps past a
         request's ``max_new``. A burst is one round of the loop that
-        dispatched something and fetched once. A prefill launch runs
-        ``batch_size`` rows of its bucket (``prefill_rows``,
-        ``prefill_positions`` = rows x bucket) for the
-        ``prefill_useful_rows`` requests admitted in it and their
-        ``prefill_prompt_tokens``; ``prefill_by_bucket`` has the same
-        five by bucket."""
-        rows = self.cfg.batch_size
+        dispatched something and fetched once. A prefill launch of
+        bucket ``b`` runs ``largest_bucket // b`` rows (at most
+        ``batch_size``, at least 1), so every launch computes about the
+        positions of one prompt in the largest bucket; summed over the
+        launches these are ``prefill_rows`` and ``prefill_positions``
+        (rows x bucket), run for the ``prefill_useful_rows`` requests
+        admitted in them and their ``prefill_prompt_tokens``;
+        ``prefill_by_bucket`` has the same five by bucket (useful rows
+        over rows is a bucket's fill share)."""
         with self._lock:
             by_bucket = {
-                b: {"launches": n, "rows": n * rows, "useful_rows": useful,
-                    "positions": n * rows * b, "prompt_tokens": tokens}
-                for b, (n, useful, tokens) in sorted(
+                b: {"launches": n, "rows": rows, "useful_rows": useful,
+                    "positions": rows * b, "prompt_tokens": tokens}
+                for b, (n, rows, useful, tokens) in sorted(
                     self._prefill_counts.items())}
             prefill = {
                 "prefill_" + k: sum(c[k] for c in by_bucket.values())
@@ -622,7 +632,8 @@ class InferenceEngine:
                 "queued": self._queue.qsize(),
                 "bursts": self._bursts,
                 "decode_steps": self._decode_steps,
-                "decode_slot_steps": self._decode_steps * rows,
+                "decode_slot_steps": (self._decode_steps
+                                      * self.cfg.batch_size),
                 "decode_tokens_kept": self._decode_tokens_kept,
                 **prefill,
                 "prefill_by_bucket": by_bucket,
@@ -707,18 +718,22 @@ class InferenceEngine:
             self._prefill_group(bucket, group)
 
     def _prefill_group(self, bucket: int, group: List[tuple]) -> None:
+        """Prefill a bucket's admitted requests, ``_prefill_rows[bucket]``
+        to a launch; only the last launch carries dummy rows."""
+        n = self._prefill_rows[bucket]
+        for i in range(0, len(group), n):
+            self._prefill_launch(bucket, group[i:i + n])
+
+    def _prefill_launch(self, bucket: int, group: List[tuple]) -> None:
         n_prog = -(-bucket // self.cfg.page_size)
         width = 2 + bucket + n_prog
-        # FIXED program shape: always batch_size rows (dummies padded).
-        # Admission arrival order races the submitter, so group sizes
-        # are nondeterministic — shape-per-size programs would compile
-        # at unpredictable moments mid-serving (measured as multi-second
-        # stalls); one shape per bucket compiles exactly once. The cost
-        # is dummy rows running the full prefill forward, which is
-        # bounded by bucket length (say 16 rows x 128 tokens on a small
-        # model ~ well under a millisecond of device time) and is paid
-        # only at admission, never per decode step.
-        n = self.cfg.batch_size
+        # ONE program shape per bucket, chosen by the bucket alone: a
+        # shape that followed the size of the group would compile while
+        # serving, whenever a size came up for the first time. The
+        # dummy rows run the whole forward like any other, so a bucket
+        # gets as many rows as fit a launch's budget of positions
+        # (_prefill_rows) and a larger group takes more launches.
+        n = self._prefill_rows[bucket]
         packed = np.zeros((n, width), np.int32)
         # dummy pad rows: scatter target out of bounds (dropped), pages
         # at the parking page, plen 1
@@ -740,10 +755,11 @@ class InferenceEngine:
             rows.append((slot, r))
         prompt_tokens = sum(len(req.prompt) for _, req, _ in group)
         with self._lock:
-            counts = self._prefill_counts.setdefault(bucket, [0, 0, 0])
+            counts = self._prefill_counts.setdefault(bucket, [0, 0, 0, 0])
             counts[0] += 1
-            counts[1] += len(group)
-            counts[2] += prompt_tokens
+            counts[1] += n
+            counts[2] += len(group)
+            counts[3] += prompt_tokens
         with spans.span("engine.prefill_launch", bucket=bucket, rows=n,
                         useful_rows=len(group),
                         prompt_tokens=prompt_tokens):

@@ -139,3 +139,52 @@ def test_scheduler_assign_kernel(chip):
         chip((nodes, res), jnp.float32), chip((1, nodes), jnp.bool_),
         chip((1,), jnp.bool_)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("bucket, rows", [(512, 1), (64, 8)])
+def test_engine_prefill_program(chip, bucket, rows, capsys):
+    """The serving cell's prefill program (Mistral-7B widths, 2 of its
+    layers, 32 slots, 1,537 pages of 16, buckets 64 to 512): a launch
+    computes the positions of one prompt in the largest bucket, so
+    ``engine_prefill_b512`` has 1 row and ``b64`` has 8. The engine is
+    built on shapes alone; its own jitted program is what compiles."""
+    from ray_tpu.models.inference import InferenceConfig, InferenceEngine
+    from ray_tpu.models.transformer import Transformer, TransformerConfig
+
+    mcfg = TransformerConfig(vocab_size=32768, d_model=4096, n_layers=2,
+                             n_heads=32, n_kv_heads=8, d_ff=14336,
+                             max_seq_len=768, rope_theta=1e6,
+                             dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    icfg = InferenceConfig(batch_size=32, page_size=16, max_pages_per_seq=48,
+                           num_pages=1537,
+                           prefill_buckets=(64, 128, 256, 512))
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: chip(x.shape, x.dtype), tree)
+    params = jax.eval_shape(Transformer(mcfg).init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    engine = InferenceEngine(params, mcfg, icfg)
+    try:
+        assert engine._prefill_rows[bucket] == rows
+        packed = chip((rows, 2 + bucket + bucket // icfg.page_size),
+                      jnp.int32)
+        compiled = engine._prefill_many[bucket].lower(
+            on_chip(params), packed, on_chip(engine._k_pages),
+            on_chip(engine._v_pages), on_chip(engine._dev_toks)).compile()
+    finally:
+        engine.shutdown()
+    assert compiled.as_text().startswith(
+        f"HloModule jit_engine_prefill_b{bucket}")
+    m = compiled.memory_analysis()
+    pool = 2 * sum(x.size * 2 for x in engine._k_pages)
+    with capsys.disabled():
+        print(f"\n[engine_prefill_b{bucket}, {rows} rows, 2 layers] "
+              f"arguments {m.argument_size_in_bytes / 1e9:.3f} GB (pool "
+              f"{pool / 1e9:.3f}), temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} GB, aliased "
+              f"{m.alias_size_in_bytes / 1e9:.3f} GB")
+    # the pool is donated and updated in place
+    assert m.alias_size_in_bytes >= pool
+    # prefill_batch of 32 x 512 took 4.7 GB beside the 16 layers'
+    # weights (PERF.md section 4); a launch's budget of 512 positions
+    # has to stay a small part of the chip
+    assert m.temp_size_in_bytes < 1 << 30

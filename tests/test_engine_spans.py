@@ -8,6 +8,8 @@ thread, so every span of the run is in it.
 """
 
 import collections
+import contextlib
+import threading
 import time
 
 import pytest
@@ -87,17 +89,22 @@ def test_stats_keeps_its_six_keys(run):
     assert [len(o) for o in outs] == MAX_NEW
 
 
+def rows_of(icfg, bucket):
+    """Rows of a prefill launch: the positions of one prompt in the
+    largest bucket, at most ``batch_size`` rows and at least one."""
+    return max(1, min(icfg.batch_size, max(icfg.prefill_buckets) // bucket))
+
+
 def test_counters_conserve(run):
     stats, outs, _ = run
     rows = ICFG.batch_size
     assert stats["prefill_useful_rows"] == len(PROMPTS)
     assert stats["prefill_prompt_tokens"] == sum(map(len, PROMPTS))
-    assert stats["prefill_rows"] == stats["prefill_launches"] * rows
     by_bucket = stats["prefill_by_bucket"]
     assert set(by_bucket) <= set(ICFG.prefill_buckets)
     for bucket, c in by_bucket.items():
-        assert c["rows"] == c["launches"] * rows
-        assert c["positions"] == c["launches"] * rows * bucket
+        assert c["rows"] == c["launches"] * rows_of(ICFG, bucket)
+        assert c["positions"] == c["rows"] * bucket
         assert 1 <= c["useful_rows"] <= c["rows"]
     for key in ("launches", "rows", "useful_rows", "positions",
                 "prompt_tokens"):
@@ -117,6 +124,7 @@ def test_ring_and_counters_agree_to_the_unit(run):
     stats, _, records = run
     launches = [r[5] for r in named(records, "engine.prefill_launch")]
     assert len(launches) == stats["prefill_launches"]
+    assert all(f["rows"] == rows_of(ICFG, f["bucket"]) for f in launches)
     for key in ("rows", "useful_rows", "prompt_tokens"):
         assert sum(f[key] for f in launches) == stats["prefill_" + key]
     assert sum(f["rows"] * f["bucket"] for f in launches) == \
@@ -194,6 +202,160 @@ def test_ring_grows_with_bursts_launches_and_requests_not_with_tokens(
                                 + 3 * len(prompts))
 
 
+@contextlib.contextmanager
+def one_admission_round(engine):
+    """Hold the loop's admission while the block submits, so that what
+    it submits (to an idle engine, with slots and pages for all of it)
+    is admitted in ONE round, a group to a bucket."""
+    gate, admit = threading.Event(), engine._try_admit
+
+    def held():
+        gate.wait()
+        admit()
+
+    engine._try_admit = held
+    try:
+        yield
+    finally:
+        del engine._try_admit
+        gate.set()
+
+
+def watch_prefill_shapes(engine):
+    """{bucket: [shape of ``packed`` at each call of its program]}"""
+    seen = collections.defaultdict(list)
+    for bucket, fn in list(engine._prefill_many.items()):
+        def call(p, packed, *rest, _fn=fn, _bucket=bucket):
+            seen[_bucket].append(packed.shape)
+            return _fn(p, packed, *rest)
+        engine._prefill_many[bucket] = call
+    return seen
+
+
+@pytest.mark.parametrize("batch_size, buckets, want", [
+    (3, (8, 16), {8: 2, 16: 1}),                    # ICFG's
+    (4, (8, 16, 32, 64), {8: 4, 16: 4, 32: 2, 64: 1}),   # 8 rows clamp to 4
+    (2, (16,), {16: 1}),
+    (32, (64, 128, 256, 512), {64: 8, 128: 4, 256: 2, 512: 1}),  # the cell's
+])
+def test_a_launch_has_the_rows_its_bucket_gives_it(tiny_model, batch_size,
+                                                   buckets, want):
+    """rows(b) = clamp(largest bucket // b, 1, batch_size): the program
+    is called with that many rows, and the launch's span and stats()
+    report that many."""
+    cfg, _model, params = tiny_model
+    largest = max(buckets)
+    icfg = InferenceConfig(batch_size=batch_size, page_size=4,
+                           max_pages_per_seq=-(-largest // 4) + 1,
+                           num_pages=2 * (largest // 4 + 2),
+                           prefill_buckets=buckets, decode_chunk=2)
+    assert {b: rows_of(icfg, b) for b in buckets} == want
+    t0 = time.perf_counter()
+    engine = InferenceEngine(params, cfg, icfg)
+    try:
+        assert engine._prefill_rows == want
+        shapes = watch_prefill_shapes(engine)
+        for bucket in buckets:      # one at a time: a launch a request
+            assert len(engine.generate([5] * bucket, 2)) == 2
+    finally:
+        engine.shutdown()
+    for bucket, rows in want.items():
+        assert shapes[bucket] == [(rows, 2 + bucket + -(-bucket // 4))]
+    launches = [r[5] for r in named(spans.since(t0), "engine.prefill_launch")]
+    assert [(f["bucket"], f["rows"], f["useful_rows"]) for f in launches] \
+        == [(b, want[b], 1) for b in buckets]
+    by_bucket = engine.stats()["prefill_by_bucket"]
+    assert {b: (c["launches"], c["rows"], c["positions"])
+            for b, c in by_bucket.items()} \
+        == {b: (1, want[b], want[b] * b) for b in buckets}
+
+
+GROUPS = InferenceConfig(batch_size=10, page_size=4, max_pages_per_seq=12,
+                         num_pages=10 * 12 + 1, prefill_buckets=(8, 16, 32),
+                         decode_chunk=4)      # rows: 4, 2, 1
+
+
+def group_prompts(sizes):
+    """``sizes[bucket]`` distinct prompts that fall into each bucket"""
+    prompts = []
+    for bucket, n in sizes.items():
+        for i in range(n):
+            plen = bucket - (i % (bucket // 2))     # bucket/2 < plen <= bucket
+            prompts.append([(7 * len(prompts) + 3 * j + 1) % 64
+                            for j in range(plen)])
+    return prompts
+
+
+@pytest.mark.parametrize("sizes", [{8: 5, 16: 3, 32: 2}, {8: 9, 16: 1},
+                                   {16: 4, 32: 3, 8: 1}])
+def test_a_group_larger_than_a_launch_is_split_and_answers_as_alone(
+        tiny_model, sizes):
+    """One admission round with more requests of a bucket than a launch
+    has rows: ceil(group / rows) launches, the last one padded, and every
+    request answers with the tokens it gets when it is served alone."""
+    cfg, _model, params = tiny_model
+    prompts = group_prompts(sizes)
+    max_new = [3 + i % 5 for i in range(len(prompts))]
+    engine = InferenceEngine(params, cfg, GROUPS)
+    try:
+        alone = [engine.generate(p, n) for p, n in zip(prompts, max_new)]
+        t0, before = time.perf_counter(), engine.stats()
+        with one_admission_round(engine):
+            futs = [engine.submit(p, n) for p, n in zip(prompts, max_new)]
+        together = [f.result(timeout=300) for f in futs]
+        after = engine.stats()
+    finally:
+        engine.shutdown()
+    assert together == alone
+    assert [len(o) for o in together] == max_new
+    launches = [r[5] for r in named(spans.since(t0), "engine.prefill_launch")]
+    for bucket, n in sizes.items():
+        rows = rows_of(GROUPS, bucket)
+        mine = [f for f in launches if f["bucket"] == bucket]
+        assert [f["useful_rows"] for f in mine] == \
+            [rows] * (n // rows) + [n % rows] * (n % rows > 0)
+        assert all(f["rows"] == rows for f in mine)
+    # positions are the launches' rows x bucket, in the ring and in stats()
+    ring = {"launches": len(launches),
+            "rows": sum(f["rows"] for f in launches),
+            "useful_rows": len(prompts),
+            "positions": sum(f["rows"] * f["bucket"] for f in launches),
+            "prompt_tokens": sum(map(len, prompts))}
+    assert {key: after["prefill_" + key] - before["prefill_" + key]
+            for key in ring} == ring
+    assert sum(f["prompt_tokens"] for f in launches) == ring["prompt_tokens"]
+
+
+def test_each_prefill_program_compiles_once_whatever_the_group(tiny_model):
+    """Groups of every size from 1 to batch_size in every bucket: a
+    bucket's program is always called with one shape, so jit has
+    specialised it once."""
+    cfg, _model, params = tiny_model
+    icfg = InferenceConfig(batch_size=4, page_size=4, max_pages_per_seq=10,
+                           num_pages=4 * 10 + 1, prefill_buckets=(8, 16, 32),
+                           decode_chunk=2)      # rows: 4, 2, 1
+    engine = InferenceEngine(params, cfg, icfg)
+    try:
+        programs = dict(engine._prefill_many)
+        for bucket in icfg.prefill_buckets:
+            for size in range(1, icfg.batch_size + 1):
+                with one_admission_round(engine):
+                    futs = [engine.submit(p, 2) for p in
+                            group_prompts({bucket: size})]
+                assert [len(f.result(timeout=300)) for f in futs] == \
+                    [2] * size
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    assert {b: fn._cache_size() for b, fn in programs.items()} == \
+        {8: 1, 16: 1, 32: 1}
+    sizes = range(1, icfg.batch_size + 1)
+    assert {b: c["launches"] for b, c in stats["prefill_by_bucket"].items()} \
+        == {b: sum(-(-n // rows_of(icfg, b)) for n in sizes)
+            for b in icfg.prefill_buckets}
+    assert stats["prefill_useful_rows"] == 3 * sum(sizes)
+
+
 def test_a_full_ring_drops_the_oldest(monkeypatch):
     monkeypatch.setattr(spans, "_RING", collections.deque(maxlen=3))
     for i in range(5):
@@ -231,7 +393,8 @@ def engine_programs(engine):
             table, lens)
     for bucket, fn in engine._prefill_many.items():
         packed = jnp.zeros(
-            (rows, 2 + bucket + -(-bucket // cfg.page_size)), jnp.int32)
+            (rows_of(cfg, bucket), 2 + bucket + -(-bucket // cfg.page_size)),
+            jnp.int32)
         yield f"jit_engine_prefill_b{bucket}", fn.lower(
             params, packed, engine._k_pages, engine._v_pages,
             engine._dev_toks)
