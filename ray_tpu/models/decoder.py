@@ -1,0 +1,141 @@
+"""A decoder described layer by layer.
+
+``TransformerConfig`` says what kind of model it is with booleans, and
+every layer is the same. The models people serve mix kinds: a softmax
+attention layer, then three with a recurrent state, experts in every
+feed-forward, an untied head. ``DecoderConfig`` names, for each layer,
+its mixer (``attention`` or ``delta_rule``) and its feed-forward
+(``dense`` or ``experts``), and beside them what the kinds need. The
+dense decoder is the one-kind case: ``describe`` lowers a
+``TransformerConfig`` to it, and the serving engine
+(``models/inference.py``) reads only this description.
+
+The parameter tree a description stands for (``layer_<i>`` under the
+root, beside ``embedding``, ``final_norm/scale`` and, untied,
+``lm_head`` [V, d]):
+
+- ``RMSNorm_0/scale``, ``RMSNorm_1/scale``;
+- mixer ``attention``: ``Attention_0/{wq [d,H,hd], wk, wv [d,KV,hd], wo
+  [H,hd,d]}`` and, gated, ``w_gate [d,H,hd]``;
+- mixer ``delta_rule`` (ops/kda.py): ``DeltaRule_0/{wq, wk [d,H,dk], wv
+  [d,H,dv], conv_q, conv_k [K,H,dk], conv_v [K,H,dv], w_f_down [d,r],
+  w_f_up [r,H,dk], dt_bias [H,dk], A_log [H], w_beta [d,H], w_g_down
+  [d,r], w_g_up [r,H,dv], o_norm [dv], wo [H,dv,d]}``;
+- feed-forward ``dense``: ``MLP_0/{w_gate, w_up [d,f], w_down [f,d]}``;
+- feed-forward ``experts`` (ops/moe.py): ``MoE_0/{router [d,E], w_gate,
+  w_up [E_held,d,fe], w_down [E_held,fe,d]}`` and, with a shared expert,
+  ``MoE_0/shared/{w_gate, w_up [d,fs], w_down [fs,d]}``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional, Tuple
+
+import jax.numpy as jnp
+
+MIXERS = ("attention", "delta_rule")
+FFNS = ("dense", "experts")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    mixer: str = "attention"
+    ffn: str = "dense"
+
+    def __post_init__(self):
+        if self.mixer not in MIXERS or self.ffn not in FFNS:
+            raise ValueError(f"unknown layer kinds {self}")
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    vocab_size: int
+    d_model: int
+    layers: Tuple[LayerSpec, ...]
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    d_ff: int = 0                       # dense feed-forward width
+    rope_theta: Optional[float] = None  # None: no rotation at all
+    attn_gate: bool = False             # out = wo(attn * sigmoid(w_gate h))
+    tie_embeddings: bool = True
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    # delta_rule layers: heads, key and value width, convolution
+    # length, rank of the two low-rank gate projections
+    dr_heads: int = 0
+    dr_key_dim: int = 0
+    dr_value_dim: int = 0
+    dr_conv: int = 4
+    dr_rank: int = 0
+    # experts layers: the router's width, the range of ids held here
+    # [first, last), picks a token, an expert's width, the shared
+    # expert's (0: none)
+    n_routed_experts: int = 0
+    experts_held: Tuple[int, int] = (0, 0)
+    experts_per_token: int = 0
+    d_expert: int = 0
+    d_shared: int = 0
+
+    def __post_init__(self):
+        lo, hi = self.experts_held
+        if self.moe_layers and not (
+                0 <= lo < hi <= self.n_routed_experts
+                and 0 < self.experts_per_token <= self.n_routed_experts):
+            raise ValueError(
+                f"experts_held {self.experts_held} / experts_per_token "
+                f"{self.experts_per_token} do not fit a router of "
+                f"{self.n_routed_experts}")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layers)
+
+    @property
+    def kv_layers(self) -> Tuple[int, ...]:
+        """Layers that keep keys and values in the paged pool."""
+        return tuple(i for i, l in enumerate(self.layers)
+                     if l.mixer == "attention")
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """Layers that keep a recurrent state and a convolution tail a
+        slot."""
+        return tuple(i for i, l in enumerate(self.layers)
+                     if l.mixer == "delta_rule")
+
+    @property
+    def moe_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, l in enumerate(self.layers)
+                     if l.ffn == "experts")
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def dr_channels(self) -> int:
+        """Channels of a delta_rule layer's convolution: q, k and v side
+        by side, a head."""
+        return 2 * self.dr_key_dim + self.dr_value_dim
+
+
+def describe(cfg: Any) -> DecoderConfig:
+    """The description the engine reads: a ``DecoderConfig`` as it is,
+    a ``TransformerConfig`` as the dense decoder it is (rotary GQA,
+    SwiGLU, tied head in every layer)."""
+    if isinstance(cfg, DecoderConfig):
+        return cfg
+    if getattr(cfg, "moe", False):
+        raise ValueError(
+            "TransformerConfig(moe=True) is the training module's top-1 "
+            "switch layer; the serving engine runs experts through a "
+            "DecoderConfig (models/decoder.py)")
+    return DecoderConfig(
+        vocab_size=cfg.vocab_size, d_model=cfg.d_model,
+        layers=(LayerSpec(),) * cfg.n_layers, n_heads=cfg.n_heads,
+        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim, d_ff=cfg.d_ff,
+        rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps, dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype)

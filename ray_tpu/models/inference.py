@@ -13,6 +13,12 @@ ragged-ness lives in page tables + sequence lengths (data, not shapes).
 Weights are the flagship Transformer's (models/transformer.py) taken
 as-is — the same param tree a Train run produces serves directly; a
 parity test pins this functional forward to the flax module's output.
+A model whose layers differ in kind (models/decoder.py: attention or a
+delta-rule recurrence, dense or expert feed-forward) is served by the
+same engine: pools for the layers that keep keys and values, a float32
+state and a convolution tail a slot for those that keep a recurrence,
+written whole by a prefill launch and updated for live slots by a
+decode step.
 
     engine = InferenceEngine(params, model_cfg, InferenceConfig(...))
     fut = engine.submit([1, 2, 3], max_new_tokens=16)
@@ -34,7 +40,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import spans, trace_plane
-from ray_tpu.models.transformer import TransformerConfig, _rope
+from ray_tpu.models.decoder import DecoderConfig, LayerSpec, describe
+from ray_tpu.models.transformer import _flash_supported, _rope
+from ray_tpu.ops import kda
+from ray_tpu.ops.moe import experts_held, route_topk
 from ray_tpu.ops.paged_attention import (append_token_kv,
                                          paged_attention_auto,
                                          write_prefill_kv)
@@ -62,8 +71,14 @@ class InferenceConfig:
 
 
 # ----------------------------------------------------------------------
-# functional forward over the flax param tree
+# functional forward over the param tree (models/decoder.py says which
+# tree a description stands for; the dense decoder's is the flax
+# module's). Every choice between kinds of layer is made while tracing.
 # ----------------------------------------------------------------------
+
+# a row longer than this never materialises its [S,S] scores
+_SCORES_MAX_SEQ = 512
+
 
 def _rms(x, scale, eps):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
@@ -78,143 +93,422 @@ def _mlp(p, x, dtype):
     return h @ p["w_down"].astype(dtype)
 
 
-def _prefill_layer(p, cfg: TransformerConfig, x, positions):
-    """Full-attention prefill for one layer over [N,S,Dm]; returns
-    (x_out, k [N,S,KV,D], v [N,S,KV,D])."""
-    a = p["Attention_0"]
-    with jax.named_scope("attn"):
-        h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(cfg.dtype))
-        k = jnp.einsum("bsd,dhk->bshk", h, a["wk"].astype(cfg.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", h, a["wv"].astype(cfg.dtype))
+def _experts(m, cfg: DecoderConfig, x, valid):
+    """The expert feed-forward over x [..., d] (normed): routed experts
+    held here plus the shared one. Returns (y, picks a held expert
+    [E_held] int32, of the ``valid`` tokens)."""
+    t = x.reshape(-1, x.shape[-1])
+    dt = cfg.dtype
+    with jax.named_scope("moe_route"):
+        ids, weights = route_topk(t, m["router"], cfg.experts_per_token)
+    with jax.named_scope("moe_experts"):
+        y, counts = experts_held(
+            t, ids, weights, m["w_gate"].astype(dt), m["w_up"].astype(dt),
+            m["w_down"].astype(dt), cfg.experts_held[0],
+            valid.reshape(-1))
+    if cfg.d_shared:
+        with jax.named_scope("moe_shared"):
+            y = y + _mlp(m["shared"], t, dt)
+    return y.reshape(x.shape), counts
+
+
+def _feed_forward(p, cfg: DecoderConfig, spec: LayerSpec, x, valid):
+    """x + FFN(norm(x)) of one layer; the picks a held expert, or
+    None."""
+    if spec.ffn == "dense":
+        with jax.named_scope("mlp"):
+            return x + _mlp(p["MLP_0"], _rms(x, p["RMSNorm_1"]["scale"],
+                                             cfg.norm_eps), cfg.dtype), None
+    y, counts = _experts(p["MoE_0"], cfg,
+                         _rms(x, p["RMSNorm_1"]["scale"], cfg.norm_eps),
+                         valid)
+    return x + y, counts
+
+
+def _blockwise_attention(q, kr, vr, block: int = _SCORES_MAX_SEQ):
+    """Causal attention over [N,S,H,D] (heads repeated) without the
+    [S,S] scores: the flash kernel where it runs (ops/flash.py), else
+    the scores of one block of query rows at a time."""
+    if _flash_supported(q.shape[-1]):
+        from ray_tpu.ops.flash import flash_attention_bshk
+
+        return flash_attention_bshk(q, kr, vr)
+    n, s, h, d = q.shape
+    pad = (-s) % block
+    qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+
+    def rows(i):
+        qb = jax.lax.dynamic_slice_in_dim(qp, i * block, block, axis=1)
+        scores = (jnp.einsum("bshk,bthk->bhst", qb, kr)
+                  / jnp.sqrt(d)).astype(jnp.float32)
+        seen = (jnp.arange(s)[None, :]
+                <= i * block + jnp.arange(block)[:, None])
+        probs = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30),
+                               axis=-1).astype(q.dtype)
+        return jnp.einsum("bhst,bthk->bshk", probs, vr)
+
+    out = jax.lax.map(rows, jnp.arange((s + pad) // block))
+    return jnp.moveaxis(out, 0, 1).reshape(n, s + pad, h, d)[:, :s]
+
+
+def _prefill_attention(a, cfg: DecoderConfig, h, positions):
+    """Softmax attention over a bucket: h [N,S,Dm] (normed) -> (out
+    [N,S,Dm] before the residual, k, v [N,S,KV,D])."""
+    q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(cfg.dtype))
+    k = jnp.einsum("bsd,dhk->bshk", h, a["wk"].astype(cfg.dtype))
+    v = jnp.einsum("bsd,dhk->bshk", h, a["wv"].astype(cfg.dtype))
+    if cfg.rope_theta is not None:
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
-        rep = cfg.n_heads // cfg.n_kv_heads
-        kr = jnp.repeat(k, rep, axis=2)
-        vr = jnp.repeat(v, rep, axis=2)
-        s = x.shape[1]
+    rep = cfg.n_heads // cfg.n_kv_heads
+    kr = jnp.repeat(k, rep, axis=2)
+    vr = jnp.repeat(v, rep, axis=2)
+    s = h.shape[1]
+    if s > _SCORES_MAX_SEQ:
+        attn = _blockwise_attention(q, kr, vr)
+    else:
         mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
         scores = (jnp.einsum("bshk,bthk->bhst", q, kr)
                   / jnp.sqrt(cfg.head_dim))
         scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
         attn = jnp.einsum("bhst,bthk->bshk", probs, vr)
-        x = x + jnp.einsum("bshk,hkd->bsd", attn,
-                           a["wo"].astype(cfg.dtype))
-    with jax.named_scope("mlp"):
-        x = x + _mlp(p["MLP_0"], _rms(x, p["RMSNorm_1"]["scale"],
-                                      cfg.norm_eps), cfg.dtype)
-    return x, k, v
+    if cfg.attn_gate:
+        attn = attn * jax.nn.sigmoid(jnp.einsum(
+            "bsd,dhk->bshk", h, a["w_gate"].astype(cfg.dtype)))
+    return jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(cfg.dtype)), k, v
 
 
-def _decode_layer(p, cfg: TransformerConfig, x, positions, k_pages,
-                  v_pages, page_table, seq_lens):
-    """Single-token decode for one layer over [B,Dm] against the paged
-    cache; appends this token's K/V. seq_lens = cache length BEFORE the
-    token. Returns (x_out, k_pages, v_pages)."""
-    a = p["Attention_0"]
-    with jax.named_scope("attn"):
+def _delta_rule_inputs(a, cfg: DecoderConfig, h, mixed):
+    """What the recurrence takes, from the normed input h [..., d] and
+    the convolved, activated projections ``mixed`` [..., H, 2dk+dv]:
+    (q, k, v, g, beta), float32."""
+    dk = cfg.dr_key_dim
+    f32 = jnp.float32
+    q = kda.l2norm(mixed[..., :dk]) * dk ** -0.5
+    k = kda.l2norm(mixed[..., dk:2 * dk])
+    v = mixed[..., 2 * dk:].astype(f32)
+    low = jnp.einsum("...d,dr->...r", h, a["w_f_down"].astype(cfg.dtype))
+    step = jnp.einsum("...r,rhk->...hk", low,
+                      a["w_f_up"].astype(cfg.dtype)).astype(f32)
+    g = -jnp.exp(a["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+        step + a["dt_bias"].astype(f32))
+    beta = 2.0 * jax.nn.sigmoid(jnp.einsum(
+        "...d,dh->...h", h, a["w_beta"].astype(cfg.dtype)).astype(f32))
+    return q, k, v, g, beta
+
+
+def _delta_rule_output(a, cfg: DecoderConfig, h, o):
+    """o [..., H, dv] float32 -> the layer's output [..., d]: a norm a
+    head, the low-rank sigmoid gate, the output projection."""
+    f32 = jnp.float32
+    o = (o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                           + cfg.norm_eps) * a["o_norm"].astype(f32))
+    low = jnp.einsum("...d,dr->...r", h, a["w_g_down"].astype(cfg.dtype))
+    gate = jax.nn.sigmoid(jnp.einsum(
+        "...r,rhv->...hv", low, a["w_g_up"].astype(cfg.dtype)).astype(f32))
+    return jnp.einsum("...hv,hvd->...d", (o * gate).astype(cfg.dtype),
+                      a["wo"].astype(cfg.dtype))
+
+
+def _delta_rule_projections(a, cfg: DecoderConfig, h):
+    """(q|k|v projections side by side a head, flattened to channels
+    [..., H*(2dk+dv)]; the convolution's weights [K, channels])."""
+    qkv = jnp.concatenate(
+        [jnp.einsum("...d,dhk->...hk", h, a[w].astype(cfg.dtype))
+         for w in ("wq", "wk", "wv")], axis=-1)
+    w = jnp.concatenate([a["conv_q"], a["conv_k"], a["conv_v"]], axis=-1)
+    return (qkv.reshape(qkv.shape[:-2] + (-1,)),
+            w.reshape(w.shape[0], -1).astype(jnp.float32))
+
+
+# positions of a launch that a delta_rule layer works on at a time: its
+# float32 intermediates (24,576 channels a position at the published
+# widths) are held for one segment, not for the bucket
+_DELTA_RULE_SEGMENT = 2048
+
+
+def _prefill_delta_rule(a, cfg: DecoderConfig, h, plens):
+    """The recurrent mixer over a bucket: h [N,S,Dm] (normed), plens
+    [N] valid positions a row -> (out [N,S,Dm], state [N,H,dk,dv]
+    float32 after position plens-1, tail [N,K-1,channels] of
+    projections before position plens). Positions past a row's length
+    leave its state alone. The bucket goes a segment of positions at a
+    time, state and convolution tail carried from one to the next."""
+    n, s, d = h.shape
+    taps = cfg.dr_conv
+    seg = min(s, max(64, _DELTA_RULE_SEGMENT // n))
+    pad = (-s) % seg
+    hp = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+    segments = jnp.moveaxis(hp.reshape(n, -1, seg, d), 1, 0)
+
+    def segment(carry, xs):
+        state, tail = carry
+        hs, start = xs
+        flat, w = _delta_rule_projections(a, cfg, hs)
+        mixed = jax.nn.silu(kda.short_conv(flat.astype(jnp.float32), w,
+                                           tail))
+        q, k, v, g, beta = _delta_rule_inputs(
+            a, cfg, hs, mixed.reshape(n, seg, cfg.dr_heads,
+                                      cfg.dr_channels))
+        g, beta = kda.pad_mask(g, beta, plens - start)
+        o, state = kda.kda_chunked(q, k, v, g, beta, state)
+        return ((state, flat[:, seg - (taps - 1):]),
+                _delta_rule_output(a, cfg, hs, o))
+
+    with jax.named_scope("kda"):
+        zero = (jnp.zeros((n, cfg.dr_heads, cfg.dr_key_dim,
+                           cfg.dr_value_dim), jnp.float32),
+                jnp.zeros((n, taps - 1, cfg.dr_heads * cfg.dr_channels),
+                          cfg.dtype))
+        (state, _), out = jax.lax.scan(
+            segment, zero,
+            (segments, jnp.arange(segments.shape[0]) * seg))
+        out = jnp.moveaxis(out, 0, 1).reshape(n, s + pad, d)[:, :s]
+        # what the convolution of position plens needs: the
+        # projections of the row's last K-1 inputs
+        tail, _ = _delta_rule_projections(
+            a, cfg, kda.conv_tail(h, plens, taps))
+        return out, state, tail
+
+
+def _decode_delta_rule(a, cfg: DecoderConfig, h, state, tail, live):
+    """One position a slot: h [B,Dm] (normed). Slots that are not
+    ``live`` keep their state and tail as they are."""
+    with jax.named_scope("kda"):
+        flat, w = _delta_rule_projections(a, cfg, h)
+        mixed, new_tail = kda.short_conv_step(
+            flat.astype(jnp.float32), w, tail)
+        q, k, v, g, beta = _delta_rule_inputs(
+            a, cfg, h, jax.nn.silu(mixed).reshape(
+                h.shape[0], cfg.dr_heads, cfg.dr_channels))
+        o, new_state = kda.kda_step(q, k, v, g, beta, state)
+        out = _delta_rule_output(a, cfg, h, o)
+    with jax.named_scope("kda_state"):
+        state = jnp.where(live[:, None, None, None], new_state, state)
+        tail = jnp.where(live[:, None, None], new_tail.astype(tail.dtype),
+                         tail)
+    return out, state, tail
+
+
+def _prefill_layer(p, cfg: DecoderConfig, spec: LayerSpec, x, positions,
+                   plens, valid):
+    """One layer over a bucket [N,S,Dm]. Returns (x_out, what the layer
+    keeps for decoding: (k, v) [N,S,KV,D] or (state, tail); picks a
+    held expert or None)."""
+    if spec.mixer == "attention":
+        with jax.named_scope("gqa" if cfg.attn_gate else "attn"):
+            h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
+            out, k, v = _prefill_attention(p["Attention_0"], cfg, h,
+                                           positions)
+            x = x + out
+        kept = (k, v)
+    else:
         h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-        q = jnp.einsum("bd,dhk->bhk", h, a["wq"].astype(cfg.dtype))
-        k = jnp.einsum("bd,dhk->bhk", h, a["wk"].astype(cfg.dtype))
-        v = jnp.einsum("bd,dhk->bhk", h, a["wv"].astype(cfg.dtype))
-        # rope over a length-1 "sequence" per slot
-        q = _rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
-        k = _rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
-    with jax.named_scope("kv_append"):
-        k_pages, v_pages = append_token_kv(k_pages, v_pages, k, v,
-                                           page_table, seq_lens)
-    with jax.named_scope("attn"):
-        out = paged_attention_auto(q, k_pages, v_pages, page_table,
-                                   seq_lens + 1)
-        x = x + jnp.einsum("bhk,hkd->bd", out.astype(cfg.dtype),
-                           a["wo"].astype(cfg.dtype))
-    with jax.named_scope("mlp"):
-        x = x + _mlp(p["MLP_0"], _rms(x, p["RMSNorm_1"]["scale"],
-                                      cfg.norm_eps), cfg.dtype)
-    return x, k_pages, v_pages
+        out, state, tail = _prefill_delta_rule(p["DeltaRule_0"], cfg, h,
+                                               plens)
+        x = x + out
+        kept = (state, tail)
+    x, counts = _feed_forward(p, cfg, spec, x, valid)
+    return x, kept, counts
 
 
-def prefill_batch(params: Dict[str, Any], cfg: TransformerConfig,
-                  tokens: jnp.ndarray):
-    """tokens [N,S] (padded to a bucket) -> (logits [N,S,V] f32,
-    k_seq/v_seq [L,N,S,KV,D]) — N prompts prefill in one program."""
-    embed = params["embedding"]
-    with jax.named_scope("embed"):
-        x = embed.astype(cfg.dtype)[tokens]
-    s = tokens.shape[1]
-    positions = jnp.arange(s)[None, :]
-    ks, vs = [], []
-    for i in range(cfg.n_layers):
-        x, k, v = _prefill_layer(params[f"layer_{i}"], cfg, x, positions)
-        ks.append(k)
-        vs.append(v)
+def _decode_layer(p, cfg: DecoderConfig, spec: LayerSpec, x, positions,
+                  kept, page_table, seq_lens, live):
+    """Single-token decode for one layer over [B,Dm]. ``kept`` is the
+    layer's (k_pages, v_pages), to which this token's K/V are appended
+    (seq_lens = cache length BEFORE the token), or its (state, tail).
+    Returns (x_out, kept, picks a held expert or None)."""
+    if spec.mixer == "attention":
+        a = p["Attention_0"]
+        k_pages, v_pages = kept
+        scope = "gqa" if cfg.attn_gate else "attn"
+        with jax.named_scope(scope):
+            h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
+            q = jnp.einsum("bd,dhk->bhk", h, a["wq"].astype(cfg.dtype))
+            k = jnp.einsum("bd,dhk->bhk", h, a["wk"].astype(cfg.dtype))
+            v = jnp.einsum("bd,dhk->bhk", h, a["wv"].astype(cfg.dtype))
+            if cfg.rope_theta is not None:
+                # rope over a length-1 "sequence" per slot
+                q = _rope(q[:, None], positions[:, None],
+                          cfg.rope_theta)[:, 0]
+                k = _rope(k[:, None], positions[:, None],
+                          cfg.rope_theta)[:, 0]
+        with jax.named_scope("kv_append"):
+            k_pages, v_pages = append_token_kv(k_pages, v_pages, k, v,
+                                               page_table, seq_lens)
+        with jax.named_scope(scope):
+            out = paged_attention_auto(q, k_pages, v_pages, page_table,
+                                       seq_lens + 1)
+            if cfg.attn_gate:
+                out = out * jax.nn.sigmoid(jnp.einsum(
+                    "bd,dhk->bhk", h, a["w_gate"].astype(cfg.dtype)))
+            x = x + jnp.einsum("bhk,hkd->bd", out.astype(cfg.dtype),
+                               a["wo"].astype(cfg.dtype))
+        kept = (k_pages, v_pages)
+    else:
+        h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
+        out, state, tail = _decode_delta_rule(p["DeltaRule_0"], cfg, h,
+                                              *kept, live)
+        x = x + out
+        kept = (state, tail)
+    valid = live if live is not None else jnp.ones(x.shape[:1], bool)
+    x, counts = _feed_forward(p, cfg, spec, x, valid)
+    return x, kept, counts
+
+
+def _head(params, cfg: DecoderConfig, x, spec: str):
+    """Final norm and output head over x [..., d]; ``spec`` is the
+    einsum of hidden and head matrix [V, d]."""
     with jax.named_scope("head"):
+        table = params["embedding" if cfg.tie_embeddings else "lm_head"]
         x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(cfg.dtype))
-        logits = logits.astype(jnp.float32)
-    return (logits, jnp.stack(ks), jnp.stack(vs))
+        logits = jnp.einsum(spec, x, table.astype(cfg.dtype))
+        return logits.astype(jnp.float32)
 
 
-def prefill(params: Dict[str, Any], cfg: TransformerConfig,
-            tokens: jnp.ndarray):
+def _sum_counts(counts):
+    counts = [c for c in counts if c is not None]
+    return sum(counts[1:], counts[0]) if counts else None
+
+
+def _prefill_hidden(params, cfg: DecoderConfig, tokens, plens=None,
+                    rows=None):
+    """tokens [N,S] (padded to a bucket) -> (hidden [N,S,Dm] before the
+    final norm; what each layer keeps, a list by layer; picks a held
+    expert summed over the layers, or None). ``plens`` [N] are the
+    rows' valid lengths (the whole bucket when None) and ``rows`` [N]
+    marks the rows that are requests: what lies past a length or in a
+    dummy row neither touches a state nor counts as a pick."""
+    n, s = tokens.shape
+    with jax.named_scope("embed"):
+        x = params["embedding"].astype(cfg.dtype)[tokens]
+    positions = jnp.arange(s)[None, :]
+    if plens is None:
+        plens = jnp.full((n,), s, jnp.int32)
+    valid = positions < plens[:, None]
+    if rows is not None:
+        valid = valid & rows[:, None]
+    kept, counts = [], []
+    for i, spec in enumerate(cfg.layers):
+        x, keep, c = _prefill_layer(params[f"layer_{i}"], cfg, spec, x,
+                                    positions, plens, valid)
+        kept.append(keep)
+        counts.append(c)
+    return x, kept, _sum_counts(counts)
+
+
+def prefill_batch(params: Dict[str, Any], cfg, tokens: jnp.ndarray):
+    """tokens [N,S] (padded to a bucket) -> (logits [N,S,V] f32,
+    k_seq/v_seq [L,N,S,KV,D]) — N prompts prefill in one program. For
+    models whose every mixer is attention (L counts their layers): one
+    with recurrent state has more to hand on than keys and values, and
+    goes through the engine."""
+    cfg = describe(cfg)
+    if cfg.state_layers:
+        raise ValueError("prefill_batch hands on keys and values only; "
+                         "this model keeps recurrent state too")
+    x, kept, _ = _prefill_hidden(params, cfg, tokens)
+    logits = _head(params, cfg, x, "bsd,vd->bsv")
+    return (logits, jnp.stack([k for k, _ in kept]),
+            jnp.stack([v for _, v in kept]))
+
+
+def prefill(params: Dict[str, Any], cfg, tokens: jnp.ndarray):
     """tokens [1,S] (padded to a bucket) -> (logits [S,V] f32,
     k_seq/v_seq [L,S,KV,D])."""
     logits, ks, vs = prefill_batch(params, cfg, tokens)
     return logits[0], ks[:, 0], vs[:, 0]
 
 
-def decode_step(params: Dict[str, Any], cfg: TransformerConfig,
-                tokens: jnp.ndarray, k_pages: jnp.ndarray,
-                v_pages: jnp.ndarray, page_table: jnp.ndarray,
-                seq_lens: jnp.ndarray):
+def _decode_step(params, cfg: DecoderConfig, tokens, k_pages, v_pages,
+                 page_table, seq_lens, state, live):
+    """``decode_step`` for any description: ``k_pages``/``v_pages`` are
+    tuples over the attention layers, ``state`` a tuple of (state, tail)
+    over the delta_rule layers, ``live`` [B] bool (None: every slot).
+    Returns (logits, k_pages, v_pages, state, picks a held expert)."""
+    with jax.named_scope("embed"):
+        x = params["embedding"].astype(cfg.dtype)[tokens]      # [B, Dm]
+    positions = seq_lens                          # this token's position
+    pools = iter(zip(k_pages, v_pages))
+    states = iter(state)
+    new_k, new_v, new_state, counts = [], [], [], []
+    for i, spec in enumerate(cfg.layers):
+        attention = spec.mixer == "attention"
+        x, kept, c = _decode_layer(
+            params[f"layer_{i}"], cfg, spec, x, positions,
+            next(pools) if attention else next(states), page_table,
+            seq_lens, live)
+        if attention:
+            new_k.append(kept[0])
+            new_v.append(kept[1])
+        else:
+            new_state.append(kept)
+        counts.append(c)
+    logits = _head(params, cfg, x, "bd,vd->bv")
+    return (logits, tuple(new_k), tuple(new_v), tuple(new_state),
+            _sum_counts(counts))
+
+
+def decode_step(params: Dict[str, Any], cfg, tokens: jnp.ndarray,
+                k_pages: jnp.ndarray, v_pages: jnp.ndarray,
+                page_table: jnp.ndarray, seq_lens: jnp.ndarray):
     """One continuous-batching step: tokens [B] int32 (last emitted or
     last prompt token per slot), cache = per-layer TUPLES of
     [P,KV,page,D] arrays (a pytree, never re-stacked: each layer's
     scatter update aliases its own buffer in place under jit/scan —
     stacking into one [L,...] array would copy the whole cache every
-    step). Returns (next_logits [B,V] f32, k_pages, v_pages)."""
-    embed = params["embedding"]
-    with jax.named_scope("embed"):
-        x = embed.astype(cfg.dtype)[tokens]      # [B, Dm]
-    positions = seq_lens                          # this token's position
-    new_k, new_v = [], []
-    for i in range(cfg.n_layers):
-        x, kp, vp = _decode_layer(params[f"layer_{i}"], cfg, x, positions,
-                                  k_pages[i], v_pages[i], page_table,
-                                  seq_lens)
-        new_k.append(kp)
-        new_v.append(vp)
-    with jax.named_scope("head"):
-        x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps)
-        logits = jnp.einsum("bd,vd->bv", x, embed.astype(cfg.dtype))
-        logits = logits.astype(jnp.float32)
-    return (logits, tuple(new_k), tuple(new_v))
+    step). Returns (next_logits [B,V] f32, k_pages, v_pages). Models
+    whose every mixer is attention."""
+    cfg = describe(cfg)
+    if cfg.state_layers:
+        raise ValueError("decode_step carries keys and values only; "
+                         "this model keeps recurrent state too")
+    return _decode_step(params, cfg, tokens, k_pages, v_pages, page_table,
+                        seq_lens, (), None)[:3]
 
 
-def decode_chunk(params: Dict[str, Any], cfg: TransformerConfig,
-                 tokens: jnp.ndarray, k_pages: jnp.ndarray,
-                 v_pages: jnp.ndarray, page_table: jnp.ndarray,
-                 seq_lens: jnp.ndarray, *, n_steps: int):
+def _decode_chunk(params, cfg: DecoderConfig, tokens, k_pages, v_pages,
+                  page_table, seq_lens, state, live, *, n_steps: int):
+    """``decode_chunk`` for any description. Returns (tokens [n_steps,
+    B], next_tokens, next_lens, k_pages, v_pages, state, picks a held
+    expert over the chunk's steps or None); for the dense decoder the
+    last two are empty and the program is ``decode_chunk``'s."""
+    def body(carry, _):
+        toks, kp, vp, lens, st, total = carry
+        logits, kp, vp, st, counts = _decode_step(
+            params, cfg, toks, kp, vp, page_table, lens, st, live)
+        with jax.named_scope("head"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if counts is not None:
+            total = total + counts
+        return (nxt, kp, vp, lens + 1, st, total), nxt
+
+    total = (jnp.zeros((cfg.n_experts_held,), jnp.int32)
+             if cfg.moe_layers else None)
+    carry, outs = jax.lax.scan(
+        body, (tokens, k_pages, v_pages, seq_lens, state, total), None,
+        length=n_steps)
+    toks, k_out, v_out, lens, state, total = carry
+    return outs, toks, lens, k_out, v_out, state, total
+
+
+def decode_chunk(params: Dict[str, Any], cfg, tokens: jnp.ndarray,
+                 k_pages: jnp.ndarray, v_pages: jnp.ndarray,
+                 page_table: jnp.ndarray, seq_lens: jnp.ndarray, *,
+                 n_steps: int):
     """n_steps greedy decode steps in ONE jitted program (lax.scan with
     argmax feedback). Returns (tokens [n_steps, B] int32, next_tokens
     [B], next_lens [B], k_pages, v_pages): the feedback state comes
     back as DEVICE arrays so the engine can chain chunks without a
     host round trip: chunks pipeline asynchronously and the host syncs
-    only when a burst ends."""
-    def body(carry, _):
-        toks, kp, vp, lens = carry
-        logits, kp, vp = decode_step(params, cfg, toks, kp, vp,
-                                     page_table, lens)
-        with jax.named_scope("head"):
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (nxt, kp, vp, lens + 1), nxt
-
-    carry, outs = jax.lax.scan(body,
-                               (tokens, k_pages, v_pages, seq_lens),
-                               None, length=n_steps)
-    toks, k_out, v_out, lens = carry
-    return outs, toks, lens, k_out, v_out
+    only when a burst ends. Models whose every mixer is attention."""
+    cfg = describe(cfg)
+    if cfg.state_layers:
+        raise ValueError("decode_chunk carries keys and values only; "
+                         "this model keeps recurrent state too")
+    return _decode_chunk(params, cfg, tokens, k_pages, v_pages, page_table,
+                         seq_lens, (), None, n_steps=n_steps)[:5]
 
 
 # ----------------------------------------------------------------------
@@ -318,7 +612,7 @@ class InferenceEngine:
       paying an un-provisioned prefill.
     """
 
-    def __init__(self, params: Dict[str, Any], model_cfg: TransformerConfig,
+    def __init__(self, params: Dict[str, Any], model_cfg: Any,
                  cfg: InferenceConfig = InferenceConfig(),
                  mode: str = "both"):
         if mode not in ("both", "prefill", "decode"):
@@ -326,11 +620,24 @@ class InferenceEngine:
         if "params" in params and "embedding" not in params:
             params = params["params"]
         self.params = params
-        self.mcfg = model_cfg
+        # a TransformerConfig or a DecoderConfig; the engine reads the
+        # layer-by-layer description either way
+        self.mcfg = model_cfg = describe(model_cfg)
+        if model_cfg.state_layers and mode != "both":
+            raise ValueError(
+                f"engine mode {mode!r} hands keys and values from a "
+                f"prefill replica to a decode replica; this model keeps "
+                f"recurrent state in layers {model_cfg.state_layers}, "
+                f"which the handoff does not carry: serve it in mode "
+                f"'both'")
         self.cfg = cfg
         self.mode = mode
-        L = model_cfg.n_layers
+        L = len(model_cfg.kv_layers)       # layers with a page pool
         KV, D = model_cfg.n_kv_heads, model_cfg.head_dim
+        # programs of a model with recurrent state or experts take the
+        # state and the live-slot mask as well, and hand back the picks
+        # a held expert; the dense decoder's programs are as they were
+        self._extras = bool(model_cfg.state_layers or model_cfg.moe_layers)
         self._idents = itertools.count()
         # cumulative counts behind stats(): decode steps dispatched,
         # decode tokens that stayed in a request's output, bursts, and
@@ -339,6 +646,11 @@ class InferenceEngine:
         self._decode_tokens_kept = 0
         self._bursts = 0
         self._prefill_counts: Dict[int, List[int]] = {}
+        # picks of routed experts by the tokens that counted (prompt
+        # tokens, live slots' decode steps): all, and by held expert
+        self._moe_picks_total = 0
+        self._moe_load = np.zeros(model_cfg.n_experts_held, np.int64)
+        self._state: Tuple[Tuple[Any, Any], ...] = ()
         # single-prompt bucketed prompt pass for prefill_export;
         # compiles lazily per bucket on first use
         mcfg = self.mcfg
@@ -369,6 +681,17 @@ class InferenceEngine:
         # the LAST physical page is the parking page for idle decode
         # slots (their dummy K/V appends land there), never allocated
         self._free_pages: List[int] = list(range(cfg.num_pages - 1))
+        # a recurrent layer keeps, for each slot, its state in float32
+        # and the last inputs of its short convolution; a prefill
+        # launch overwrites a slot's, a decode step updates live slots'
+        self._state = tuple(
+            (jnp.zeros((cfg.batch_size, model_cfg.dr_heads,
+                        model_cfg.dr_key_dim, model_cfg.dr_value_dim),
+                       jnp.float32),
+             jnp.zeros((cfg.batch_size, model_cfg.dr_conv - 1,
+                        model_cfg.dr_heads * model_cfg.dr_channels),
+                       model_cfg.dtype))
+            for _ in model_cfg.state_layers)
         self._slots = [_Slot() for _ in range(cfg.batch_size)]
         self._queue: "queue.Queue[_Request]" = queue.Queue()
         self._lock = threading.Lock()
@@ -393,18 +716,25 @@ class InferenceEngine:
         for steps in self._chunk_sizes:
             self._decode_chunks[steps] = _program(
                 f"engine_decode_n{steps}",
-                lambda p, toks, kp, vp, table, lens, _n=steps:
-                decode_chunk(p, mcfg, toks, kp, vp, table, lens,
-                             n_steps=_n),
-                donate_argnums=(2, 3))
+                (lambda p, toks, kp, vp, table, lens, state, live, _n=steps:
+                 _decode_chunk(p, mcfg, toks, kp, vp, table, lens, state,
+                               live, n_steps=_n)) if self._extras else
+                (lambda p, toks, kp, vp, table, lens, _n=steps:
+                 _decode_chunk(p, mcfg, toks, kp, vp, table, lens, (),
+                               None, n_steps=_n)),
+                donate_argnums=(2, 3, 6) if self._extras else (2, 3))
         # burst state rides ONE packed upload [B, 1 + max_pages]
         # (column 0 = seq_lens, rest = page table — one transfer
         # instead of two); lens then EVOLVES
         # on device across the burst's chained chunks while the table
         # stays fixed
+        # (a slot is live when its length is not 0: a prompt has at
+        # least one token)
         self._split_packed = _program(
             "engine_split_packed",
-            lambda packed: (packed[:, 1:], packed[:, 0]))
+            (lambda packed: (packed[:, 1:], packed[:, 0],
+                             packed[:, 0] > 0)) if self._extras else
+            (lambda packed: (packed[:, 1:], packed[:, 0])))
 
         # BATCHED prefill: N admissions in one program behind ONE packed
         # upload. packed [N, 2 + bucket + n_prog] int32 rows of
@@ -423,7 +753,7 @@ class InferenceEngine:
             new_k, new_v = list(kp), list(vp)
             n = packed.shape[0]
             with jax.named_scope("kv_append"):
-                for i in range(mcfg.n_layers):
+                for i in range(len(new_k)):
                     ki, vi = new_k[i], new_v[i]
                     for r in range(n):
                         ki, vi = write_prefill_kv(ki, vi, k_seq[i, r],
@@ -435,6 +765,41 @@ class InferenceEngine:
                 toks_vec = toks_vec.at[slots].set(nxt)
             return nxt, toks_vec, tuple(new_k), tuple(new_v)
 
+        def prefill_write_extras(p, packed, kp, vp, toks_vec, state,
+                                 bucket):
+            """``prefill_write_many`` for a model with recurrent state
+            or experts: a row's final state and convolution tail go to
+            its slot whole (nothing of the slot's previous tenant
+            survives), only the row's last position goes through the
+            head, and the picks a held expert ride behind the first
+            tokens."""
+            n_prog = -(-bucket // cfg.page_size)
+            slots = packed[:, 0]
+            plens = packed[:, 1]
+            toks = packed[:, 2:2 + bucket]
+            pages = packed[:, 2 + bucket:2 + bucket + n_prog]
+            n = packed.shape[0]
+            x, kept, counts = _prefill_hidden(
+                p, mcfg, toks, plens, slots < cfg.batch_size)
+            new_k, new_v, new_state = list(kp), list(vp), []
+            with jax.named_scope("kv_append"):
+                for j, i in enumerate(mcfg.kv_layers):
+                    for r in range(n):
+                        new_k[j], new_v[j] = write_prefill_kv(
+                            new_k[j], new_v[j], kept[i][0][r],
+                            kept[i][1][r], pages[r])
+            with jax.named_scope("kda_state"):
+                for (st, tail), i in zip(state, mcfg.state_layers):
+                    new_state.append((st.at[slots].set(kept[i][0]),
+                                      tail.at[slots].set(kept[i][1])))
+            logits = _head(p, mcfg, x[jnp.arange(n), plens - 1], "bd,vd->bv")
+            with jax.named_scope("head"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                toks_vec = toks_vec.at[slots].set(nxt)
+            first = nxt if counts is None else jnp.concatenate([nxt, counts])
+            return (first, toks_vec, tuple(new_k), tuple(new_v),
+                    tuple(new_state))
+
         # every launch computes one budget of positions, that of a
         # single prompt in the largest bucket: a bucket b runs
         # largest // b rows (at most batch_size, at least 1)
@@ -445,9 +810,12 @@ class InferenceEngine:
         self._prefill_many = ({} if mode == "decode" else {
             b: _program(
                 f"engine_prefill_b{b}",
-                lambda p, packed, kp, vp, toks_vec, _b=b:
-                prefill_write_many(p, packed, kp, vp, toks_vec, _b),
-                donate_argnums=(2, 3, 4))
+                (lambda p, packed, kp, vp, toks_vec, state, _b=b:
+                 prefill_write_extras(p, packed, kp, vp, toks_vec, state,
+                                      _b)) if self._extras else
+                (lambda p, packed, kp, vp, toks_vec, _b=b:
+                 prefill_write_many(p, packed, kp, vp, toks_vec, _b)),
+                donate_argnums=(2, 3, 4, 5) if self._extras else (2, 3, 4))
             for b in cfg.prefill_buckets
         })
 
@@ -461,7 +829,7 @@ class InferenceEngine:
                           slot_first):
             new_k, new_v = list(kp), list(vp)
             with jax.named_scope("kv_append"):
-                for i in range(mcfg.n_layers):
+                for i in range(len(new_k)):
                     new_k[i], new_v[i] = write_prefill_kv(
                         new_k[i], new_v[i], k_seq[i], v_seq[i], pages)
             toks_vec = toks_vec.at[slot_first[0]].set(slot_first[1])
@@ -475,8 +843,11 @@ class InferenceEngine:
         # stalls the dispatch pipeline; a dispatch does not)
         self._dev_toks = jnp.zeros(cfg.batch_size, jnp.int32)
         # prefill next-tokens awaiting the next burst's combined fetch:
-        # (device array [N], [(slot, row)])
-        self._pending_firsts: List[Tuple[Any, List[Tuple[_Slot, int]]]] = []
+        # (device array [N], or [N + E_held] with the launch's picks a
+        # held expert behind them; [(slot, row)]; the launch's span
+        # fields, which the picks complete)
+        self._pending_firsts: List[Tuple[Any, List[Tuple[_Slot, int]],
+                                         dict]] = []
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="ray_tpu_llm_engine")
         self._thread.start()
@@ -505,6 +876,11 @@ class InferenceEngine:
             raise RuntimeError(
                 f"engine is in {self.mode!r} mode; this entry point "
                 f"needs {wants!r}")
+        if self.mcfg.state_layers:
+            raise RuntimeError(
+                "the KV handoff carries keys and values only; this model "
+                "keeps recurrent state too and is served whole (submit, "
+                "submit_stream)")
 
     def submit(self, prompt: Sequence[int],
                max_new_tokens: Optional[int] = None) -> Future:
@@ -612,7 +988,14 @@ class InferenceEngine:
         (rows x bucket), run for the ``prefill_useful_rows`` requests
         admitted in them and their ``prefill_prompt_tokens``;
         ``prefill_by_bucket`` has the same five by bucket (useful rows
-        over rows is a bucket's fill share)."""
+        over rows is a bucket's fill share). Of a model with experts,
+        ``moe_picks_total`` counts the picks of routed experts by the
+        tokens that counted (every prompt token, every decode step of a
+        live slot; ``experts_per_token`` a token and expert layer),
+        ``moe_picks_local`` those that fell on an expert held here and
+        were computed, ``moe_load_by_expert`` the same by held expert.
+        ``state_bytes`` is what the recurrent layers keep for all slots
+        and ``pool_tokens`` the tokens the page pool can hold."""
         with self._lock:
             by_bucket = {
                 b: {"launches": n, "rows": rows, "useful_rows": useful,
@@ -637,6 +1020,14 @@ class InferenceEngine:
                 "decode_tokens_kept": self._decode_tokens_kept,
                 **prefill,
                 "prefill_by_bucket": by_bucket,
+                "moe_picks_total": self._moe_picks_total,
+                "moe_picks_local": int(self._moe_load.sum()),
+                "moe_load_by_expert": self._moe_load.tolist(),
+                "state_bytes": sum(a.nbytes for pair in self._state
+                                   for a in pair),
+                "pool_tokens": (max(0, self.cfg.num_pages - 1)
+                                * self.cfg.page_size
+                                if self.mode != "prefill" else 0),
             }
 
     def shutdown(self) -> None:
@@ -762,12 +1153,20 @@ class InferenceEngine:
             counts[3] += prompt_tokens
         with spans.span("engine.prefill_launch", bucket=bucket, rows=n,
                         useful_rows=len(group),
-                        prompt_tokens=prompt_tokens):
-            nxt, self._dev_toks, self._k_pages, self._v_pages = \
-                self._prefill_many[bucket](
-                    self.params, jnp.asarray(packed), self._k_pages,
-                    self._v_pages, self._dev_toks)
-        self._pending_firsts.append((nxt, rows))
+                        prompt_tokens=prompt_tokens) as launch:
+            launch.fields["prompt_lens"] = [len(req.prompt)
+                                            for _, req, _ in group]
+            if self._extras:
+                (nxt, self._dev_toks, self._k_pages, self._v_pages,
+                 self._state) = self._prefill_many[bucket](
+                     self.params, jnp.asarray(packed), self._k_pages,
+                     self._v_pages, self._dev_toks, self._state)
+            else:
+                nxt, self._dev_toks, self._k_pages, self._v_pages = \
+                    self._prefill_many[bucket](
+                        self.params, jnp.asarray(packed), self._k_pages,
+                        self._v_pages, self._dev_toks)
+        self._pending_firsts.append((nxt, rows, launch.fields))
 
     def _import_group(self, slot: _Slot, req: _Request,
                       pages: List[int]) -> None:
@@ -781,7 +1180,7 @@ class InferenceEngine:
         bucket = next(b for b in sorted(self.cfg.prefill_buckets)
                       if b >= plen)
         n_prog = -(-bucket // self.cfg.page_size)
-        L = self.mcfg.n_layers
+        L = len(self.mcfg.kv_layers)
         KV, D = self.mcfg.n_kv_heads, self.mcfg.head_dim
         k_pad = np.zeros((L, bucket, KV, D), k.dtype)
         v_pad = np.zeros((L, bucket, KV, D), v.dtype)
@@ -870,15 +1269,20 @@ class InferenceEngine:
         with spans.span("engine.dispatch", live_slots=len(active),
                         live_ctx_tokens=sum(s.seq_len for s in active)
                         ) as burst:
+            if self._state:
+                burst.fields["state_slots_live"] = len(active)
             pending = self._dispatch_burst(active)
-            steps = sum(chunk for _, chunk in pending)
+            steps = sum(chunk for _, chunk, _ in pending)
             burst.fields.update(steps=steps, chunks=len(pending))
 
         # ONE fetch per burst: chunk outputs + any pending prefill
-        # first-tokens, concatenated on device, read together
+        # first-tokens (+ the picks a held expert, of the launches and
+        # of the chunks), concatenated on device, read together
         firsts, self._pending_firsts = self._pending_firsts, []
-        parts = [outs.reshape(-1) for outs, _ in pending]
-        parts.extend(arr for arr, _rows in firsts)
+        parts = [outs.reshape(-1) for outs, _, _ in pending]
+        parts.extend(arr for arr, _rows, _fields in firsts)
+        parts.extend(counts for _, _, counts in pending
+                     if counts is not None)
         if not parts:
             return
         with spans.span("engine.fetch"):
@@ -887,15 +1291,37 @@ class InferenceEngine:
         with spans.span("engine.deliver") as deliver:
             kept = self._deliver(active, pending, firsts, flat)
             deliver.fields["kept_tokens"] = kept
+            if self.mcfg.moe_layers:
+                deliver.fields.update(self._count_picks(
+                    flat[len(flat) - len(pending) * len(self._moe_load):],
+                    len(active) * steps))
         with self._lock:      # a burst's counts land together
             self._bursts += 1
             self._decode_steps += steps
             self._decode_tokens_kept += kept
 
-    def _dispatch_burst(self, active: List[_Slot]) -> List[Tuple[Any, int]]:
+    def _count_picks(self, counts: np.ndarray, tokens: int
+                     ) -> Dict[str, int]:
+        """Add fetched picks a held expert (any number of [E_held]
+        rows, flat) and the ``tokens`` they were counted over to the
+        cumulative counters; returns the span fields that say the
+        same of this launch or burst."""
+        load = counts.reshape(-1, len(self._moe_load)).sum(axis=0)
+        total = (tokens * self.mcfg.experts_per_token
+                 * len(self.mcfg.moe_layers))
+        with self._lock:
+            self._moe_load += load
+            self._moe_picks_total += total
+        return {"moe_picks_total": total,
+                "moe_picks_local": int(load.sum()),
+                "moe_expert_load_max": int(load.max(initial=0)),
+                "moe_load_by_expert": load.tolist()}
+
+    def _dispatch_burst(self, active: List[_Slot]
+                        ) -> List[Tuple[Any, int, Any]]:
         """Upload the burst's lens + page table and dispatch its decode
         chunks back-to-back; returns [(chunk's tokens on the device,
-        its steps)]."""
+        its steps, its picks a held expert on the device or None)]."""
         # ONE packed upload per burst carries lens + page table
         # (host bookkeeping is authoritative for both); the TOKEN
         # feedback vector lives on device across bursts — prefill
@@ -915,7 +1341,8 @@ class InferenceEngine:
                 for j, p in enumerate(s.pages):
                     packed[i, 1 + j] = p
         dev_toks = self._dev_toks
-        dev_table, dev_lens = self._split_packed(jnp.asarray(packed))
+        dev_table, dev_lens, *dev_live = self._split_packed(
+            jnp.asarray(packed))
 
         # async burst: dispatch chunks back-to-back WITHOUT reading
         # results (jax dispatch is async).
@@ -923,7 +1350,7 @@ class InferenceEngine:
         # combined fetch — or per-chunk when EOS detection is
         # configured (early exit needs the values).
         inflight = 0
-        pending: List[Tuple[Any, int]] = []
+        pending: List[Tuple[Any, int, Any]] = []
         while True:
             remaining = min(s.req.max_new - s.req.emitted
                             for s in active) - inflight
@@ -938,12 +1365,13 @@ class InferenceEngine:
                         if c >= remaining]
             chunk = (min(covering) if covering
                      else self._chunk_sizes[-1])
-            (outs, dev_toks, dev_lens, self._k_pages,
-             self._v_pages) = self._decode_chunks[chunk](
+            extras = (self._state, *dev_live) if self._extras else ()
+            (outs, dev_toks, dev_lens, self._k_pages, self._v_pages,
+             self._state, counts) = self._decode_chunks[chunk](
                  self.params, dev_toks, self._k_pages, self._v_pages,
-                 dev_table, dev_lens)
+                 dev_table, dev_lens, *extras)
             self.num_steps += 1
-            pending.append((outs, chunk))
+            pending.append((outs, chunk, counts))
             inflight += chunk
             for s in active:
                 s.seq_len += chunk
@@ -952,23 +1380,28 @@ class InferenceEngine:
         self._dev_toks = dev_toks
         return pending
 
-    def _deliver(self, active: List[_Slot], pending: List[Tuple[Any, int]],
-                 firsts: List[Tuple[Any, List[Tuple[_Slot, int]]]],
+    def _deliver(self, active: List[_Slot],
+                 pending: List[Tuple[Any, int, Any]],
+                 firsts: List[Tuple[Any, List[Tuple[_Slot, int]], dict]],
                  flat: np.ndarray) -> int:
         """Distribute a burst's fetched tokens to its requests, finish
         those that are done and feed the streams. Returns how many of
         the burst's decode tokens stayed in a request's output."""
         # first-tokens sit after this burst's chunk rows
-        off = sum(c * self.cfg.batch_size for _, c in pending)
-        for arr, rows in firsts:
+        off = sum(c * self.cfg.batch_size for _, c, _ in pending)
+        held = len(self._moe_load) if self.mcfg.moe_layers else 0
+        for arr, rows, launch in firsts:
             for slot, r in rows:
                 if slot.req is not None:
                     slot.req.out.insert(0, int(flat[off + r]))
             off += len(arr)
+            if held:      # the launch's picks ride behind its tokens
+                launch.update(self._count_picks(
+                    flat[off - held:off], launch["prompt_tokens"]))
         live = {id(s) for s in active}
         kept = -sum(len(s.req.out) for s in active if s.req is not None)
         pos = 0
-        for outs, chunk in pending:
+        for _outs, chunk, _counts in pending:
             arr = flat[pos:pos + chunk * self.cfg.batch_size].reshape(
                 chunk, self.cfg.batch_size)
             pos += chunk * self.cfg.batch_size

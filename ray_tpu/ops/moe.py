@@ -12,12 +12,21 @@ Layout inside shard_map over ("expert",):
   experts  [E_local, ...]    (expert weights sharded over `expert`)
   dispatch [E_total, C, D]   per device -> all_to_all -> each device
            holds its E_local experts' slices from every peer.
+
+Beside that training layer stands the expert layer as deployed models
+have it (``route_topk``, ``experts_held``): sigmoid scores, the top-k
+of ALL experts with weights normalised over the picked, gated (SwiGLU)
+experts, no capacity and no dropped token. A chip is told which experts
+it holds (a range of ids) and computes the part of the result that its
+own experts give, by grouped products over the picks sorted by expert
+(``jax.lax.ragged_dot``); picks that fall on experts held elsewhere add
+nothing here. On one chip the layer runs without its exchange.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -128,3 +137,74 @@ def moe_ffn_sharded(tokens, w_router, w_in, w_out, mesh,
                   P(axis_name, None, None), P(axis_name, None, None)),
         out_specs=(P(axis_name, None), P()))(
             tokens, w_router, w_in, w_out)
+
+
+# ----------------------------------------------------------------------
+# the deployed expert layer: top-k of all, dropless, a held share
+# ----------------------------------------------------------------------
+
+def route_topk(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x [T,d], w_router [d,E] -> (ids [T,k] int32, weights [T,k] f32).
+    The router runs in float32 whatever the model's type: a near-tie
+    between the k-th and the next expert decides which experts a token
+    sees. Scores are sigmoids; the weights of the k picked sum to 1."""
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    picked, ids = jax.lax.top_k(scores, top_k)
+    return ids.astype(jnp.int32), picked / picked.sum(-1, keepdims=True)
+
+
+def _experts_held_block(x, ids, weights, valid, w_gate, w_up, w_down,
+                        lo: int):
+    t, k = ids.shape
+    e = w_gate.shape[0]
+    local = (ids >= lo) & (ids < lo + e) & valid[:, None]
+    # a pick on an expert held elsewhere goes to group e, which sorts
+    # last and which the grouped product never reaches
+    group = jnp.where(local, ids - lo, e).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((e + 1,), jnp.int32).at[group].add(1)[:e]
+    rows = x[order // k]                               # [T*k, d]
+    h = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
+         * jax.lax.ragged_dot(rows, w_up, sizes))
+    out = jax.lax.ragged_dot(h, w_down, sizes)
+    # back to the picks' own order; rows past the last group are
+    # whatever the kernel left there, so they are masked, not scaled
+    back = jnp.argsort(order)
+    picked = jnp.where(local[:, :, None], out[back].reshape(t, k, -1), 0)
+    y = (picked.astype(jnp.float32) * weights[:, :, None]).sum(axis=1)
+    return y.astype(x.dtype), sizes
+
+
+def experts_held(x: jnp.ndarray, ids: jnp.ndarray, weights: jnp.ndarray,
+                 w_gate: jnp.ndarray, w_up: jnp.ndarray,
+                 w_down: jnp.ndarray, first_held: int,
+                 valid: Optional[jnp.ndarray] = None,
+                 block_tokens: int = 8192
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The held experts' part of the layer: ``sum_i w_i E_i(x)`` over a
+    token's picks ``i`` in ``[first_held, first_held + E_held)``, with
+    ``E(x) = w_down(silu(w_gate x) * (w_up x))``. x [T,d]; ids, weights
+    [T,k] from ``route_topk``; w_gate, w_up [E_held,d,f]; w_down
+    [E_held,f,d]. No capacity: every local pick is computed. ``valid``
+    [T] marks the tokens that count (a padded bucket's other positions
+    are neither computed nor counted). Returns (y [T,d], picks a held
+    expert [E_held] int32). More than ``block_tokens`` tokens go a block
+    at a time, so that the sorted copies of a long prompt's picks stay
+    bounded."""
+    t = x.shape[0]
+    if valid is None:
+        valid = jnp.ones((t,), bool)
+    if t <= block_tokens:
+        return _experts_held_block(x, ids, weights, valid, w_gate, w_up,
+                                   w_down, first_held)
+    pad = (-t) % block_tokens
+    parts = [jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+             for a in (x, ids, weights, valid)]
+    parts = [a.reshape((-1, block_tokens) + a.shape[1:]) for a in parts]
+    y, sizes = jax.lax.map(
+        lambda b: _experts_held_block(*b, w_gate, w_up, w_down,
+                                      first_held), tuple(parts))
+    return y.reshape(-1, y.shape[-1])[:t], sizes.sum(axis=0)

@@ -206,7 +206,9 @@ def append_token_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     vector-index scatters lower to serial per-index loops on TPU, which
     dominated the whole decode step; the dense mask-multiply is a pure
     VPU/MXU streaming op over the cache (slots are unique per batch —
-    the page allocator never shares a page between live sequences)."""
+    the page allocator never shares a page between live sequences;
+    idle slots do share the parking page's cell, which then holds the
+    sum of their dummy tokens)."""
     P, KV, page, D = k_pages.shape
     logical = seq_lens // page
     slot = seq_lens % page
@@ -215,7 +217,14 @@ def append_token_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     oh_p = jax.nn.one_hot(phys, P, dtype=k_pages.dtype)        # [B,P]
     oh_s = jax.nn.one_hot(slot, page, dtype=k_pages.dtype)     # [B,page]
     mask = jnp.einsum("bp,bs->ps", oh_p, oh_s)                 # [P,page]
-    keep = (1 - mask)[:, None, :, None]
+    # idle slots all park their dummy token in ONE cell (same parking
+    # page, same length), so the mask counts them there: 1 - count
+    # would multiply that cell by -(idle - 1) every time it is hit,
+    # and with no prefill launch to rewrite the parking page (a drain,
+    # long answers) it overflows in a few hundred steps; 0 x inf at the
+    # masked positions of every table that names the parking page is
+    # then NaN in all live slots. The cell is overwritten, not scaled.
+    keep = (1 - jnp.minimum(mask, 1))[:, None, :, None]
     k_contrib = jnp.einsum("bp,bs,bkd->pksd", oh_p, oh_s,
                            k_new.astype(k_pages.dtype))
     v_contrib = jnp.einsum("bp,bs,bkd->pksd", oh_p, oh_s,

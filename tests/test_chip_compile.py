@@ -188,3 +188,113 @@ def test_engine_prefill_program(chip, bucket, rows, capsys):
     # weights (PERF.md section 4); a launch's budget of 512 positions
     # has to stay a small part of the chip
     assert m.temp_size_in_bytes < 1 << 30
+
+
+def test_kda_chunk_scan(chip):
+    """One segment of a delta-rule layer at the published widths of the
+    second served family (64 heads, key and value width 128): 2,048
+    positions through the chunkwise scan. The pairwise decay of a chunk
+    ([64 heads, 16, 16, 128] float32) lives inside the scan's body and
+    nowhere else."""
+    from ray_tpu.ops.kda import kda_chunked
+
+    qk = chip((1, 2048, 64, 128), jnp.float32)
+    compiled, text = _compile(
+        kda_chunked, qk, qk, qk, qk, chip((1, 2048, 64), jnp.float32),
+        chip((1, 64, 128, 128), jnp.float32))
+    assert "while" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+@pytest.mark.parametrize("tokens", [64, 8192])
+def test_experts_grouped_products(chip, tokens):
+    """The held experts' part of an expert layer (40 of 320 held, top-8,
+    width 1,280 at d 4,096) for a decode step's 64 slots and for a
+    block of a long prompt: the grouped products are the TPU's own
+    ragged-dot kernel, not a dense product a group."""
+    from ray_tpu.ops.moe import experts_held, route_topk
+
+    def layer(x, router, w_gate, w_up, w_down):
+        ids, weights = route_topk(x, router, 8)
+        return experts_held(x, ids, weights, w_gate, w_up, w_down, 0)
+
+    w = chip((40, 4096, 1280), jnp.bfloat16)
+    compiled, text = _compile(
+        layer, chip((tokens, 4096), jnp.bfloat16),
+        chip((4096, 320), jnp.bfloat16), w, w,
+        chip((40, 1280, 4096), jnp.bfloat16))
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    # the sorted copies of a block's picks stay a small part of the chip
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
+    """The decode program of the benchmark's second configuration at its
+    own size, on shapes alone: one gated-GQA layer on a pool of 4,097
+    pages of 128 read by the Pallas paged kernel, three delta-rule
+    layers on a float32 state of 64 slots, 40 held experts a layer, an
+    eighth of the vocabulary. It has to fit one chip beside nothing
+    else: arguments + temporaries under 15 GB."""
+    from ray_tpu.models import inference
+    from ray_tpu.models.decoder import DecoderConfig, LayerSpec
+
+    # the trace-time choice of kernel follows the backend; this test
+    # compiles for the chip, so it answers as the chip would
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d, h, hd, f, e, v = 4096, 64, 128, 1280, 40, 24576
+    bf, f32 = jnp.bfloat16, jnp.float32
+    mcfg = DecoderConfig(
+        vocab_size=v, d_model=d, n_heads=h, n_kv_heads=8, head_dim=hd,
+        layers=(LayerSpec("attention", "experts"),)
+        + (LayerSpec("delta_rule", "experts"),) * 3,
+        rope_theta=None, attn_gate=True, tie_embeddings=False,
+        dr_heads=h, dr_key_dim=hd, dr_value_dim=hd, dr_conv=4, dr_rank=128,
+        n_routed_experts=320, experts_held=(0, e), experts_per_token=8,
+        d_expert=f, d_shared=f)
+    mlp = lambda *lead: {"w_gate": chip(lead + (d, f), bf),  # noqa: E731
+                         "w_up": chip(lead + (d, f), bf),
+                         "w_down": chip(lead + (f, d), bf)}
+    norm = {"scale": chip((d,), bf)}
+    moe = {"router": chip((d, 320), bf), "shared": mlp(), **mlp(e)}
+    proj = lambda n=h: chip((d, n, hd), bf)  # noqa: E731
+    attention = {"wq": proj(), "wk": proj(8), "wv": proj(8),
+                 "w_gate": proj(), "wo": chip((h, hd, d), bf)}
+    low = lambda: chip((d, 128), bf)  # noqa: E731
+    delta = {"wq": proj(), "wk": proj(), "wv": proj(),
+             "wo": chip((h, hd, d), bf),
+             **{c: chip((4, h, hd), bf)
+                for c in ("conv_q", "conv_k", "conv_v")},
+             "w_f_down": low(), "w_f_up": chip((128, h, hd), bf),
+             "w_g_down": low(), "w_g_up": chip((128, h, hd), bf),
+             "A_log": chip((h,), bf), "dt_bias": chip((h, hd), bf),
+             "w_beta": chip((d, h), bf), "o_norm": chip((hd,), bf)}
+    params = {"embedding": chip((v, d), bf), "lm_head": chip((v, d), bf),
+              "final_norm": norm}
+    for i in range(4):
+        mixer = ({"Attention_0": attention} if i == 0
+                 else {"DeltaRule_0": delta})
+        params[f"layer_{i}"] = {**mixer, "MoE_0": moe, "RMSNorm_0": norm,
+                                "RMSNorm_1": norm}
+    slots, page, pages_a_seq = 64, 128, 132
+    pool = (chip((4097, 8, page, hd), bf),)
+    state = tuple((chip((slots, h, hd, hd), f32),
+                   chip((slots, 3, h * 3 * hd), bf)) for _ in range(3))
+    compiled = jax.jit(
+        lambda p, t, kp, vp, table, lens, st, live: inference._decode_chunk(
+            p, mcfg, t, kp, vp, table, lens, st, live, n_steps=32),
+        donate_argnums=(2, 3, 6)).lower(
+            params, chip((slots,), jnp.int32), pool, pool,
+            chip((slots, pages_a_seq), jnp.int32),
+            chip((slots,), jnp.int32), state,
+            chip((slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" in text
+    m = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[hybrid decode chunk, 32 steps] arguments "
+              f"{m.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} GB, aliased "
+              f"{m.alias_size_in_bytes / 1e9:.3f} GB")
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15e9
+    # pool and state are donated and updated in place
+    assert m.alias_size_in_bytes > 2.9e9

@@ -1,0 +1,135 @@
+"""ops/kda.py: the chunkwise gated delta rule against the recurrence
+taken one position at a time in float64, the one-step form, padding,
+and the short convolution with its tail."""
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from ray_tpu.ops import kda  # noqa: E402
+
+H, DK, DV = 3, 8, 6
+
+
+def inputs(seed, n, s, g_scale=3.0, beta_max=2.0):
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    q = kda.l2norm(f(n, s, H, DK)) / np.sqrt(DK)
+    k = kda.l2norm(f(n, s, H, DK))
+    v = f(n, s, H, DV)
+    g = -jnp.asarray(rng.uniform(0, g_scale, (n, s, H, DK)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0, beta_max, (n, s, H)), jnp.float32)
+    return q, k, v, g, beta
+
+
+def recurrence(q, k, v, g, beta, state=None):
+    """Token by token, float64: the definition."""
+    q, k, v, g, beta = (np.asarray(a, np.float64) for a in (q, k, v, g, beta))
+    n, s = q.shape[:2]
+    st = (np.zeros((n, H, DK, DV)) if state is None
+          else np.asarray(state, np.float64))
+    out = np.zeros((n, s, H, DV))
+    for t in range(s):
+        st = st * np.exp(g[:, t])[..., None]
+        u = beta[:, t][..., None] * (
+            v[:, t] - np.einsum("nhk,nhkv->nhv", k[:, t], st))
+        st = st + k[:, t][..., None] * u[..., None, :]
+        out[:, t] = np.einsum("nhk,nhkv->nhv", q[:, t], st)
+    return out, st
+
+
+@pytest.mark.parametrize("chunk", [4, 16, 64])
+@pytest.mark.parametrize("s", [37, 64, 100])
+def test_chunkwise_matches_the_recurrence(chunk, s):
+    x = inputs(chunk * 1000 + s, 2, s)
+    o, st = kda.kda_chunked(*x, chunk=chunk)
+    ro, rst = recurrence(*x)
+    np.testing.assert_allclose(np.asarray(o), ro, atol=5e-6)
+    np.testing.assert_allclose(np.asarray(st), rst, atol=5e-6)
+
+
+def test_beta_of_two_reaches_the_negative_eigenvalue():
+    q, k, v, g, _ = inputs(7, 1, 48, g_scale=0.05)
+    beta = jnp.full((1, 48, H), 2.0, jnp.float32)
+    o, st = kda.kda_chunked(q, k, v, g, beta, chunk=16)
+    ro, rst = recurrence(q, k, v, g, beta)
+    np.testing.assert_allclose(np.asarray(o), ro, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(st), rst, atol=2e-5)
+
+
+def test_a_channel_may_decay_by_any_factor_inside_a_chunk():
+    """exp(-40) a step, 64 steps a chunk: a K / exp(G) formulation
+    overflows float32; differences of G never do."""
+    q, k, v, g, beta = inputs(11, 1, 128, g_scale=1.0)
+    g = g.at[:, :, 0, :4].set(-40.0)
+    o, st = kda.kda_chunked(q, k, v, g, beta, chunk=64)
+    ro, rst = recurrence(q, k, v, g, beta)
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(np.asarray(o), ro, atol=5e-6)
+    np.testing.assert_allclose(np.asarray(st), rst, atol=5e-6)
+
+
+@pytest.mark.parametrize("lens", [(5, 29), (16, 1), (32, 31)])
+def test_padding_does_not_touch_the_state(lens):
+    """Rows of a padded bucket: the state after the bucket is the state
+    after each row's own length."""
+    s = 32
+    q, k, v, g, beta = inputs(sum(lens), 2, s)
+    gm, bm = kda.pad_mask(g, beta, jnp.asarray(lens))
+    o, st = kda.kda_chunked(q, k, v, gm, bm, chunk=8)
+    for n, ln in enumerate(lens):
+        ro, rst = recurrence(*(a[n:n + 1, :ln] for a in (q, k, v, g, beta)))
+        np.testing.assert_allclose(np.asarray(o)[n, :ln], ro[0], atol=5e-6)
+        np.testing.assert_allclose(np.asarray(st)[n], rst[0], atol=5e-6)
+
+
+@pytest.mark.parametrize("split", [1, 20, 39])
+def test_prefill_then_decode_continues_the_state(split):
+    q, k, v, g, beta = inputs(split, 2, 40)
+    full, last = kda.kda_chunked(q, k, v, g, beta, chunk=16)
+    o, st = kda.kda_chunked(*(a[:, :split] for a in (q, k, v, g, beta)),
+                            chunk=16)
+    outs = [o]
+    for t in range(split, 40):
+        ot, st = kda.kda_step(q[:, t], k[:, t], v[:, t], g[:, t],
+                              beta[:, t], st)
+        outs.append(ot[:, None])
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, 1)),
+                               np.asarray(full), atol=5e-6)
+    np.testing.assert_allclose(np.asarray(st), np.asarray(last), atol=5e-6)
+
+
+def test_a_given_state_is_carried_on():
+    q, k, v, g, beta = inputs(3, 1, 24)
+    s0 = jnp.asarray(np.random.default_rng(0).normal(size=(1, H, DK, DV)),
+                     jnp.float32)
+    o, st = kda.kda_chunked(q, k, v, g, beta, s0, chunk=8)
+    ro, rst = recurrence(q, k, v, g, beta, s0)
+    np.testing.assert_allclose(np.asarray(o), ro, atol=5e-6)
+    np.testing.assert_allclose(np.asarray(st), rst, atol=5e-6)
+
+
+def test_short_convolution_and_its_tail():
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(2, 12, 5)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(4, 5)), jnp.float32)
+    y = np.asarray(kda.short_conv(x, w))
+    xn, wn = np.asarray(x), np.asarray(w)
+    for t in range(12):
+        want = sum(wn[3 - j] * xn[:, t - j] for j in range(4) if t - j >= 0)
+        np.testing.assert_allclose(y[:, t], want, atol=1e-6)
+    # the tail of a row of 7 (and of 2: zeros before position 0) lets
+    # the one-step form continue where the prompt's convolution ended
+    lens = jnp.asarray([7, 2])
+    tail = kda.conv_tail(x, lens, 4)
+    for n, ln in enumerate((7, 2)):
+        t = tail[n:n + 1]
+        for pos in range(ln, 12):
+            yt, t = kda.short_conv_step(x[n:n + 1, pos], w, t)
+            if ln == 7:      # row 0's later inputs are the same x
+                np.testing.assert_allclose(np.asarray(yt)[0], y[n, pos],
+                                           atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(tail)[1, 0], 0.0)
+    np.testing.assert_allclose(np.asarray(tail)[1, 1:], xn[1, :2])

@@ -1,0 +1,123 @@
+"""ops/moe.py's deployed expert layer (``route_topk``,
+``experts_held``): against a loop over experts, the share test of the
+model-configs guide (the shares of the experts, with the shared expert
+counted once, add up to the uncut layer), blocks, padding and the
+picks it counts."""
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from ray_tpu.ops.moe import experts_held, route_topk  # noqa: E402
+
+D, F, E, K = 16, 8, 40, 8
+
+
+@pytest.fixture(scope="module")
+def layer():
+    rng = np.random.default_rng(0)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape) / 4, jnp.float32)  # noqa: E731
+    return {"router": f(D, E), "w_gate": f(E, D, F), "w_up": f(E, D, F),
+            "w_down": f(E, F, D),
+            "shared": {"w_gate": f(D, F), "w_up": f(D, F), "w_down": f(F, D)},
+            "x": f(50, D) * 4}
+
+
+def swiglu(x, g, u, d):
+    return (jax.nn.silu(x @ g) * (x @ u)) @ d
+
+
+def loop_over_experts(p, lo, hi):
+    """The definition: for every token, the weighted sum over its picks
+    that fall in [lo, hi)."""
+    x = p["x"]
+    scores = jax.nn.sigmoid(x @ p["router"])
+    top = np.argsort(-np.asarray(scores), axis=1)[:, :K]
+    y = np.zeros((x.shape[0], D))
+    for t in range(x.shape[0]):
+        norm = float(scores[t, top[t]].sum())
+        for e in top[t]:
+            if lo <= e < hi:
+                y[t] += float(scores[t, e]) / norm * np.asarray(swiglu(
+                    x[t:t + 1], p["w_gate"][e], p["w_up"][e],
+                    p["w_down"][e]))[0]
+    return y, top
+
+
+def held(p, lo, hi, **kw):
+    ids, w = route_topk(p["x"], p["router"], K)
+    return experts_held(p["x"], ids, w, p["w_gate"][lo:hi],
+                        p["w_up"][lo:hi], p["w_down"][lo:hi], lo, **kw)
+
+
+def test_router_picks_k_and_normalises_over_the_picked(layer):
+    ids, w = route_topk(layer["x"], layer["router"], K)
+    assert ids.shape == w.shape == (50, K) and ids.dtype == jnp.int32
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, atol=1e-6)
+    _, top = loop_over_experts(layer, 0, E)
+    assert (np.sort(np.asarray(ids), 1) == np.sort(top, 1)).all()
+    assert w.dtype == jnp.float32          # whatever the model's type
+    ids16, _ = route_topk(layer["x"].astype(jnp.bfloat16),
+                          layer["router"].astype(jnp.bfloat16), K)
+    assert ids16.shape == (50, K)
+
+
+@pytest.mark.parametrize("lo, hi", [(0, E), (0, 5), (5, 10), (35, 40)])
+def test_held_experts_against_a_loop_over_experts(layer, lo, hi):
+    y, counts = held(layer, lo, hi)
+    want, top = loop_over_experts(layer, lo, hi)
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-6)
+    np.testing.assert_array_equal(
+        np.asarray(counts), [(top == e).sum() for e in range(lo, hi)])
+
+
+def test_the_shares_add_up_to_the_uncut_layer(layer):
+    """Eight chips, five experts each, every one routing over all 40:
+    their parts, plus the shared expert ONCE, are the whole layer."""
+    parts = [held(layer, lo, lo + 5) for lo in range(0, E, 5)]
+    sh = layer["shared"]
+    shared = swiglu(layer["x"], sh["w_gate"], sh["w_up"], sh["w_down"])
+    whole, _ = loop_over_experts(layer, 0, E)
+    np.testing.assert_allclose(
+        np.asarray(sum(y for y, _ in parts) + shared),
+        whole + np.asarray(shared), atol=5e-6)
+    # no pick dropped, none counted twice
+    assert sum(int(c.sum()) for _, c in parts) == 50 * K
+    # a share alone is not the layer (the test can fail)
+    assert np.abs(np.asarray(parts[0][0]) - whole).max() > 1e-3
+
+
+def test_no_capacity_every_pick_on_one_expert_is_computed(layer):
+    """All tokens alike: all 50 pick the same experts; nothing drops."""
+    p = dict(layer, x=jnp.tile(layer["x"][:1], (50, 1)))
+    y, counts = held(p, 0, E)
+    want, _ = loop_over_experts(p, 0, E)
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-6)
+    assert sorted(np.asarray(counts))[-K:] == [50] * K
+
+
+def test_blocks_of_tokens_give_the_same(layer):
+    y, counts = held(layer, 5, 25)
+    yb, cb = held(layer, 5, 25, block_tokens=16)     # 50 tokens: 4 blocks
+    np.testing.assert_allclose(np.asarray(yb), np.asarray(y), atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(cb), np.asarray(counts))
+
+
+def test_padding_is_neither_computed_nor_counted(layer):
+    valid = jnp.arange(50) < 30
+    y, counts = held(layer, 0, 20, valid=valid)
+    want, top = loop_over_experts(layer, 0, 20)
+    np.testing.assert_allclose(np.asarray(y)[:30], want[:30], atol=2e-6)
+    assert not np.asarray(y)[30:].any()
+    np.testing.assert_array_equal(
+        np.asarray(counts), [(top[:30] == e).sum() for e in range(20)])
+
+
+def test_jits_and_runs_in_the_models_type(layer):
+    bf = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), layer)
+    y, _ = jax.jit(lambda p: held(p, 0, 20))(bf)
+    want, _ = loop_over_experts(layer, 0, 20)
+    assert y.dtype == jnp.bfloat16
+    assert np.abs(np.asarray(y, np.float32) - want).max() < 0.1
