@@ -502,27 +502,38 @@ DECODE_MODEL = dict(vocab_size=32_000, d_model=1024, n_layers=8,
                     n_heads=8, n_kv_heads=4, d_ff=2816, max_seq_len=2048)
 
 
-def _decode_program_has_kernel(params, mcfg, icfg) -> bool:
+def _decode_program_has_kernel(params, mcfg, icfg, shape=None) -> bool:
     """Compile the engine's decode step for this geometry and look for
-    the Pallas custom call. The engine jits exactly this function
-    (inference.decode_chunk); paged_attention_auto picks the path at
-    trace time from max_pages_per_seq * page_size."""
+    the paged attention kernel: a Pallas custom call under the ``attn``
+    scope. (ANY custom call will not do: every decode program holds
+    ``append_token_kv``'s kernel, under ``kv_append``.) The engine jits
+    this function (inference.decode_chunk) with its pools donated, and
+    so does this: not donated, the compiler copies each pool first, and
+    where that copy fits VMEM its memory assignment aborts on the
+    kernel's HBM pin (append_token_kv's docstring).
+    paged_attention_auto picks the path at trace time from
+    max_pages_per_seq * page_size. ``shape(dims, dtype)`` describes an
+    argument; tests/test_chip_compile.py places them on a described
+    chip."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import inference
 
+    shape = shape or jax.ShapeDtypeStruct
     kv = tuple(
-        jax.ShapeDtypeStruct((icfg.num_pages, mcfg.n_kv_heads,
-                              icfg.page_size, mcfg.head_dim), mcfg.dtype)
+        shape((icfg.num_pages, mcfg.n_kv_heads, icfg.page_size,
+               mcfg.head_dim), mcfg.dtype)
         for _ in range(mcfg.n_layers))
-    ints = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    ints = lambda *s: shape(s, jnp.int32)  # noqa: E731
     fn = jax.jit(lambda p, t, kp, vp, table, lens: inference.decode_chunk(
-        p, mcfg, t, kp, vp, table, lens, n_steps=1))
+        p, mcfg, t, kp, vp, table, lens, n_steps=1), donate_argnums=(2, 3))
     compiled = fn.lower(params, ints(icfg.batch_size), kv, kv,
                         ints(icfg.batch_size, icfg.max_pages_per_seq),
                         ints(icfg.batch_size)).compile()
-    return "tpu_custom_call" in compiled.as_text()
+    return any('custom_call_target="tpu_custom_call"' in line
+               and re.search(r'op_name="[^"]*/attn/', line)
+               for line in compiled.as_text().splitlines())
 
 
 def _check_against_naive(ref_model, params, prompt: Sequence[int],

@@ -456,9 +456,10 @@ def decode_step(params: Dict[str, Any], cfg, tokens: jnp.ndarray,
     """One continuous-batching step: tokens [B] int32 (last emitted or
     last prompt token per slot), cache = per-layer TUPLES of
     [P,KV,page,D] arrays (a pytree, never re-stacked: each layer's
-    scatter update aliases its own buffer in place under jit/scan —
-    stacking into one [L,...] array would copy the whole cache every
-    step). Returns (next_logits [B,V] f32, k_pages, v_pages). Models
+    append kernel takes its own pool as input and output of one buffer
+    under jit/scan; stacking into one [L,...] array would copy the
+    whole cache every step). Returns (next_logits [B,V] f32, k_pages,
+    v_pages). Models
     whose every mixer is attention."""
     cfg = describe(cfg)
     if cfg.state_layers:
@@ -670,8 +671,10 @@ class InferenceEngine:
             self.num_steps = 0
             self.max_concurrent = 0
             return
-        # per-layer tuple (pytree), NOT a stacked [L,...] array: in-place
-        # scatter updates per layer under the donated decode program
+        # per-layer tuple (pytree), NOT a stacked [L,...] array: each
+        # layer's pool is written in place under the donated programs
+        # (a decode step's cells by append_token_kv's kernel, a
+        # launch's pages by write_prefill_kv's scatter)
         self._k_pages = tuple(
             jnp.zeros((cfg.num_pages, KV, cfg.page_size, D),
                       model_cfg.dtype) for _ in range(L))

@@ -9,16 +9,21 @@ granularity instead of max-seq granularity. Reference-framework analog:
 the serving stack's attention kernels (the reference runs vLLM-style
 paged attention on GPU); here it is a Pallas TPU kernel.
 
-Two implementations, parity-tested:
+Two implementations of the read, parity-tested, and the two writes:
 
   - ``paged_attention_reference``: pure-XLA gather over the page table
     (the short-context path and the numerics oracle);
   - ``paged_attention``: Pallas flash-decoding kernel. Grid =
-    (batch, kv_heads, pages); the page table rides scalar prefetch and
+    (batch, pages); the page table rides scalar prefetch and
     the K/V BlockSpec index_maps select each sequence's physical page,
     so the kernel only ever DMAs pages the sequence actually owns.
     Online softmax state (m, l, acc) persists in VMEM scratch across
-    the page axis of the grid (the flash-attention recurrence).
+    the page axis of the grid (the flash-attention recurrence);
+  - ``append_token_kv``: a decode step's write, one cell a sequence.
+    A Pallas kernel too: each sequence's tail page goes through VMEM
+    and back to where it lay, the rest of the pool is not touched;
+  - ``write_prefill_kv``: a prompt's write, whole pages by an XLA
+    scatter.
 
 Layout: K/V pages are [n_pages, n_kv_heads, page_size, head_dim];
 queries are single decode tokens [B, n_heads, head_dim] (GQA: n_heads =
@@ -194,42 +199,95 @@ def paged_attention_auto(q, k_pages, v_pages, page_table, seq_lens):
 # page-cache update helpers (functional; jit-friendly)
 # ----------------------------------------------------------------------
 
+def _append_kernel(phys_ref, slot_ref, k_new_ref, v_new_ref, k_in_ref,
+                   v_in_ref, k_out_ref, v_out_ref):
+    """One grid cell = one sequence: its tail page comes in, the row
+    ``slot_ref[b]`` is replaced by the token, the page goes back to
+    where it came from. The row is picked by a ``where`` over an iota
+    (a dynamic sublane store into packed bfloat16 need not lower); a
+    slot of -1 picks none and the page goes back as it came."""
+    import jax.experimental.pallas as pl
+
+    del phys_ref  # read by the index maps
+    slot = slot_ref[pl.program_id(0)]
+    hit = jax.lax.broadcasted_iota(jnp.int32, k_in_ref.shape[1:],
+                                   1) == slot
+    k_out_ref[0] = jnp.where(hit, k_new_ref[0][:, None, :], k_in_ref[0])
+    v_out_ref[0] = jnp.where(hit, v_new_ref[0][:, None, :], v_in_ref[0])
+
+
 def append_token_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                     k_new: jnp.ndarray, v_new: jnp.ndarray,
                     page_table: jnp.ndarray,
                     seq_lens: jnp.ndarray
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Write one decode token's K/V [B,KV,D] into each sequence's tail
-    slot (page_table[b, seq_len // page], seq_len % page).
+    cell (page_table[b, seq_len // page], seq_len % page), in place.
 
-    Formulated as a ONE-HOT masked update, not an XLA scatter: batched
-    vector-index scatters lower to serial per-index loops on TPU, which
-    dominated the whole decode step; the dense mask-multiply is a pure
-    VPU/MXU streaming op over the cache (slots are unique per batch —
-    the page allocator never shares a page between live sequences;
-    idle slots do share the parking page's cell, which then holds the
-    sum of their dummy tokens)."""
+    A Pallas kernel over grid (B,), K and V in one call: the pools are
+    input AND output of the same buffers (``input_output_aliases``) in
+    blocks of one page, chosen by the physical page ids that ride
+    scalar prefetch, so a step reads and writes back B pages and
+    touches nothing else of the pool. A Pallas operand also pins the
+    pool's default layout, the one the gather and the paged kernel
+    read: the one-hot product this replaces, an XLA scatter and a loop
+    of ``dynamic_update_slice`` each make the compiler choose a layout
+    of their own for the pool and re-lay it out around the update,
+    whole, every step (PERF.md, PR 28). The outputs, and through the
+    aliases the inputs, are pinned to HBM: left free, the compiler
+    fetched a pool that fits VMEM (50 MB) there for the kernel and the
+    gather after it and wrote it back, whole, every step. Off the TPU
+    the kernel runs in interpret mode, as ``paged_attention_auto``'s
+    does.
+
+    A live sequence's cell gets exactly its token (the page allocator
+    never shares a page between live sequences). Idle slots all name
+    ONE cell of the parking page: it holds the token of whichever of
+    them was written back last, always a finite value, and nothing
+    live reads it unmasked. A sequence whose length lies past its page
+    table writes nothing (a finished slot decoding out a burst: its
+    own last page goes through unchanged).
+
+    Two things the caller owes. Every id in ``page_table`` names a page
+    of the pool, as the engine's do (what it has not allocated is the
+    parking page): an id outside is clipped, so that no DMA leaves the
+    pool, and the token lands in the clipped page. And under jit on a
+    TPU the pools are donated, or carried from donated ones, as in
+    every engine program: not donated, the compiler copies the pool
+    first, and where that copy fits VMEM its memory assignment aborts
+    on the HBM pin (seen compiling for the described v5e, PR 28: a
+    16 MB pool, one step)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
     P, KV, page, D = k_pages.shape
+    B, MP = page_table.shape
     logical = seq_lens // page
-    slot = seq_lens % page
-    phys = jnp.take_along_axis(page_table, logical[:, None],
+    phys = jnp.take_along_axis(page_table,
+                               jnp.minimum(logical, MP - 1)[:, None],
                                axis=1)[:, 0]                   # [B]
-    oh_p = jax.nn.one_hot(phys, P, dtype=k_pages.dtype)        # [B,P]
-    oh_s = jax.nn.one_hot(slot, page, dtype=k_pages.dtype)     # [B,page]
-    mask = jnp.einsum("bp,bs->ps", oh_p, oh_s)                 # [P,page]
-    # idle slots all park their dummy token in ONE cell (same parking
-    # page, same length), so the mask counts them there: 1 - count
-    # would multiply that cell by -(idle - 1) every time it is hit,
-    # and with no prefill launch to rewrite the parking page (a drain,
-    # long answers) it overflows in a few hundred steps; 0 x inf at the
-    # masked positions of every table that names the parking page is
-    # then NaN in all live slots. The cell is overwritten, not scaled.
-    keep = (1 - jnp.minimum(mask, 1))[:, None, :, None]
-    k_contrib = jnp.einsum("bp,bs,bkd->pksd", oh_p, oh_s,
-                           k_new.astype(k_pages.dtype))
-    v_contrib = jnp.einsum("bp,bs,bkd->pksd", oh_p, oh_s,
-                           v_new.astype(v_pages.dtype))
-    return (k_pages * keep + k_contrib, v_pages * keep + v_contrib)
+    phys = jnp.clip(phys, 0, P - 1).astype(jnp.int32)
+    # a cell that writes nothing still moves a page: its own last one
+    slot = jnp.where(logical < MP, seq_lens % page, -1).astype(jnp.int32)
+
+    token = pl.BlockSpec((1, KV, D), lambda b, phys, slot: (b, 0, 0))
+    tail_page = pl.BlockSpec((1, KV, page, D),
+                             lambda b, phys, slot: (phys[b], 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,   # phys, slot
+        grid=(B,),
+        in_specs=[token, token, tail_page, tail_page],
+        out_specs=[tail_page, tail_page],
+    )
+    return tuple(pl.pallas_call(
+        _append_kernel, grid_spec=grid_spec,
+        out_shape=[pltpu.HBM(k_pages.shape, k_pages.dtype),
+                   pltpu.HBM(v_pages.shape, v_pages.dtype)],
+        # operands count the two prefetched scalars: 4, 5 are the pools
+        input_output_aliases={4: 0, 5: 1},
+        interpret=jax.default_backend() != "tpu",
+    )(phys, slot, k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype),
+      k_pages, v_pages))
 
 
 def write_prefill_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,

@@ -15,6 +15,8 @@ land on another worker, whose fixture would then skip in silence).
 """
 
 import importlib
+import inspect
+import re
 
 import jax
 import jax.numpy as jnp
@@ -141,6 +143,20 @@ def test_scheduler_assign_kernel(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+def _mistral_two_layers(max_seq_len):
+    """Mistral-7B's widths, 2 of its layers, bfloat16: the description
+    and the parameter tree as shapes (no device yet)."""
+    from ray_tpu.models.transformer import Transformer, TransformerConfig
+
+    mcfg = TransformerConfig(vocab_size=32768, d_model=4096, n_layers=2,
+                             n_heads=32, n_kv_heads=8, d_ff=14336,
+                             max_seq_len=max_seq_len, rope_theta=1e6,
+                             dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(Transformer(mcfg).init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    return mcfg, params
+
+
 @pytest.mark.parametrize("bucket, rows", [(512, 1), (64, 8)])
 def test_engine_prefill_program(chip, bucket, rows, capsys):
     """The serving cell's prefill program (Mistral-7B widths, 2 of its
@@ -149,19 +165,13 @@ def test_engine_prefill_program(chip, bucket, rows, capsys):
     ``engine_prefill_b512`` has 1 row and ``b64`` has 8. The engine is
     built on shapes alone; its own jitted program is what compiles."""
     from ray_tpu.models.inference import InferenceConfig, InferenceEngine
-    from ray_tpu.models.transformer import Transformer, TransformerConfig
 
-    mcfg = TransformerConfig(vocab_size=32768, d_model=4096, n_layers=2,
-                             n_heads=32, n_kv_heads=8, d_ff=14336,
-                             max_seq_len=768, rope_theta=1e6,
-                             dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    mcfg, params = _mistral_two_layers(768)
     icfg = InferenceConfig(batch_size=32, page_size=16, max_pages_per_seq=48,
                            num_pages=1537,
                            prefill_buckets=(64, 128, 256, 512))
     on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
         lambda x: chip(x.shape, x.dtype), tree)
-    params = jax.eval_shape(Transformer(mcfg).init, jax.random.PRNGKey(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
     engine = InferenceEngine(params, mcfg, icfg)
     try:
         assert engine._prefill_rows[bucket] == rows
@@ -188,6 +198,108 @@ def test_engine_prefill_program(chip, bucket, rows, capsys):
     # weights (PERF.md section 4); a launch's budget of 512 positions
     # has to stay a small part of the chip
     assert m.temp_size_in_bytes < 1 << 30
+
+
+def _pool_shaped(text, pool_dims):
+    """The instructions of a compiled program's text whose result, or
+    one element of whose result tuple, has the bfloat16 pool's shape:
+    (name, opcode, the line)."""
+    shape = f"bf16[{','.join(map(str, pool_dims))}]"
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if m and shape in m.group(2):
+            found.append((m.group(1), m.group(3), line))
+    return found
+
+
+def _no_pool_moves(whole):
+    """No copy yields a whole pool (a ``copy`` re-lays it out, a
+    ``copy-start`` moves it between HBM and VMEM: with the kernel's
+    pools not pinned to HBM the compiler fetched a 50 MB pool into
+    VMEM for the gather and wrote it back, every step), and no fusion
+    under ``kv_append`` does."""
+    assert [n for n, op, _l in whole if op in ("copy", "copy-start")] == []
+    assert [n for n, op, line in whole
+            if op == "fusion" and "kv_append" in line] == []
+
+
+def test_chip_smoke_tells_the_attention_kernel_from_the_append(
+        chip, monkeypatch):
+    """chip_smoke.py phase C holds ``paged_attention_auto`` to its
+    choice: the gather at 16 pages a sequence, the kernel at 128. Every
+    decode program holds a Pallas custom call since the append is one,
+    so "a custom call" says nothing: the check has to read False, True
+    at the phase's own two geometries (2 of the model's 8 layers)."""
+    import os
+    import sys
+
+    from ray_tpu.models.inference import InferenceConfig
+    from ray_tpu.models.transformer import Transformer, TransformerConfig
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(repo)
+    import chip_smoke
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mcfg = TransformerConfig(**dict(chip_smoke.DECODE_MODEL, n_layers=2))
+    params = jax.tree_util.tree_map(
+        lambda x: chip(x.shape, x.dtype),
+        jax.eval_shape(Transformer(mcfg).init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"])
+    defaults = inspect.signature(chip_smoke.phase_serving).parameters
+    found = [chip_smoke._decode_program_has_kernel(
+        params, mcfg, InferenceConfig(
+            batch_size=defaults["slots"].default,
+            page_size=defaults["page_size"].default,
+            max_pages_per_seq=mp, num_pages=pages), shape=chip)
+        for mp, pages in defaults["geometries"].default]
+    assert found == list(defaults["expect_kernel"].default) == [False, True]
+
+
+@pytest.mark.parametrize("num_pages, pages_a_seq", [(1537, 48), (2177, 68)])
+def test_dense_decode_chunk(chip, monkeypatch, capsys, num_pages,
+                            pages_a_seq):
+    """The decode program of the two dense serving cells (Mistral-7B
+    widths, 2 of its layers, 32 slots, pages of 16: 1,537 pages and 48
+    a sequence in ``chat-steady``, 2,177 and 68 in ``decode-heavy``),
+    4 steps, pools donated. A step appends one cell a slot IN PLACE and
+    in the layout the gather reads: the compiled text may hold no
+    ``copy`` that yields a whole pool, in the scan's body or in
+    ``main``, and nothing pool-shaped may come out of a fusion under
+    ``kv_append`` (the one-hot form left six such copies and four such
+    fusions a layer). The compiler's analysis, not a chip reading."""
+    from ray_tpu.models import inference
+    from ray_tpu.models.decoder import describe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, layers = 32, 2
+    mcfg, params = _mistral_two_layers(16 * pages_a_seq)
+    params = jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), params)
+    pool_dims = (num_pages, 8, 16, 128)
+    pools = tuple(chip(pool_dims, jnp.bfloat16) for _ in range(layers))
+    compiled = jax.jit(
+        lambda p, t, kp, vp, table, lens: inference._decode_chunk(
+            p, describe(mcfg), t, kp, vp, table, lens, (), None, n_steps=4),
+        donate_argnums=(2, 3)).lower(
+            params, chip((slots,), jnp.int32), pools, pools,
+            chip((slots, pages_a_seq), jnp.int32),
+            chip((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    pool_bytes = 2 * layers * 2 * num_pages * 8 * 16 * 128
+    with capsys.disabled():
+        print(f"\n[dense decode chunk, {num_pages} pages, 4 steps, 2 layers] "
+              f"arguments {m.argument_size_in_bytes / 1e9:.3f} GB (pool "
+              f"{pool_bytes / 1e9:.3f}), temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} GB, aliased "
+              f"{m.alias_size_in_bytes / 1e9:.3f} GB")
+    assert "tpu_custom_call" in text    # the append is the kernel
+    _no_pool_moves(_pool_shaped(text, pool_dims))
+    # the pools go through the scan in their own buffers
+    assert m.alias_size_in_bytes >= pool_bytes
+    # what is left is the gather's: the one-hot form held 1.5 pools
+    assert m.temp_size_in_bytes < pool_bytes
 
 
 def test_kda_chunk_scan(chip):
@@ -298,3 +410,10 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15e9
     # pool and state are donated and updated in place
     assert m.alias_size_in_bytes > 2.9e9
+    # the append writes its cells into the pool where it lies: no copy
+    # of a pool in the text, nothing pool-shaped out of a fusion under
+    # ``kv_append``, and the temporaries hold no K and V pair of 2.15 GB
+    # (4.01 GB with the one-hot append, 0.83 GB since; the compiler's
+    # analysis, not a chip reading)
+    _no_pool_moves(_pool_shaped(text, (4097, 8, page, hd)))
+    assert m.temp_size_in_bytes < 1.9e9

@@ -333,10 +333,13 @@ def test_served_through_serve_run(model):
 
 # sha256 (16 hex digits) of the programs' lowered text at the commit
 # before the layer-by-layer description (84b28e3), locations and the
-# module's name stripped. The two decode programs are NOT the parent's:
-# they differ from it by one ``minimum`` in ``append_token_kv`` (the
-# parking cell is overwritten, not scaled: tests/test_parking_page.py),
-# and by nothing of the description; their hashes are this commit's.
+# module's name stripped: prefill, split and export are still the
+# parent's to the letter. The two decode programs are not: their
+# ``append_token_kv`` is a Pallas kernel that writes one cell a slot
+# in place (PR 28; before it a one-hot product over the whole pool,
+# PR 27's with the parking cell overwritten), lowered here in interpret
+# mode; nothing of the description shows in them either. Their hashes
+# are this commit's.
 PARENT_PROGRAMS = {
     "prefill_b8": "ab1c7c908e0a4268",
     "prefill_b16": "d5cefb7ddf632494",
@@ -344,9 +347,9 @@ PARENT_PROGRAMS = {
     "export_b8": "b3bca5d09d7192d8",
     "export_b16": "9f3f5b3beffab62a",
 }
-WITH_THE_PARKING_CELL_FIX = {
-    "decode_n1": "2d952efeb5537c32",
-    "decode_n2": "c635761f43fd8d5a",
+WITH_THE_IN_PLACE_APPEND = {
+    "decode_n1": "5204b95cc5dd9c5c",
+    "decode_n2": "02b49d64d90d4998",
 }
 
 
@@ -399,7 +402,7 @@ def dense():
 def test_dense_decoder_programs_lower_to_the_parents_text(dense):
     cfg, params = dense
     assert lowered_programs(params, cfg) == {**PARENT_PROGRAMS,
-                                             **WITH_THE_PARKING_CELL_FIX}
+                                             **WITH_THE_IN_PLACE_APPEND}
 
 
 def test_untied_head_is_the_only_difference_it_makes(dense):
