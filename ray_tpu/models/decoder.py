@@ -7,8 +7,10 @@ feed-forward, an untied head. ``DecoderConfig`` names, for each layer,
 its mixer (``attention`` or ``delta_rule``) and its feed-forward
 (``dense`` or ``experts``), and beside them what the kinds need. The
 dense decoder is the one-kind case: ``describe`` lowers a
-``TransformerConfig`` to it, and the serving engine
-(``models/inference.py``) reads only this description.
+``TransformerConfig`` to it. ``models/decoder_forward.py`` runs a
+description (the functional forward, and the cache of what each kind of
+layer keeps between steps); the serving engine (``models/inference.py``)
+hands that module the description and knows no kind of layer itself.
 
 The parameter tree a description stands for (``layer_<i>`` under the
 root, beside ``embedding``, ``final_norm/scale`` and, untied,
@@ -123,7 +125,7 @@ class DecoderConfig:
 
 
 def describe(cfg: Any) -> DecoderConfig:
-    """The description the engine reads: a ``DecoderConfig`` as it is,
+    """The description the engine is given: a ``DecoderConfig`` as it is,
     a ``TransformerConfig`` as the dense decoder it is (rotary GQA,
     SwiGLU, tied head in every layer)."""
     if isinstance(cfg, DecoderConfig):

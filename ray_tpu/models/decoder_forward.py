@@ -1,0 +1,526 @@
+"""What a described decoder computes, and what it keeps between steps.
+
+``models/decoder.py`` says, layer by layer, what a model is; this module
+runs it: the functional forward over the parameter tree a description
+stands for (the dense decoder's is the flax module's, so what a Train
+run produces serves directly; a parity test pins this forward to the
+module's output). Every choice between kinds of layer is made here,
+while tracing.
+
+It owns the cache, one entry a layer: ``(k_pages, v_pages)`` for an
+attention layer (its own pool, [P,KV,page,D]), ``(state, tail)`` a slot
+for a delta-rule layer. A pytree, never stacked: each layer's append
+kernel takes its own pool as input and output of one buffer under
+jit/scan, and one [L,...] array would be copied whole every step. The
+serving engine (models/inference.py) holds it as one donated value and
+never looks inside; page tables, slots and the parking page are the
+engine's.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.decoder import DecoderConfig, LayerSpec, describe
+from ray_tpu.ops import kda
+from ray_tpu.ops.flash import flash_attention_bshk, flash_supported
+from ray_tpu.ops.moe import experts_held, route_topk
+from ray_tpu.ops.paged_attention import (append_token_kv,
+                                         paged_attention_auto,
+                                         write_prefill_kv)
+from ray_tpu.ops.rope import rope
+
+# a row longer than this never materialises its [S,S] scores
+_SCORES_MAX_SEQ = 512
+
+
+def _rms(x, scale, eps):
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
+                   keepdims=True)
+    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
+            * scale).astype(x.dtype)
+
+
+def _mlp(p, x, dtype):
+    h = (jax.nn.silu(x @ p["w_gate"].astype(dtype))
+         * (x @ p["w_up"].astype(dtype)))
+    return h @ p["w_down"].astype(dtype)
+
+
+def _experts(m, cfg: DecoderConfig, x, valid):
+    """The expert feed-forward over x [..., d] (normed): routed experts
+    held here plus the shared one. Returns (y, picks a held expert
+    [E_held] int32, of the ``valid`` tokens)."""
+    t = x.reshape(-1, x.shape[-1])
+    dt = cfg.dtype
+    with jax.named_scope("moe_route"):
+        ids, weights = route_topk(t, m["router"], cfg.experts_per_token)
+    with jax.named_scope("moe_experts"):
+        y, counts = experts_held(
+            t, ids, weights, m["w_gate"].astype(dt), m["w_up"].astype(dt),
+            m["w_down"].astype(dt), cfg.experts_held[0],
+            valid.reshape(-1))
+    if cfg.d_shared:
+        with jax.named_scope("moe_shared"):
+            y = y + _mlp(m["shared"], t, dt)
+    return y.reshape(x.shape), counts
+
+
+def _feed_forward(p, cfg: DecoderConfig, spec: LayerSpec, x, valid):
+    """x + FFN(norm(x)) of one layer; the picks a held expert, or
+    None."""
+    if spec.ffn == "dense":
+        with jax.named_scope("mlp"):
+            return x + _mlp(p["MLP_0"], _rms(x, p["RMSNorm_1"]["scale"],
+                                             cfg.norm_eps), cfg.dtype), None
+    y, counts = _experts(p["MoE_0"], cfg,
+                         _rms(x, p["RMSNorm_1"]["scale"], cfg.norm_eps),
+                         valid)
+    return x + y, counts
+
+
+def _blockwise_attention(q, kr, vr, block: int = _SCORES_MAX_SEQ):
+    """Causal attention over [N,S,H,D] (heads repeated) without the
+    [S,S] scores: the flash kernel where it runs (ops/flash.py), else
+    the scores of one block of query rows at a time."""
+    if flash_supported(q.shape[-1]):
+        return flash_attention_bshk(q, kr, vr)
+    n, s, h, d = q.shape
+    pad = (-s) % block
+    qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+
+    def rows(i):
+        qb = jax.lax.dynamic_slice_in_dim(qp, i * block, block, axis=1)
+        scores = (jnp.einsum("bshk,bthk->bhst", qb, kr)
+                  / jnp.sqrt(d)).astype(jnp.float32)
+        seen = (jnp.arange(s)[None, :]
+                <= i * block + jnp.arange(block)[:, None])
+        probs = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30),
+                               axis=-1).astype(q.dtype)
+        return jnp.einsum("bhst,bthk->bshk", probs, vr)
+
+    out = jax.lax.map(rows, jnp.arange((s + pad) // block))
+    return jnp.moveaxis(out, 0, 1).reshape(n, s + pad, h, d)[:, :s]
+
+
+def _prefill_attention(a, cfg: DecoderConfig, h, positions):
+    """Softmax attention over a bucket: h [N,S,Dm] (normed) -> (out
+    [N,S,Dm] before the residual, k, v [N,S,KV,D])."""
+    q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(cfg.dtype))
+    k = jnp.einsum("bsd,dhk->bshk", h, a["wk"].astype(cfg.dtype))
+    v = jnp.einsum("bsd,dhk->bshk", h, a["wv"].astype(cfg.dtype))
+    if cfg.rope_theta is not None:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    rep = cfg.n_heads // cfg.n_kv_heads
+    kr = jnp.repeat(k, rep, axis=2)
+    vr = jnp.repeat(v, rep, axis=2)
+    s = h.shape[1]
+    if s > _SCORES_MAX_SEQ:
+        attn = _blockwise_attention(q, kr, vr)
+    else:
+        mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
+        scores = (jnp.einsum("bshk,bthk->bhst", q, kr)
+                  / jnp.sqrt(cfg.head_dim))
+        scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+        attn = jnp.einsum("bhst,bthk->bshk", probs, vr)
+    if cfg.attn_gate:
+        attn = attn * jax.nn.sigmoid(jnp.einsum(
+            "bsd,dhk->bshk", h, a["w_gate"].astype(cfg.dtype)))
+    return jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(cfg.dtype)), k, v
+
+
+def _delta_rule_inputs(a, cfg: DecoderConfig, h, mixed):
+    """What the recurrence takes, from the normed input h [..., d] and
+    the convolved, activated projections ``mixed`` [..., H, 2dk+dv]:
+    (q, k, v, g, beta), float32."""
+    dk = cfg.dr_key_dim
+    f32 = jnp.float32
+    q = kda.l2norm(mixed[..., :dk]) * dk ** -0.5
+    k = kda.l2norm(mixed[..., dk:2 * dk])
+    v = mixed[..., 2 * dk:].astype(f32)
+    low = jnp.einsum("...d,dr->...r", h, a["w_f_down"].astype(cfg.dtype))
+    step = jnp.einsum("...r,rhk->...hk", low,
+                      a["w_f_up"].astype(cfg.dtype)).astype(f32)
+    g = -jnp.exp(a["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+        step + a["dt_bias"].astype(f32))
+    beta = 2.0 * jax.nn.sigmoid(jnp.einsum(
+        "...d,dh->...h", h, a["w_beta"].astype(cfg.dtype)).astype(f32))
+    return q, k, v, g, beta
+
+
+def _delta_rule_output(a, cfg: DecoderConfig, h, o):
+    """o [..., H, dv] float32 -> the layer's output [..., d]: a norm a
+    head, the low-rank sigmoid gate, the output projection."""
+    f32 = jnp.float32
+    o = (o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                           + cfg.norm_eps) * a["o_norm"].astype(f32))
+    low = jnp.einsum("...d,dr->...r", h, a["w_g_down"].astype(cfg.dtype))
+    gate = jax.nn.sigmoid(jnp.einsum(
+        "...r,rhv->...hv", low, a["w_g_up"].astype(cfg.dtype)).astype(f32))
+    return jnp.einsum("...hv,hvd->...d", (o * gate).astype(cfg.dtype),
+                      a["wo"].astype(cfg.dtype))
+
+
+def _delta_rule_projections(a, cfg: DecoderConfig, h):
+    """(q|k|v projections side by side a head, flattened to channels
+    [..., H*(2dk+dv)]; the convolution's weights [K, channels])."""
+    qkv = jnp.concatenate(
+        [jnp.einsum("...d,dhk->...hk", h, a[w].astype(cfg.dtype))
+         for w in ("wq", "wk", "wv")], axis=-1)
+    w = jnp.concatenate([a["conv_q"], a["conv_k"], a["conv_v"]], axis=-1)
+    return (qkv.reshape(qkv.shape[:-2] + (-1,)),
+            w.reshape(w.shape[0], -1).astype(jnp.float32))
+
+
+# positions of a launch that a delta_rule layer works on at a time: its
+# float32 intermediates (24,576 channels a position at the published
+# widths) are held for one segment, not for the bucket
+_DELTA_RULE_SEGMENT = 2048
+
+
+def _prefill_delta_rule(a, cfg: DecoderConfig, h, plens):
+    """The recurrent mixer over a bucket: h [N,S,Dm] (normed), plens
+    [N] valid positions a row -> (out [N,S,Dm], state [N,H,dk,dv]
+    float32 after position plens-1, tail [N,K-1,channels] of
+    projections before position plens). Positions past a row's length
+    leave its state alone. The bucket goes a segment of positions at a
+    time, state and convolution tail carried from one to the next."""
+    n, s, d = h.shape
+    taps = cfg.dr_conv
+    seg = min(s, max(64, _DELTA_RULE_SEGMENT // n))
+    pad = (-s) % seg
+    hp = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+    segments = jnp.moveaxis(hp.reshape(n, -1, seg, d), 1, 0)
+
+    def segment(carry, xs):
+        state, tail = carry
+        hs, start = xs
+        flat, w = _delta_rule_projections(a, cfg, hs)
+        mixed = jax.nn.silu(kda.short_conv(flat.astype(jnp.float32), w,
+                                           tail))
+        q, k, v, g, beta = _delta_rule_inputs(
+            a, cfg, hs, mixed.reshape(n, seg, cfg.dr_heads,
+                                      cfg.dr_channels))
+        g, beta = kda.pad_mask(g, beta, plens - start)
+        o, state = kda.kda_chunked(q, k, v, g, beta, state)
+        return ((state, flat[:, seg - (taps - 1):]),
+                _delta_rule_output(a, cfg, hs, o))
+
+    with jax.named_scope("kda"):
+        zero = (jnp.zeros((n, cfg.dr_heads, cfg.dr_key_dim,
+                           cfg.dr_value_dim), jnp.float32),
+                jnp.zeros((n, taps - 1, cfg.dr_heads * cfg.dr_channels),
+                          cfg.dtype))
+        (state, _), out = jax.lax.scan(
+            segment, zero,
+            (segments, jnp.arange(segments.shape[0]) * seg))
+        out = jnp.moveaxis(out, 0, 1).reshape(n, s + pad, d)[:, :s]
+        # what the convolution of position plens needs: the
+        # projections of the row's last K-1 inputs
+        tail, _ = _delta_rule_projections(
+            a, cfg, kda.conv_tail(h, plens, taps))
+        return out, state, tail
+
+
+def _decode_delta_rule(a, cfg: DecoderConfig, h, state, tail, live):
+    """One position a slot: h [B,Dm] (normed). Slots that are not
+    ``live`` keep their state and tail as they are."""
+    with jax.named_scope("kda"):
+        flat, w = _delta_rule_projections(a, cfg, h)
+        mixed, new_tail = kda.short_conv_step(
+            flat.astype(jnp.float32), w, tail)
+        q, k, v, g, beta = _delta_rule_inputs(
+            a, cfg, h, jax.nn.silu(mixed).reshape(
+                h.shape[0], cfg.dr_heads, cfg.dr_channels))
+        o, new_state = kda.kda_step(q, k, v, g, beta, state)
+        out = _delta_rule_output(a, cfg, h, o)
+    with jax.named_scope("kda_state"):
+        state = jnp.where(live[:, None, None, None], new_state, state)
+        tail = jnp.where(live[:, None, None], new_tail.astype(tail.dtype),
+                         tail)
+    return out, state, tail
+
+
+def _prefill_layer(p, cfg: DecoderConfig, spec: LayerSpec, x, positions,
+                   plens, valid):
+    """One layer over a bucket [N,S,Dm]. Returns (x_out, what the layer
+    keeps for decoding: (k, v) [N,S,KV,D] or (state, tail); picks a
+    held expert or None)."""
+    if spec.mixer == "attention":
+        with jax.named_scope("gqa" if cfg.attn_gate else "attn"):
+            h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
+            out, k, v = _prefill_attention(p["Attention_0"], cfg, h,
+                                           positions)
+            x = x + out
+        kept = (k, v)
+    else:
+        h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
+        out, state, tail = _prefill_delta_rule(p["DeltaRule_0"], cfg, h,
+                                               plens)
+        x = x + out
+        kept = (state, tail)
+    x, counts = _feed_forward(p, cfg, spec, x, valid)
+    return x, kept, counts
+
+
+def _decode_layer(p, cfg: DecoderConfig, spec: LayerSpec, x, kept,
+                  page_table, seq_lens, live):
+    """Single-token decode for one layer over [B,Dm]. ``kept`` is the
+    layer's (k_pages, v_pages), to which this token's K/V are appended
+    (seq_lens = cache length BEFORE the token = the token's position),
+    or its (state, tail). Returns (x_out, kept, picks a held expert or
+    None)."""
+    if spec.mixer == "attention":
+        a = p["Attention_0"]
+        k_pages, v_pages = kept
+        scope = "gqa" if cfg.attn_gate else "attn"
+        with jax.named_scope(scope):
+            h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
+            q = jnp.einsum("bd,dhk->bhk", h, a["wq"].astype(cfg.dtype))
+            k = jnp.einsum("bd,dhk->bhk", h, a["wk"].astype(cfg.dtype))
+            v = jnp.einsum("bd,dhk->bhk", h, a["wv"].astype(cfg.dtype))
+            if cfg.rope_theta is not None:
+                # rope over a length-1 "sequence" per slot
+                q = rope(q[:, None], seq_lens[:, None],
+                         cfg.rope_theta)[:, 0]
+                k = rope(k[:, None], seq_lens[:, None],
+                         cfg.rope_theta)[:, 0]
+        with jax.named_scope("kv_append"):
+            k_pages, v_pages = append_token_kv(k_pages, v_pages, k, v,
+                                               page_table, seq_lens)
+        with jax.named_scope(scope):
+            out = paged_attention_auto(q, k_pages, v_pages, page_table,
+                                       seq_lens + 1)
+            if cfg.attn_gate:
+                out = out * jax.nn.sigmoid(jnp.einsum(
+                    "bd,dhk->bhk", h, a["w_gate"].astype(cfg.dtype)))
+            x = x + jnp.einsum("bhk,hkd->bd", out.astype(cfg.dtype),
+                               a["wo"].astype(cfg.dtype))
+        kept = (k_pages, v_pages)
+    else:
+        h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
+        out, state, tail = _decode_delta_rule(p["DeltaRule_0"], cfg, h,
+                                              *kept, live)
+        x = x + out
+        kept = (state, tail)
+    valid = live if live is not None else jnp.ones(x.shape[:1], bool)
+    x, counts = _feed_forward(p, cfg, spec, x, valid)
+    return x, kept, counts
+
+
+def _head(params, cfg: DecoderConfig, x, spec: str):
+    """Final norm and output head over x [..., d]; ``spec`` is the
+    einsum of hidden and head matrix [V, d]."""
+    with jax.named_scope("head"):
+        table = params["embedding" if cfg.tie_embeddings else "lm_head"]
+        x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps)
+        logits = jnp.einsum(spec, x, table.astype(cfg.dtype))
+        return logits.astype(jnp.float32)
+
+
+def _sum_counts(counts):
+    counts = [c for c in counts if c is not None]
+    return sum(counts[1:], counts[0]) if counts else None
+
+
+def _prefill_hidden(params, cfg: DecoderConfig, tokens, plens=None,
+                    rows=None):
+    """tokens [N,S] (padded to a bucket) -> (hidden [N,S,Dm] before the
+    final norm; what each layer keeps, a list by layer; picks a held
+    expert summed over the layers, or None). ``plens`` [N] are the
+    rows' valid lengths (the whole bucket when None) and ``rows`` [N]
+    marks the rows that are requests: what lies past a length or in a
+    dummy row neither touches a state nor counts as a pick."""
+    n, s = tokens.shape
+    with jax.named_scope("embed"):
+        x = params["embedding"].astype(cfg.dtype)[tokens]
+    positions = jnp.arange(s)[None, :]
+    if plens is None:
+        plens = jnp.full((n,), s, jnp.int32)
+    valid = positions < plens[:, None]
+    if rows is not None:
+        valid = valid & rows[:, None]
+    kept, counts = [], []
+    for i, spec in enumerate(cfg.layers):
+        x, keep, c = _prefill_layer(params[f"layer_{i}"], cfg, spec, x,
+                                    positions, plens, valid)
+        kept.append(keep)
+        counts.append(c)
+    return x, kept, _sum_counts(counts)
+
+
+# ----------------------------------------------------------------------
+# the cache: what the layers keep between steps, one entry a layer
+# ----------------------------------------------------------------------
+
+def init_cache(cfg: DecoderConfig, icfg) -> tuple:
+    """The empty cache of ``cfg`` for an engine of ``icfg`` (its
+    ``num_pages``, ``page_size`` and ``batch_size``): a tuple with one
+    entry a layer. An attention layer keeps ``(k_pages, v_pages)``, each
+    [num_pages, KV, page_size, D] in the model's dtype; a delta-rule
+    layer keeps, for each slot, ``(state, tail)``: its state [B, H, dk,
+    dv] in float32 and the last K-1 inputs of its short convolution
+    [B, K-1, channels]."""
+    def entry(spec):
+        if spec.mixer == "attention":
+            pool = (icfg.num_pages, cfg.n_kv_heads, icfg.page_size,
+                    cfg.head_dim)
+            return jnp.zeros(pool, cfg.dtype), jnp.zeros(pool, cfg.dtype)
+        return (jnp.zeros((icfg.batch_size, cfg.dr_heads, cfg.dr_key_dim,
+                           cfg.dr_value_dim), jnp.float32),
+                jnp.zeros((icfg.batch_size, cfg.dr_conv - 1,
+                           cfg.dr_heads * cfg.dr_channels), cfg.dtype))
+
+    return tuple(entry(spec) for spec in cfg.layers)
+
+
+def _write_kept(spec: LayerSpec, entry, kept, slots, pages):
+    """What a layer kept of a launch's rows into its cache entry: row
+    r's keys and values [S,KV,D] go to the pages ``pages[r]``, its final
+    state and tail to slot ``slots[r]`` whole (nothing of the slot's
+    previous tenant survives; a dummy row's slot is out of bounds and
+    its scatter is dropped)."""
+    if spec.mixer == "attention":
+        k_pages, v_pages = entry
+        with jax.named_scope("kv_append"):
+            for r in range(pages.shape[0]):
+                k_pages, v_pages = write_prefill_kv(
+                    k_pages, v_pages, kept[0][r], kept[1][r], pages[r])
+        return k_pages, v_pages
+    with jax.named_scope("kda_state"):
+        return tuple(held.at[slots].set(new)
+                     for held, new in zip(entry, kept))
+
+
+def prefill_cached(params, cfg: DecoderConfig, cache, tokens, plens, slots,
+                   pages, requests):
+    """One prefill launch into the cache: tokens [N,S] (padded to a
+    bucket), plens [N] the rows' lengths, slots [N] and pages [N,
+    ceil(S/page_size)] where each row's state and its keys and values
+    go, requests [N] bool the rows that are requests and not padding.
+    Only a row's last position goes through the head. Returns (logits
+    [N,V] f32 at each row's last position, cache, picks a held expert
+    summed over the layers or None)."""
+    x, kept, counts = _prefill_hidden(params, cfg, tokens, plens, requests)
+    cache = tuple(_write_kept(spec, entry, keep, slots, pages)
+                  for spec, entry, keep in zip(cfg.layers, cache, kept))
+    last = x[jnp.arange(tokens.shape[0]), plens - 1]
+    return _head(params, cfg, last, "bd,vd->bv"), cache, counts
+
+
+def import_kv(cfg: DecoderConfig, cache, k_seq, v_seq, pages):
+    """Write one sequence's keys and values ([L,S,KV,D] over the layers
+    of ``cfg.kv_layers``, as ``prefill`` hands them on) into ``pages`` of
+    those layers' pools."""
+    cache = list(cache)
+    with jax.named_scope("kv_append"):
+        for j, i in enumerate(cfg.kv_layers):
+            cache[i] = write_prefill_kv(*cache[i], k_seq[j], v_seq[j], pages)
+    return tuple(cache)
+
+
+def decode_step_cached(params, cfg: DecoderConfig, tokens, cache, page_table,
+                       seq_lens, live):
+    """One continuous-batching step: tokens [B] int32 (last emitted or
+    last prompt token per slot), ``seq_lens`` [B] the cache length
+    before the token, ``live`` [B] bool the slots that hold a request
+    (None: every slot). Returns (next_logits [B,V] f32, cache, picks a
+    held expert or None)."""
+    with jax.named_scope("embed"):
+        x = params["embedding"].astype(cfg.dtype)[tokens]      # [B, Dm]
+    new_cache, counts = [], []
+    for i, (spec, kept) in enumerate(zip(cfg.layers, cache)):
+        x, kept, c = _decode_layer(params[f"layer_{i}"], cfg, spec, x, kept,
+                                   page_table, seq_lens, live)
+        new_cache.append(kept)
+        counts.append(c)
+    logits = _head(params, cfg, x, "bd,vd->bv")
+    return logits, tuple(new_cache), _sum_counts(counts)
+
+
+def decode_chunk_cached(params, cfg: DecoderConfig, tokens, cache, page_table,
+                        seq_lens, live, *, n_steps: int):
+    """n_steps greedy decode steps in ONE jitted program (lax.scan with
+    argmax feedback). Returns (tokens [n_steps, B] int32, next_tokens
+    [B], next_lens [B], cache, picks a held expert over the chunk's
+    steps or None): the feedback state comes back as DEVICE arrays so
+    the engine can chain chunks without a host round trip: chunks
+    pipeline asynchronously and the host syncs only when a burst
+    ends."""
+    def body(carry, _):
+        toks, kept, lens, total = carry
+        logits, kept, counts = decode_step_cached(
+            params, cfg, toks, kept, page_table, lens, live)
+        with jax.named_scope("head"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if counts is not None:
+            total = total + counts
+        return (nxt, kept, lens + 1, total), nxt
+
+    total = (jnp.zeros((cfg.n_experts_held,), jnp.int32)
+             if cfg.moe_layers else None)
+    (toks, cache, lens, total), outs = jax.lax.scan(
+        body, (tokens, cache, seq_lens, total), None, length=n_steps)
+    return outs, toks, lens, cache, total
+
+
+# ----------------------------------------------------------------------
+# the same forward for callers that hold the keys and values themselves
+# (chip_smoke.py, the benchmark's compile checks): models whose every
+# mixer is attention, since one with recurrent state has more to hand
+# on than keys and values, and goes through the engine
+# ----------------------------------------------------------------------
+
+def _attention_only(cfg, who: str) -> DecoderConfig:
+    cfg = describe(cfg)
+    if cfg.state_layers:
+        raise ValueError(f"{who} carries keys and values only; this model "
+                         f"keeps recurrent state too")
+    return cfg
+
+
+def prefill_batch(params: Dict[str, Any], cfg, tokens: jnp.ndarray):
+    """tokens [N,S] (padded to a bucket) -> (logits [N,S,V] f32,
+    k_seq/v_seq [L,N,S,KV,D]) — N prompts prefill in one program."""
+    cfg = _attention_only(cfg, "prefill_batch")
+    x, kept, _ = _prefill_hidden(params, cfg, tokens)
+    k_seq, v_seq = zip(*kept)
+    return (_head(params, cfg, x, "bsd,vd->bsv"), jnp.stack(k_seq),
+            jnp.stack(v_seq))
+
+
+def prefill(params: Dict[str, Any], cfg, tokens: jnp.ndarray):
+    """tokens [1,S] (padded to a bucket) -> (logits [S,V] f32,
+    k_seq/v_seq [L,S,KV,D])."""
+    logits, ks, vs = prefill_batch(params, cfg, tokens)
+    return logits[0], ks[:, 0], vs[:, 0]
+
+
+def decode_step(params: Dict[str, Any], cfg, tokens: jnp.ndarray,
+                k_pages, v_pages, page_table: jnp.ndarray,
+                seq_lens: jnp.ndarray):
+    """``decode_step_cached`` over per-layer TUPLES of [P,KV,page,D]
+    pools. Returns (next_logits [B,V] f32, k_pages, v_pages)."""
+    cfg = _attention_only(cfg, "decode_step")
+    logits, cache, _ = decode_step_cached(
+        params, cfg, tokens, tuple(zip(k_pages, v_pages)), page_table,
+        seq_lens, None)
+    return (logits, *zip(*cache))
+
+
+def decode_chunk(params: Dict[str, Any], cfg, tokens: jnp.ndarray,
+                 k_pages, v_pages, page_table: jnp.ndarray,
+                 seq_lens: jnp.ndarray, *, n_steps: int):
+    """``decode_chunk_cached`` over per-layer tuples of pools. Returns
+    (tokens [n_steps, B] int32, next_tokens [B], next_lens [B], k_pages,
+    v_pages)."""
+    cfg = _attention_only(cfg, "decode_chunk")
+    outs, toks, lens, cache, _ = decode_chunk_cached(
+        params, cfg, tokens, tuple(zip(k_pages, v_pages)), page_table,
+        seq_lens, None, n_steps=n_steps)
+    return (outs, toks, lens, *zip(*cache))

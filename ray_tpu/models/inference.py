@@ -10,15 +10,16 @@ ops/paged_attention.py (arXiv:2604.15464 pattern, PAPERS.md), prefill
 jits per prompt-length bucket so compile count stays bounded, and all
 ragged-ness lives in page tables + sequence lengths (data, not shapes).
 
-Weights are the flagship Transformer's (models/transformer.py) taken
-as-is — the same param tree a Train run produces serves directly; a
-parity test pins this functional forward to the flax module's output.
-A model whose layers differ in kind (models/decoder.py: attention or a
-delta-rule recurrence, dense or expert feed-forward) is served by the
-same engine: pools for the layers that keep keys and values, a float32
-state and a convolution tail a slot for those that keep a recurrence,
-written whole by a prefill launch and updated for live slots by a
-decode step.
+Three modules, arrows one way: serve/llm.py (deployments) -> this file
+(requests, slots, the page allocator, admission, prefill launches,
+decode bursts, the one fetch a burst, spans and counters) ->
+models/decoder_forward.py (what the model described in
+models/decoder.py computes, and the cache of what its layers keep
+between steps) -> ops/. The engine builds one family of programs for
+every description and holds the cache as one donated value it never
+looks into; what a description has no use for (the live-slot mask of a
+dense decoder, the picks of a model without experts) falls away while
+tracing.
 
     engine = InferenceEngine(params, model_cfg, InferenceConfig(...))
     fut = engine.submit([1, 2, 3], max_new_tokens=16)
@@ -28,6 +29,7 @@ decode step.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import queue
 import threading
@@ -40,13 +42,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import spans, trace_plane
-from ray_tpu.models.decoder import DecoderConfig, LayerSpec, describe
-from ray_tpu.models.transformer import _flash_supported, _rope
-from ray_tpu.ops import kda
-from ray_tpu.ops.moe import experts_held, route_topk
-from ray_tpu.ops.paged_attention import (append_token_kv,
-                                         paged_attention_auto,
-                                         write_prefill_kv)
+from ray_tpu.models import decoder_forward as forward
+from ray_tpu.models.decoder import describe
+from ray_tpu.models.decoder_forward import (decode_chunk,  # noqa: F401
+                                            decode_step, prefill,
+                                            prefill_batch)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,452 +69,6 @@ class InferenceConfig:
     def max_context(self) -> int:
         return self.page_size * self.max_pages_per_seq
 
-
-# ----------------------------------------------------------------------
-# functional forward over the param tree (models/decoder.py says which
-# tree a description stands for; the dense decoder's is the flax
-# module's). Every choice between kinds of layer is made while tracing.
-# ----------------------------------------------------------------------
-
-# a row longer than this never materialises its [S,S] scores
-_SCORES_MAX_SEQ = 512
-
-
-def _rms(x, scale, eps):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
-                   keepdims=True)
-    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
-            * scale).astype(x.dtype)
-
-
-def _mlp(p, x, dtype):
-    h = (jax.nn.silu(x @ p["w_gate"].astype(dtype))
-         * (x @ p["w_up"].astype(dtype)))
-    return h @ p["w_down"].astype(dtype)
-
-
-def _experts(m, cfg: DecoderConfig, x, valid):
-    """The expert feed-forward over x [..., d] (normed): routed experts
-    held here plus the shared one. Returns (y, picks a held expert
-    [E_held] int32, of the ``valid`` tokens)."""
-    t = x.reshape(-1, x.shape[-1])
-    dt = cfg.dtype
-    with jax.named_scope("moe_route"):
-        ids, weights = route_topk(t, m["router"], cfg.experts_per_token)
-    with jax.named_scope("moe_experts"):
-        y, counts = experts_held(
-            t, ids, weights, m["w_gate"].astype(dt), m["w_up"].astype(dt),
-            m["w_down"].astype(dt), cfg.experts_held[0],
-            valid.reshape(-1))
-    if cfg.d_shared:
-        with jax.named_scope("moe_shared"):
-            y = y + _mlp(m["shared"], t, dt)
-    return y.reshape(x.shape), counts
-
-
-def _feed_forward(p, cfg: DecoderConfig, spec: LayerSpec, x, valid):
-    """x + FFN(norm(x)) of one layer; the picks a held expert, or
-    None."""
-    if spec.ffn == "dense":
-        with jax.named_scope("mlp"):
-            return x + _mlp(p["MLP_0"], _rms(x, p["RMSNorm_1"]["scale"],
-                                             cfg.norm_eps), cfg.dtype), None
-    y, counts = _experts(p["MoE_0"], cfg,
-                         _rms(x, p["RMSNorm_1"]["scale"], cfg.norm_eps),
-                         valid)
-    return x + y, counts
-
-
-def _blockwise_attention(q, kr, vr, block: int = _SCORES_MAX_SEQ):
-    """Causal attention over [N,S,H,D] (heads repeated) without the
-    [S,S] scores: the flash kernel where it runs (ops/flash.py), else
-    the scores of one block of query rows at a time."""
-    if _flash_supported(q.shape[-1]):
-        from ray_tpu.ops.flash import flash_attention_bshk
-
-        return flash_attention_bshk(q, kr, vr)
-    n, s, h, d = q.shape
-    pad = (-s) % block
-    qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-
-    def rows(i):
-        qb = jax.lax.dynamic_slice_in_dim(qp, i * block, block, axis=1)
-        scores = (jnp.einsum("bshk,bthk->bhst", qb, kr)
-                  / jnp.sqrt(d)).astype(jnp.float32)
-        seen = (jnp.arange(s)[None, :]
-                <= i * block + jnp.arange(block)[:, None])
-        probs = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30),
-                               axis=-1).astype(q.dtype)
-        return jnp.einsum("bhst,bthk->bshk", probs, vr)
-
-    out = jax.lax.map(rows, jnp.arange((s + pad) // block))
-    return jnp.moveaxis(out, 0, 1).reshape(n, s + pad, h, d)[:, :s]
-
-
-def _prefill_attention(a, cfg: DecoderConfig, h, positions):
-    """Softmax attention over a bucket: h [N,S,Dm] (normed) -> (out
-    [N,S,Dm] before the residual, k, v [N,S,KV,D])."""
-    q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(cfg.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, a["wk"].astype(cfg.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, a["wv"].astype(cfg.dtype))
-    if cfg.rope_theta is not None:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-    rep = cfg.n_heads // cfg.n_kv_heads
-    kr = jnp.repeat(k, rep, axis=2)
-    vr = jnp.repeat(v, rep, axis=2)
-    s = h.shape[1]
-    if s > _SCORES_MAX_SEQ:
-        attn = _blockwise_attention(q, kr, vr)
-    else:
-        mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
-        scores = (jnp.einsum("bshk,bthk->bhst", q, kr)
-                  / jnp.sqrt(cfg.head_dim))
-        scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum("bhst,bthk->bshk", probs, vr)
-    if cfg.attn_gate:
-        attn = attn * jax.nn.sigmoid(jnp.einsum(
-            "bsd,dhk->bshk", h, a["w_gate"].astype(cfg.dtype)))
-    return jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(cfg.dtype)), k, v
-
-
-def _delta_rule_inputs(a, cfg: DecoderConfig, h, mixed):
-    """What the recurrence takes, from the normed input h [..., d] and
-    the convolved, activated projections ``mixed`` [..., H, 2dk+dv]:
-    (q, k, v, g, beta), float32."""
-    dk = cfg.dr_key_dim
-    f32 = jnp.float32
-    q = kda.l2norm(mixed[..., :dk]) * dk ** -0.5
-    k = kda.l2norm(mixed[..., dk:2 * dk])
-    v = mixed[..., 2 * dk:].astype(f32)
-    low = jnp.einsum("...d,dr->...r", h, a["w_f_down"].astype(cfg.dtype))
-    step = jnp.einsum("...r,rhk->...hk", low,
-                      a["w_f_up"].astype(cfg.dtype)).astype(f32)
-    g = -jnp.exp(a["A_log"].astype(f32))[:, None] * jax.nn.softplus(
-        step + a["dt_bias"].astype(f32))
-    beta = 2.0 * jax.nn.sigmoid(jnp.einsum(
-        "...d,dh->...h", h, a["w_beta"].astype(cfg.dtype)).astype(f32))
-    return q, k, v, g, beta
-
-
-def _delta_rule_output(a, cfg: DecoderConfig, h, o):
-    """o [..., H, dv] float32 -> the layer's output [..., d]: a norm a
-    head, the low-rank sigmoid gate, the output projection."""
-    f32 = jnp.float32
-    o = (o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                           + cfg.norm_eps) * a["o_norm"].astype(f32))
-    low = jnp.einsum("...d,dr->...r", h, a["w_g_down"].astype(cfg.dtype))
-    gate = jax.nn.sigmoid(jnp.einsum(
-        "...r,rhv->...hv", low, a["w_g_up"].astype(cfg.dtype)).astype(f32))
-    return jnp.einsum("...hv,hvd->...d", (o * gate).astype(cfg.dtype),
-                      a["wo"].astype(cfg.dtype))
-
-
-def _delta_rule_projections(a, cfg: DecoderConfig, h):
-    """(q|k|v projections side by side a head, flattened to channels
-    [..., H*(2dk+dv)]; the convolution's weights [K, channels])."""
-    qkv = jnp.concatenate(
-        [jnp.einsum("...d,dhk->...hk", h, a[w].astype(cfg.dtype))
-         for w in ("wq", "wk", "wv")], axis=-1)
-    w = jnp.concatenate([a["conv_q"], a["conv_k"], a["conv_v"]], axis=-1)
-    return (qkv.reshape(qkv.shape[:-2] + (-1,)),
-            w.reshape(w.shape[0], -1).astype(jnp.float32))
-
-
-# positions of a launch that a delta_rule layer works on at a time: its
-# float32 intermediates (24,576 channels a position at the published
-# widths) are held for one segment, not for the bucket
-_DELTA_RULE_SEGMENT = 2048
-
-
-def _prefill_delta_rule(a, cfg: DecoderConfig, h, plens):
-    """The recurrent mixer over a bucket: h [N,S,Dm] (normed), plens
-    [N] valid positions a row -> (out [N,S,Dm], state [N,H,dk,dv]
-    float32 after position plens-1, tail [N,K-1,channels] of
-    projections before position plens). Positions past a row's length
-    leave its state alone. The bucket goes a segment of positions at a
-    time, state and convolution tail carried from one to the next."""
-    n, s, d = h.shape
-    taps = cfg.dr_conv
-    seg = min(s, max(64, _DELTA_RULE_SEGMENT // n))
-    pad = (-s) % seg
-    hp = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
-    segments = jnp.moveaxis(hp.reshape(n, -1, seg, d), 1, 0)
-
-    def segment(carry, xs):
-        state, tail = carry
-        hs, start = xs
-        flat, w = _delta_rule_projections(a, cfg, hs)
-        mixed = jax.nn.silu(kda.short_conv(flat.astype(jnp.float32), w,
-                                           tail))
-        q, k, v, g, beta = _delta_rule_inputs(
-            a, cfg, hs, mixed.reshape(n, seg, cfg.dr_heads,
-                                      cfg.dr_channels))
-        g, beta = kda.pad_mask(g, beta, plens - start)
-        o, state = kda.kda_chunked(q, k, v, g, beta, state)
-        return ((state, flat[:, seg - (taps - 1):]),
-                _delta_rule_output(a, cfg, hs, o))
-
-    with jax.named_scope("kda"):
-        zero = (jnp.zeros((n, cfg.dr_heads, cfg.dr_key_dim,
-                           cfg.dr_value_dim), jnp.float32),
-                jnp.zeros((n, taps - 1, cfg.dr_heads * cfg.dr_channels),
-                          cfg.dtype))
-        (state, _), out = jax.lax.scan(
-            segment, zero,
-            (segments, jnp.arange(segments.shape[0]) * seg))
-        out = jnp.moveaxis(out, 0, 1).reshape(n, s + pad, d)[:, :s]
-        # what the convolution of position plens needs: the
-        # projections of the row's last K-1 inputs
-        tail, _ = _delta_rule_projections(
-            a, cfg, kda.conv_tail(h, plens, taps))
-        return out, state, tail
-
-
-def _decode_delta_rule(a, cfg: DecoderConfig, h, state, tail, live):
-    """One position a slot: h [B,Dm] (normed). Slots that are not
-    ``live`` keep their state and tail as they are."""
-    with jax.named_scope("kda"):
-        flat, w = _delta_rule_projections(a, cfg, h)
-        mixed, new_tail = kda.short_conv_step(
-            flat.astype(jnp.float32), w, tail)
-        q, k, v, g, beta = _delta_rule_inputs(
-            a, cfg, h, jax.nn.silu(mixed).reshape(
-                h.shape[0], cfg.dr_heads, cfg.dr_channels))
-        o, new_state = kda.kda_step(q, k, v, g, beta, state)
-        out = _delta_rule_output(a, cfg, h, o)
-    with jax.named_scope("kda_state"):
-        state = jnp.where(live[:, None, None, None], new_state, state)
-        tail = jnp.where(live[:, None, None], new_tail.astype(tail.dtype),
-                         tail)
-    return out, state, tail
-
-
-def _prefill_layer(p, cfg: DecoderConfig, spec: LayerSpec, x, positions,
-                   plens, valid):
-    """One layer over a bucket [N,S,Dm]. Returns (x_out, what the layer
-    keeps for decoding: (k, v) [N,S,KV,D] or (state, tail); picks a
-    held expert or None)."""
-    if spec.mixer == "attention":
-        with jax.named_scope("gqa" if cfg.attn_gate else "attn"):
-            h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-            out, k, v = _prefill_attention(p["Attention_0"], cfg, h,
-                                           positions)
-            x = x + out
-        kept = (k, v)
-    else:
-        h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-        out, state, tail = _prefill_delta_rule(p["DeltaRule_0"], cfg, h,
-                                               plens)
-        x = x + out
-        kept = (state, tail)
-    x, counts = _feed_forward(p, cfg, spec, x, valid)
-    return x, kept, counts
-
-
-def _decode_layer(p, cfg: DecoderConfig, spec: LayerSpec, x, positions,
-                  kept, page_table, seq_lens, live):
-    """Single-token decode for one layer over [B,Dm]. ``kept`` is the
-    layer's (k_pages, v_pages), to which this token's K/V are appended
-    (seq_lens = cache length BEFORE the token), or its (state, tail).
-    Returns (x_out, kept, picks a held expert or None)."""
-    if spec.mixer == "attention":
-        a = p["Attention_0"]
-        k_pages, v_pages = kept
-        scope = "gqa" if cfg.attn_gate else "attn"
-        with jax.named_scope(scope):
-            h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-            q = jnp.einsum("bd,dhk->bhk", h, a["wq"].astype(cfg.dtype))
-            k = jnp.einsum("bd,dhk->bhk", h, a["wk"].astype(cfg.dtype))
-            v = jnp.einsum("bd,dhk->bhk", h, a["wv"].astype(cfg.dtype))
-            if cfg.rope_theta is not None:
-                # rope over a length-1 "sequence" per slot
-                q = _rope(q[:, None], positions[:, None],
-                          cfg.rope_theta)[:, 0]
-                k = _rope(k[:, None], positions[:, None],
-                          cfg.rope_theta)[:, 0]
-        with jax.named_scope("kv_append"):
-            k_pages, v_pages = append_token_kv(k_pages, v_pages, k, v,
-                                               page_table, seq_lens)
-        with jax.named_scope(scope):
-            out = paged_attention_auto(q, k_pages, v_pages, page_table,
-                                       seq_lens + 1)
-            if cfg.attn_gate:
-                out = out * jax.nn.sigmoid(jnp.einsum(
-                    "bd,dhk->bhk", h, a["w_gate"].astype(cfg.dtype)))
-            x = x + jnp.einsum("bhk,hkd->bd", out.astype(cfg.dtype),
-                               a["wo"].astype(cfg.dtype))
-        kept = (k_pages, v_pages)
-    else:
-        h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-        out, state, tail = _decode_delta_rule(p["DeltaRule_0"], cfg, h,
-                                              *kept, live)
-        x = x + out
-        kept = (state, tail)
-    valid = live if live is not None else jnp.ones(x.shape[:1], bool)
-    x, counts = _feed_forward(p, cfg, spec, x, valid)
-    return x, kept, counts
-
-
-def _head(params, cfg: DecoderConfig, x, spec: str):
-    """Final norm and output head over x [..., d]; ``spec`` is the
-    einsum of hidden and head matrix [V, d]."""
-    with jax.named_scope("head"):
-        table = params["embedding" if cfg.tie_embeddings else "lm_head"]
-        x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps)
-        logits = jnp.einsum(spec, x, table.astype(cfg.dtype))
-        return logits.astype(jnp.float32)
-
-
-def _sum_counts(counts):
-    counts = [c for c in counts if c is not None]
-    return sum(counts[1:], counts[0]) if counts else None
-
-
-def _prefill_hidden(params, cfg: DecoderConfig, tokens, plens=None,
-                    rows=None):
-    """tokens [N,S] (padded to a bucket) -> (hidden [N,S,Dm] before the
-    final norm; what each layer keeps, a list by layer; picks a held
-    expert summed over the layers, or None). ``plens`` [N] are the
-    rows' valid lengths (the whole bucket when None) and ``rows`` [N]
-    marks the rows that are requests: what lies past a length or in a
-    dummy row neither touches a state nor counts as a pick."""
-    n, s = tokens.shape
-    with jax.named_scope("embed"):
-        x = params["embedding"].astype(cfg.dtype)[tokens]
-    positions = jnp.arange(s)[None, :]
-    if plens is None:
-        plens = jnp.full((n,), s, jnp.int32)
-    valid = positions < plens[:, None]
-    if rows is not None:
-        valid = valid & rows[:, None]
-    kept, counts = [], []
-    for i, spec in enumerate(cfg.layers):
-        x, keep, c = _prefill_layer(params[f"layer_{i}"], cfg, spec, x,
-                                    positions, plens, valid)
-        kept.append(keep)
-        counts.append(c)
-    return x, kept, _sum_counts(counts)
-
-
-def prefill_batch(params: Dict[str, Any], cfg, tokens: jnp.ndarray):
-    """tokens [N,S] (padded to a bucket) -> (logits [N,S,V] f32,
-    k_seq/v_seq [L,N,S,KV,D]) — N prompts prefill in one program. For
-    models whose every mixer is attention (L counts their layers): one
-    with recurrent state has more to hand on than keys and values, and
-    goes through the engine."""
-    cfg = describe(cfg)
-    if cfg.state_layers:
-        raise ValueError("prefill_batch hands on keys and values only; "
-                         "this model keeps recurrent state too")
-    x, kept, _ = _prefill_hidden(params, cfg, tokens)
-    logits = _head(params, cfg, x, "bsd,vd->bsv")
-    return (logits, jnp.stack([k for k, _ in kept]),
-            jnp.stack([v for _, v in kept]))
-
-
-def prefill(params: Dict[str, Any], cfg, tokens: jnp.ndarray):
-    """tokens [1,S] (padded to a bucket) -> (logits [S,V] f32,
-    k_seq/v_seq [L,S,KV,D])."""
-    logits, ks, vs = prefill_batch(params, cfg, tokens)
-    return logits[0], ks[:, 0], vs[:, 0]
-
-
-def _decode_step(params, cfg: DecoderConfig, tokens, k_pages, v_pages,
-                 page_table, seq_lens, state, live):
-    """``decode_step`` for any description: ``k_pages``/``v_pages`` are
-    tuples over the attention layers, ``state`` a tuple of (state, tail)
-    over the delta_rule layers, ``live`` [B] bool (None: every slot).
-    Returns (logits, k_pages, v_pages, state, picks a held expert)."""
-    with jax.named_scope("embed"):
-        x = params["embedding"].astype(cfg.dtype)[tokens]      # [B, Dm]
-    positions = seq_lens                          # this token's position
-    pools = iter(zip(k_pages, v_pages))
-    states = iter(state)
-    new_k, new_v, new_state, counts = [], [], [], []
-    for i, spec in enumerate(cfg.layers):
-        attention = spec.mixer == "attention"
-        x, kept, c = _decode_layer(
-            params[f"layer_{i}"], cfg, spec, x, positions,
-            next(pools) if attention else next(states), page_table,
-            seq_lens, live)
-        if attention:
-            new_k.append(kept[0])
-            new_v.append(kept[1])
-        else:
-            new_state.append(kept)
-        counts.append(c)
-    logits = _head(params, cfg, x, "bd,vd->bv")
-    return (logits, tuple(new_k), tuple(new_v), tuple(new_state),
-            _sum_counts(counts))
-
-
-def decode_step(params: Dict[str, Any], cfg, tokens: jnp.ndarray,
-                k_pages: jnp.ndarray, v_pages: jnp.ndarray,
-                page_table: jnp.ndarray, seq_lens: jnp.ndarray):
-    """One continuous-batching step: tokens [B] int32 (last emitted or
-    last prompt token per slot), cache = per-layer TUPLES of
-    [P,KV,page,D] arrays (a pytree, never re-stacked: each layer's
-    append kernel takes its own pool as input and output of one buffer
-    under jit/scan; stacking into one [L,...] array would copy the
-    whole cache every step). Returns (next_logits [B,V] f32, k_pages,
-    v_pages). Models
-    whose every mixer is attention."""
-    cfg = describe(cfg)
-    if cfg.state_layers:
-        raise ValueError("decode_step carries keys and values only; "
-                         "this model keeps recurrent state too")
-    return _decode_step(params, cfg, tokens, k_pages, v_pages, page_table,
-                        seq_lens, (), None)[:3]
-
-
-def _decode_chunk(params, cfg: DecoderConfig, tokens, k_pages, v_pages,
-                  page_table, seq_lens, state, live, *, n_steps: int):
-    """``decode_chunk`` for any description. Returns (tokens [n_steps,
-    B], next_tokens, next_lens, k_pages, v_pages, state, picks a held
-    expert over the chunk's steps or None); for the dense decoder the
-    last two are empty and the program is ``decode_chunk``'s."""
-    def body(carry, _):
-        toks, kp, vp, lens, st, total = carry
-        logits, kp, vp, st, counts = _decode_step(
-            params, cfg, toks, kp, vp, page_table, lens, st, live)
-        with jax.named_scope("head"):
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if counts is not None:
-            total = total + counts
-        return (nxt, kp, vp, lens + 1, st, total), nxt
-
-    total = (jnp.zeros((cfg.n_experts_held,), jnp.int32)
-             if cfg.moe_layers else None)
-    carry, outs = jax.lax.scan(
-        body, (tokens, k_pages, v_pages, seq_lens, state, total), None,
-        length=n_steps)
-    toks, k_out, v_out, lens, state, total = carry
-    return outs, toks, lens, k_out, v_out, state, total
-
-
-def decode_chunk(params: Dict[str, Any], cfg, tokens: jnp.ndarray,
-                 k_pages: jnp.ndarray, v_pages: jnp.ndarray,
-                 page_table: jnp.ndarray, seq_lens: jnp.ndarray, *,
-                 n_steps: int):
-    """n_steps greedy decode steps in ONE jitted program (lax.scan with
-    argmax feedback). Returns (tokens [n_steps, B] int32, next_tokens
-    [B], next_lens [B], k_pages, v_pages): the feedback state comes
-    back as DEVICE arrays so the engine can chain chunks without a
-    host round trip: chunks pipeline asynchronously and the host syncs
-    only when a burst ends. Models whose every mixer is attention."""
-    cfg = describe(cfg)
-    if cfg.state_layers:
-        raise ValueError("decode_chunk carries keys and values only; "
-                         "this model keeps recurrent state too")
-    return _decode_chunk(params, cfg, tokens, k_pages, v_pages, page_table,
-                         seq_lens, (), None, n_steps=n_steps)[:5]
-
-
-# ----------------------------------------------------------------------
-# the engine
-# ----------------------------------------------------------------------
 
 def _program(name: str, fn, **jit_kwargs):
     """``jax.jit(fn)`` under a name of its own. A profile lists each
@@ -623,23 +177,22 @@ class InferenceEngine:
         self.params = params
         # a TransformerConfig or a DecoderConfig; the engine reads the
         # layer-by-layer description either way
-        self.mcfg = model_cfg = describe(model_cfg)
-        if model_cfg.state_layers and mode != "both":
+        self.mcfg = mcfg = describe(model_cfg)
+        if mcfg.state_layers and mode != "both":
             raise ValueError(
                 f"engine mode {mode!r} hands keys and values from a "
                 f"prefill replica to a decode replica; this model keeps "
-                f"recurrent state in layers {model_cfg.state_layers}, "
+                f"recurrent state in layers {mcfg.state_layers}, "
                 f"which the handoff does not carry: serve it in mode "
                 f"'both'")
         self.cfg = cfg
         self.mode = mode
-        L = len(model_cfg.kv_layers)       # layers with a page pool
-        KV, D = model_cfg.n_kv_heads, model_cfg.head_dim
-        # programs of a model with recurrent state or experts take the
-        # state and the live-slot mask as well, and hand back the picks
-        # a held expert; the dense decoder's programs are as they were
-        self._extras = bool(model_cfg.state_layers or model_cfg.moe_layers)
         self._idents = itertools.count()
+        self._queue: "queue.Queue[_Request]" = queue.Queue()
+        self._lock = threading.Lock()
+        self._shutdown = False
+        self.num_steps = 0
+        self.max_concurrent = 0
         # cumulative counts behind stats(): decode steps dispatched,
         # decode tokens that stayed in a request's output, bursts, and
         # by prefill bucket [launches, rows, useful rows, prompt tokens]
@@ -650,11 +203,9 @@ class InferenceEngine:
         # picks of routed experts by the tokens that counted (prompt
         # tokens, live slots' decode steps): all, and by held expert
         self._moe_picks_total = 0
-        self._moe_load = np.zeros(model_cfg.n_experts_held, np.int64)
-        self._state: Tuple[Tuple[Any, Any], ...] = ()
+        self._moe_load = np.zeros(mcfg.n_experts_held, np.int64)
         # single-prompt bucketed prompt pass for prefill_export;
         # compiles lazily per bucket on first use
-        mcfg = self.mcfg
         self._export_jits = {
             b: _program(f"engine_prefill_export_b{b}",
                         lambda p, t: prefill(p, mcfg, t))
@@ -662,51 +213,25 @@ class InferenceEngine:
         }
         if mode == "prefill":
             # everything decode-shaped is absent
+            self._cache = ()
             self._slots = []
             self._free_pages = []
-            self._queue = queue.Queue()
-            self._lock = threading.Lock()
-            self._shutdown = False
             self._thread = None
-            self.num_steps = 0
-            self.max_concurrent = 0
             return
-        # per-layer tuple (pytree), NOT a stacked [L,...] array: each
-        # layer's pool is written in place under the donated programs
-        # (a decode step's cells by append_token_kv's kernel, a
-        # launch's pages by write_prefill_kv's scatter)
-        self._k_pages = tuple(
-            jnp.zeros((cfg.num_pages, KV, cfg.page_size, D),
-                      model_cfg.dtype) for _ in range(L))
-        self._v_pages = tuple(
-            jnp.zeros((cfg.num_pages, KV, cfg.page_size, D),
-                      model_cfg.dtype) for _ in range(L))
+        # what the model's layers keep between steps (page pools, a
+        # state a slot: decoder_forward.init_cache), one pytree that
+        # every program below takes donated and hands back, so each
+        # step and each launch updates it in place on the device
+        self._cache = forward.init_cache(mcfg, cfg)
         # the LAST physical page is the parking page for idle decode
         # slots (their dummy K/V appends land there), never allocated
         self._free_pages: List[int] = list(range(cfg.num_pages - 1))
-        # a recurrent layer keeps, for each slot, its state in float32
-        # and the last inputs of its short convolution; a prefill
-        # launch overwrites a slot's, a decode step updates live slots'
-        self._state = tuple(
-            (jnp.zeros((cfg.batch_size, model_cfg.dr_heads,
-                        model_cfg.dr_key_dim, model_cfg.dr_value_dim),
-                       jnp.float32),
-             jnp.zeros((cfg.batch_size, model_cfg.dr_conv - 1,
-                        model_cfg.dr_heads * model_cfg.dr_channels),
-                       model_cfg.dtype))
-            for _ in model_cfg.state_layers)
         self._slots = [_Slot() for _ in range(cfg.batch_size)]
-        self._queue: "queue.Queue[_Request]" = queue.Queue()
-        self._lock = threading.Lock()
         self._wake = threading.Event()
-        self._shutdown = False
-        self.num_steps = 0
-        self.max_concurrent = 0
 
         # params are ARGUMENTS of the jitted programs, never closed-over
         # constants (a closure would bake every weight into the HLO as a
         # literal — catastrophic compile times at real model sizes).
-        # The cache is donated: each step updates it in place on device.
         # chunked decode programs (1, 2, 4, ... decode_chunk steps per
         # dispatch); the loop picks the largest chunk no active slot's
         # remaining budget forbids
@@ -715,29 +240,22 @@ class InferenceEngine:
         while n <= max(1, cfg.decode_chunk):
             self._chunk_sizes.append(n)
             n *= 2
-        self._decode_chunks = {}
-        for steps in self._chunk_sizes:
-            self._decode_chunks[steps] = _program(
+        self._decode_chunks = {
+            steps: _program(
                 f"engine_decode_n{steps}",
-                (lambda p, toks, kp, vp, table, lens, state, live, _n=steps:
-                 _decode_chunk(p, mcfg, toks, kp, vp, table, lens, state,
-                               live, n_steps=_n)) if self._extras else
-                (lambda p, toks, kp, vp, table, lens, _n=steps:
-                 _decode_chunk(p, mcfg, toks, kp, vp, table, lens, (),
-                               None, n_steps=_n)),
-                donate_argnums=(2, 3, 6) if self._extras else (2, 3))
+                lambda p, toks, cache, table, lens, live, _n=steps:
+                forward.decode_chunk_cached(p, mcfg, toks, cache, table,
+                                            lens, live, n_steps=_n),
+                donate_argnums=(2,))
+            for steps in self._chunk_sizes}
         # burst state rides ONE packed upload [B, 1 + max_pages]
         # (column 0 = seq_lens, rest = page table — one transfer
-        # instead of two); lens then EVOLVES
-        # on device across the burst's chained chunks while the table
-        # stays fixed
-        # (a slot is live when its length is not 0: a prompt has at
-        # least one token)
+        # instead of two); lens then EVOLVES on device across the
+        # burst's chained chunks while the table stays fixed. A slot is
+        # live when its length is not 0: a prompt has at least one token
         self._split_packed = _program(
             "engine_split_packed",
-            (lambda packed: (packed[:, 1:], packed[:, 0],
-                             packed[:, 0] > 0)) if self._extras else
-            (lambda packed: (packed[:, 1:], packed[:, 0])))
+            lambda packed: (packed[:, 1:], packed[:, 0], packed[:, 0] > 0))
 
         # BATCHED prefill: N admissions in one program behind ONE packed
         # upload. packed [N, 2 + bucket + n_prog] int32 rows of
@@ -745,63 +263,23 @@ class InferenceEngine:
         # rows carry slot_idx == batch_size, whose scatter is dropped
         # (out-of-bounds scatters drop) and whose pages point at the
         # parking page. N is _prefill_rows[bucket], so jit specializes
-        # once per bucket.
-        def prefill_write_many(p, packed, kp, vp, toks_vec, bucket):
+        # once per bucket. The first tokens go into the feedback
+        # vector, and the launch's picks a held expert (of a model with
+        # experts) ride behind them to the host.
+        def prefill_write(p, packed, cache, toks_vec, bucket):
             n_prog = -(-bucket // cfg.page_size)
             slots = packed[:, 0]
             plens = packed[:, 1]
             toks = packed[:, 2:2 + bucket]
             pages = packed[:, 2 + bucket:2 + bucket + n_prog]
-            logits, k_seq, v_seq = prefill_batch(p, mcfg, toks)
-            new_k, new_v = list(kp), list(vp)
-            n = packed.shape[0]
-            with jax.named_scope("kv_append"):
-                for i in range(len(new_k)):
-                    ki, vi = new_k[i], new_v[i]
-                    for r in range(n):
-                        ki, vi = write_prefill_kv(ki, vi, k_seq[i, r],
-                                                  v_seq[i, r], pages[r])
-                    new_k[i], new_v[i] = ki, vi
-            with jax.named_scope("head"):
-                row_logits = logits[jnp.arange(n), plens - 1]   # [N,V]
-                nxt = jnp.argmax(row_logits, axis=-1).astype(jnp.int32)
-                toks_vec = toks_vec.at[slots].set(nxt)
-            return nxt, toks_vec, tuple(new_k), tuple(new_v)
-
-        def prefill_write_extras(p, packed, kp, vp, toks_vec, state,
-                                 bucket):
-            """``prefill_write_many`` for a model with recurrent state
-            or experts: a row's final state and convolution tail go to
-            its slot whole (nothing of the slot's previous tenant
-            survives), only the row's last position goes through the
-            head, and the picks a held expert ride behind the first
-            tokens."""
-            n_prog = -(-bucket // cfg.page_size)
-            slots = packed[:, 0]
-            plens = packed[:, 1]
-            toks = packed[:, 2:2 + bucket]
-            pages = packed[:, 2 + bucket:2 + bucket + n_prog]
-            n = packed.shape[0]
-            x, kept, counts = _prefill_hidden(
-                p, mcfg, toks, plens, slots < cfg.batch_size)
-            new_k, new_v, new_state = list(kp), list(vp), []
-            with jax.named_scope("kv_append"):
-                for j, i in enumerate(mcfg.kv_layers):
-                    for r in range(n):
-                        new_k[j], new_v[j] = write_prefill_kv(
-                            new_k[j], new_v[j], kept[i][0][r],
-                            kept[i][1][r], pages[r])
-            with jax.named_scope("kda_state"):
-                for (st, tail), i in zip(state, mcfg.state_layers):
-                    new_state.append((st.at[slots].set(kept[i][0]),
-                                      tail.at[slots].set(kept[i][1])))
-            logits = _head(p, mcfg, x[jnp.arange(n), plens - 1], "bd,vd->bv")
+            logits, cache, counts = forward.prefill_cached(
+                p, mcfg, cache, toks, plens, slots, pages,
+                slots < cfg.batch_size)
             with jax.named_scope("head"):
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 toks_vec = toks_vec.at[slots].set(nxt)
             first = nxt if counts is None else jnp.concatenate([nxt, counts])
-            return (first, toks_vec, tuple(new_k), tuple(new_v),
-                    tuple(new_state))
+            return first, toks_vec, cache
 
         # every launch computes one budget of positions, that of a
         # single prompt in the largest bucket: a bucket b runs
@@ -813,12 +291,8 @@ class InferenceEngine:
         self._prefill_many = ({} if mode == "decode" else {
             b: _program(
                 f"engine_prefill_b{b}",
-                (lambda p, packed, kp, vp, toks_vec, state, _b=b:
-                 prefill_write_extras(p, packed, kp, vp, toks_vec, state,
-                                      _b)) if self._extras else
-                (lambda p, packed, kp, vp, toks_vec, _b=b:
-                 prefill_write_many(p, packed, kp, vp, toks_vec, _b)),
-                donate_argnums=(2, 3, 4, 5) if self._extras else (2, 3, 4))
+                functools.partial(prefill_write, bucket=b),
+                donate_argnums=(2, 3))
             for b in cfg.prefill_buckets
         })
 
@@ -828,19 +302,13 @@ class InferenceEngine:
         # decode-pool half of the disaggregated handoff. One request
         # per dispatch (handoffs arrive one at a time off the object
         # plane); jit specializes per bucket like prefill.
-        def kv_import_one(kp, vp, toks_vec, k_seq, v_seq, pages,
-                          slot_first):
-            new_k, new_v = list(kp), list(vp)
-            with jax.named_scope("kv_append"):
-                for i in range(len(new_k)):
-                    new_k[i], new_v[i] = write_prefill_kv(
-                        new_k[i], new_v[i], k_seq[i], v_seq[i], pages)
-            toks_vec = toks_vec.at[slot_first[0]].set(slot_first[1])
-            return toks_vec, tuple(new_k), tuple(new_v)
+        def kv_import_one(cache, toks_vec, k_seq, v_seq, pages, slot_first):
+            cache = forward.import_kv(mcfg, cache, k_seq, v_seq, pages)
+            return toks_vec.at[slot_first[0]].set(slot_first[1]), cache
 
         # one jit, respecialized per padded bucket shape
         self._kv_import = _program("engine_kv_import", kv_import_one,
-                                   donate_argnums=(0, 1, 2))
+                                   donate_argnums=(0, 1))
         # persistent device-resident feedback state: admission scatters
         # the prefill's next-token in WITHOUT a host read (a sync
         # stalls the dispatch pipeline; a dispatch does not)
@@ -1026,8 +494,9 @@ class InferenceEngine:
                 "moe_picks_total": self._moe_picks_total,
                 "moe_picks_local": int(self._moe_load.sum()),
                 "moe_load_by_expert": self._moe_load.tolist(),
-                "state_bytes": sum(a.nbytes for pair in self._state
-                                   for a in pair),
+                "state_bytes": sum(
+                    a.nbytes for i in self.mcfg.state_layers
+                    for a in jax.tree_util.tree_leaves(self._cache[i])),
                 "pool_tokens": (max(0, self.cfg.num_pages - 1)
                                 * self.cfg.page_size
                                 if self.mode != "prefill" else 0),
@@ -1159,16 +628,9 @@ class InferenceEngine:
                         prompt_tokens=prompt_tokens) as launch:
             launch.fields["prompt_lens"] = [len(req.prompt)
                                             for _, req, _ in group]
-            if self._extras:
-                (nxt, self._dev_toks, self._k_pages, self._v_pages,
-                 self._state) = self._prefill_many[bucket](
-                     self.params, jnp.asarray(packed), self._k_pages,
-                     self._v_pages, self._dev_toks, self._state)
-            else:
-                nxt, self._dev_toks, self._k_pages, self._v_pages = \
-                    self._prefill_many[bucket](
-                        self.params, jnp.asarray(packed), self._k_pages,
-                        self._v_pages, self._dev_toks)
+            nxt, self._dev_toks, self._cache = self._prefill_many[bucket](
+                self.params, jnp.asarray(packed), self._cache,
+                self._dev_toks)
         self._pending_firsts.append((nxt, rows, launch.fields))
 
     def _import_group(self, slot: _Slot, req: _Request,
@@ -1183,18 +645,17 @@ class InferenceEngine:
         bucket = next(b for b in sorted(self.cfg.prefill_buckets)
                       if b >= plen)
         n_prog = -(-bucket // self.cfg.page_size)
-        L = len(self.mcfg.kv_layers)
-        KV, D = self.mcfg.n_kv_heads, self.mcfg.head_dim
-        k_pad = np.zeros((L, bucket, KV, D), k.dtype)
-        v_pad = np.zeros((L, bucket, KV, D), v.dtype)
+        # k, v [L, plen, KV, D] over the layers that keep pages
+        k_pad = np.zeros((k.shape[0], bucket) + k.shape[2:], k.dtype)
+        v_pad = np.zeros((v.shape[0], bucket) + v.shape[2:], v.dtype)
         k_pad[:, :plen] = k
         v_pad[:, :plen] = v
         # pad rows past the prompt are DON'T-CARE (appends overwrite,
         # attention masks by seq_len); pages past the allocation park
         page_list = (pages + [self._parking_page] * n_prog)[:n_prog]
         slot_idx = self._slots.index(slot)
-        self._dev_toks, self._k_pages, self._v_pages = self._kv_import(
-            self._k_pages, self._v_pages, self._dev_toks,
+        self._dev_toks, self._cache = self._kv_import(
+            self._cache, self._dev_toks,
             jnp.asarray(k_pad), jnp.asarray(v_pad),
             jnp.asarray(np.asarray(page_list, np.int32)),
             jnp.asarray(np.asarray([slot_idx, first], np.int32)))
@@ -1272,7 +733,7 @@ class InferenceEngine:
         with spans.span("engine.dispatch", live_slots=len(active),
                         live_ctx_tokens=sum(s.seq_len for s in active)
                         ) as burst:
-            if self._state:
+            if self.mcfg.state_layers:
                 burst.fields["state_slots_live"] = len(active)
             pending = self._dispatch_burst(active)
             steps = sum(chunk for _, chunk, _ in pending)
@@ -1344,7 +805,7 @@ class InferenceEngine:
                 for j, p in enumerate(s.pages):
                     packed[i, 1 + j] = p
         dev_toks = self._dev_toks
-        dev_table, dev_lens, *dev_live = self._split_packed(
+        dev_table, dev_lens, dev_live = self._split_packed(
             jnp.asarray(packed))
 
         # async burst: dispatch chunks back-to-back WITHOUT reading
@@ -1368,11 +829,10 @@ class InferenceEngine:
                         if c >= remaining]
             chunk = (min(covering) if covering
                      else self._chunk_sizes[-1])
-            extras = (self._state, *dev_live) if self._extras else ()
-            (outs, dev_toks, dev_lens, self._k_pages, self._v_pages,
-             self._state, counts) = self._decode_chunks[chunk](
-                 self.params, dev_toks, self._k_pages, self._v_pages,
-                 dev_table, dev_lens, *extras)
+            outs, dev_toks, dev_lens, self._cache, counts = \
+                self._decode_chunks[chunk](
+                    self.params, dev_toks, self._cache, dev_table, dev_lens,
+                    dev_live)
             self.num_steps += 1
             pending.append((outs, chunk, counts))
             inflight += chunk

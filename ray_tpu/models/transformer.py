@@ -27,6 +27,9 @@ import jax
 import jax.numpy as jnp
 from flax.linen import partitioning as nn_partitioning
 
+from ray_tpu.ops.flash import flash_attention_bshk, flash_supported
+from ray_tpu.ops.rope import rope
+
 param_with_axes = nn_partitioning.param_with_axes
 with_sharding_constraint = nn_partitioning.with_sharding_constraint
 
@@ -77,38 +80,6 @@ class TransformerConfig:
                                  max_seq_len=128)
 
 
-def _flash_supported(head_dim: int) -> bool:
-    """The fused kernel covers the SINGLE-CHIP causal path: TPU
-    backend, lane-aligned head_dim, and no multi-device mesh active —
-    pallas_call carries no GSPMD partitioning rule, so sharded
-    activations must take the einsum path (XLA partitions it) or the
-    ring path (which owns seq parallelism explicitly). Ragged sequence
-    lengths pad inside the wrapper (ops/flash.py)."""
-    import jax
-
-    if jax.default_backend() != "tpu" or head_dim % 128 != 0:
-        return False
-    from ray_tpu.parallel import mesh as mesh_lib
-
-    m = mesh_lib.current_mesh()
-    return m is None or all(v <= 1 for v in m.shape.values())
-
-
-def _rope(x: jnp.ndarray, positions: jnp.ndarray,
-          theta: float) -> jnp.ndarray:
-    """Rotary embedding over the last dim of [..., seq, heads, head_dim]."""
-    hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [.., S, hd/2]
-    cos = jnp.cos(angles)[..., :, None, :]
-    sin = jnp.sin(angles)[..., :, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    rx1 = x1 * cos - x2 * sin
-    rx2 = x2 * cos + x1 * sin
-    out = jnp.stack([rx1, rx2], axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
-
-
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     param_dtype: Any = jnp.float32
@@ -151,8 +122,8 @@ class Attention(nn.Module):
         v = jnp.einsum("bsd,dhk->bshk", x, wv.astype(cfg.dtype))
         q = with_sharding_constraint(q, ("batch", "act_seq", "heads",
                                          "head_dim"))
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
 
         ring_mesh = None
         if cfg.ring_attention and mask is None:
@@ -172,9 +143,7 @@ class Attention(nn.Module):
 
             out = ring_attention_sharded(q, k, v, ring_mesh, causal=True)
         elif (mask is None and cfg.flash_attention != "off"
-              and _flash_supported(hd)):
-            from ray_tpu.ops.flash import flash_attention_bshk
-
+              and flash_supported(hd)):
             rep = cfg.n_heads // cfg.n_kv_heads
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)

@@ -21,6 +21,21 @@ import jax
 import jax.numpy as jnp
 
 
+def flash_supported(head_dim: int) -> bool:
+    """The fused kernel covers the SINGLE-CHIP causal path: TPU
+    backend, lane-aligned head_dim, and no multi-device mesh active —
+    pallas_call carries no GSPMD partitioning rule, so sharded
+    activations must take the einsum path (XLA partitions it) or the
+    ring path (which owns seq parallelism explicitly). Ragged sequence
+    lengths pad inside the wrapper (flash_attention_bhsd)."""
+    if jax.default_backend() != "tpu" or head_dim % 128 != 0:
+        return False
+    from ray_tpu.parallel import mesh as mesh_lib
+
+    m = mesh_lib.current_mesh()
+    return m is None or all(v <= 1 for v in m.shape.values())
+
+
 def _block(seq: int) -> int:
     """One source of truth for the kernel tile width: padding rounds
     seq up to a multiple of this, and BlockSizes uses exactly this."""

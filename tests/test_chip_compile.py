@@ -178,14 +178,14 @@ def test_engine_prefill_program(chip, bucket, rows, capsys):
         packed = chip((rows, 2 + bucket + bucket // icfg.page_size),
                       jnp.int32)
         compiled = engine._prefill_many[bucket].lower(
-            on_chip(params), packed, on_chip(engine._k_pages),
-            on_chip(engine._v_pages), on_chip(engine._dev_toks)).compile()
+            on_chip(params), packed, on_chip(engine._cache),
+            on_chip(engine._dev_toks)).compile()
     finally:
         engine.shutdown()
     assert compiled.as_text().startswith(
         f"HloModule jit_engine_prefill_b{bucket}")
     m = compiled.memory_analysis()
-    pool = 2 * sum(x.size * 2 for x in engine._k_pages)
+    pool = sum(x.size * 2 for x in jax.tree_util.tree_leaves(engine._cache))
     with capsys.disabled():
         print(f"\n[engine_prefill_b{bucket}, {rows} rows, 2 layers] "
               f"arguments {m.argument_size_in_bytes / 1e9:.3f} GB (pool "
@@ -269,7 +269,7 @@ def test_dense_decode_chunk(chip, monkeypatch, capsys, num_pages,
     ``main``, and nothing pool-shaped may come out of a fusion under
     ``kv_append`` (the one-hot form left six such copies and four such
     fusions a layer). The compiler's analysis, not a chip reading."""
-    from ray_tpu.models import inference
+    from ray_tpu.models import decoder_forward
     from ray_tpu.models.decoder import describe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -277,14 +277,15 @@ def test_dense_decode_chunk(chip, monkeypatch, capsys, num_pages,
     mcfg, params = _mistral_two_layers(16 * pages_a_seq)
     params = jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype), params)
     pool_dims = (num_pages, 8, 16, 128)
-    pools = tuple(chip(pool_dims, jnp.bfloat16) for _ in range(layers))
+    pool = chip(pool_dims, jnp.bfloat16)
     compiled = jax.jit(
-        lambda p, t, kp, vp, table, lens: inference._decode_chunk(
-            p, describe(mcfg), t, kp, vp, table, lens, (), None, n_steps=4),
-        donate_argnums=(2, 3)).lower(
-            params, chip((slots,), jnp.int32), pools, pools,
+        lambda p, t, cache, table, lens, live:
+        decoder_forward.decode_chunk_cached(
+            p, describe(mcfg), t, cache, table, lens, live, n_steps=4),
+        donate_argnums=(2,)).lower(
+            params, chip((slots,), jnp.int32), ((pool, pool),) * layers,
             chip((slots, pages_a_seq), jnp.int32),
-            chip((slots,), jnp.int32)).compile()
+            chip((slots,), jnp.int32), chip((slots,), jnp.bool_)).compile()
     text = compiled.as_text()
     m = compiled.memory_analysis()
     pool_bytes = 2 * layers * 2 * num_pages * 8 * 16 * 128
@@ -347,7 +348,7 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
     layers on a float32 state of 64 slots, 40 held experts a layer, an
     eighth of the vocabulary. It has to fit one chip beside nothing
     else: arguments + temporaries under 15 GB."""
-    from ray_tpu.models import inference
+    from ray_tpu.models import decoder_forward
     from ray_tpu.models.decoder import DecoderConfig, LayerSpec
 
     # the trace-time choice of kernel follows the backend; this test
@@ -388,17 +389,16 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
         params[f"layer_{i}"] = {**mixer, "MoE_0": moe, "RMSNorm_0": norm,
                                 "RMSNorm_1": norm}
     slots, page, pages_a_seq = 64, 128, 132
-    pool = (chip((4097, 8, page, hd), bf),)
-    state = tuple((chip((slots, h, hd, hd), f32),
-                   chip((slots, 3, h * 3 * hd), bf)) for _ in range(3))
+    pool = chip((4097, 8, page, hd), bf)
+    state = (chip((slots, h, hd, hd), f32), chip((slots, 3, h * 3 * hd), bf))
     compiled = jax.jit(
-        lambda p, t, kp, vp, table, lens, st, live: inference._decode_chunk(
-            p, mcfg, t, kp, vp, table, lens, st, live, n_steps=32),
-        donate_argnums=(2, 3, 6)).lower(
-            params, chip((slots,), jnp.int32), pool, pool,
+        lambda p, t, cache, table, lens, live:
+        decoder_forward.decode_chunk_cached(
+            p, mcfg, t, cache, table, lens, live, n_steps=32),
+        donate_argnums=(2,)).lower(
+            params, chip((slots,), jnp.int32), ((pool, pool),) + (state,) * 3,
             chip((slots, pages_a_seq), jnp.int32),
-            chip((slots,), jnp.int32), state,
-            chip((slots,), jnp.bool_)).compile()
+            chip((slots,), jnp.int32), chip((slots,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ragged-dot" in text
     m = compiled.memory_analysis()

@@ -384,20 +384,19 @@ def engine_programs(engine):
     yield "jit_engine_split_packed", engine._split_packed.lower(
         jnp.zeros((rows, 1 + cfg.max_pages_per_seq), jnp.int32))
     yield "jit_engine_kv_import", engine._kv_import.lower(
-        engine._k_pages, engine._v_pages, engine._dev_toks,
+        engine._cache, engine._dev_toks,
         jnp.zeros(kv_shape), jnp.zeros(kv_shape),
         jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32))
     for steps, fn in engine._decode_chunks.items():
         yield f"jit_engine_decode_n{steps}", fn.lower(
-            params, engine._dev_toks, engine._k_pages, engine._v_pages,
-            table, lens)
+            params, engine._dev_toks, engine._cache, table, lens,
+            jnp.zeros((rows,), bool))
     for bucket, fn in engine._prefill_many.items():
         packed = jnp.zeros(
             (rows_of(cfg, bucket), 2 + bucket + -(-bucket // cfg.page_size)),
             jnp.int32)
         yield f"jit_engine_prefill_b{bucket}", fn.lower(
-            params, packed, engine._k_pages, engine._v_pages,
-            engine._dev_toks)
+            params, packed, engine._cache, engine._dev_toks)
     for bucket, fn in engine._export_jits.items():
         yield f"jit_engine_prefill_export_b{bucket}", fn.lower(
             params, jnp.zeros((1, bucket), jnp.int32))
@@ -438,9 +437,10 @@ def test_the_forward_carries_its_scopes(tiny_model):
     engine = InferenceEngine(params, cfg, ICFG)
     try:
         rows = ICFG.batch_size
+        k_pages, v_pages = zip(*engine._cache)
         step = jax.jit(lambda p, t, k, v, table, lens: decode_step(
             p, cfg, t, k, v, table, lens)).lower(
-                params, engine._dev_toks, engine._k_pages, engine._v_pages,
+                params, engine._dev_toks, k_pages, v_pages,
                 jnp.zeros((rows, ICFG.max_pages_per_seq), jnp.int32),
                 jnp.zeros((rows,), jnp.int32))
         assert scopes_in(step) == {"embed", "attn", "kv_append", "mlp",

@@ -7,9 +7,7 @@ over experts) on LOGITS, prefill and then decode through both caches;
 and the dense decoder's programs are what they were."""
 
 import dataclasses
-import hashlib
 import os
-import re
 import sys
 
 import numpy as np
@@ -25,6 +23,7 @@ if ROOT not in sys.path:
 from benchmark import weights_solar_open2 as W  # noqa: E402
 from benchmark.reference import solar_open2 as ref  # noqa: E402
 from ray_tpu._private import spans  # noqa: E402
+from ray_tpu.models import decoder_forward as forward  # noqa: E402
 from ray_tpu.models import inference  # noqa: E402
 from ray_tpu.models.decoder import (DecoderConfig, LayerSpec,  # noqa: E402
                                     describe)
@@ -32,7 +31,6 @@ from ray_tpu.models.inference import (InferenceConfig,  # noqa: E402
                                       InferenceEngine)
 from ray_tpu.models.transformer import (Transformer,  # noqa: E402
                                         TransformerConfig)
-from ray_tpu.ops.paged_attention import write_prefill_kv  # noqa: E402
 
 TINY = {
     "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
@@ -96,8 +94,9 @@ def test_kinds_of_layers(model):
 @pytest.mark.parametrize("plen", [1, 11, 16])
 def test_prefill_then_decode_logits_match_the_reference(model, plen):
     """One row: a prompt of ``plen`` in a bucket of 16, then decode
-    steps teacher-forced on the row, through a page pool for layer 0
-    and a state for layers 1-3. Every logit of every position."""
+    steps teacher-forced on the row, through the cache (a page pool
+    for layer 0, a state for layers 1-3). Every logit of every
+    position."""
     mcfg, params = model
     rng = np.random.default_rng(plen)
     row = rng.integers(1, 96, 24)
@@ -105,23 +104,25 @@ def test_prefill_then_decode_logits_match_the_reference(model, plen):
     toks = np.zeros((1, 16), np.int32)
     toks[0, :plen] = row[:plen]
     plens = jnp.asarray([plen])
-    x, kept, counts = inference._prefill_hidden(
-        params, mcfg, jnp.asarray(toks), plens)
-    got = np.asarray(inference._head(params, mcfg, x, "bsd,vd->bsv"))[0]
+    x, _, counts = forward._prefill_hidden(params, mcfg, jnp.asarray(toks),
+                                           plens)
+    got = np.asarray(forward._head(params, mcfg, x, "bsd,vd->bsv"))[0]
     np.testing.assert_allclose(got[:plen], want[:plen], atol=2e-4)
     assert int(counts.sum()) <= plen * 4 * 4       # picks of valid tokens
-    page, n_pages = 4, 8
-    pool = jnp.zeros((n_pages + 1, 2, page, 16), jnp.float32)
-    pages = jnp.arange(4)
-    kp, vp = write_prefill_kv(pool, pool, kept[0][0][0], kept[0][1][0], pages)
+    n_pages = 8
+    cache = forward.init_cache(mcfg, InferenceConfig(
+        batch_size=1, page_size=4, num_pages=n_pages + 1))
+    last, cache, _ = forward.prefill_cached(
+        params, mcfg, cache, jnp.asarray(toks), plens, jnp.asarray([0]),
+        jnp.arange(4)[None], jnp.asarray([True]))
+    np.testing.assert_allclose(np.asarray(last)[0], want[plen - 1],
+                               atol=2e-4)
     table = jnp.arange(n_pages)[None]
-    state = tuple(kept[i] for i in mcfg.state_layers)
-    kp, vp = (kp,), (vp,)
     live = jnp.asarray([True])
     for pos in range(plen, 24):
-        logits, kp, vp, state, _ = inference._decode_step(
-            params, mcfg, jnp.asarray(row[pos:pos + 1], jnp.int32), kp, vp,
-            table, jnp.asarray([pos]), state, live)
+        logits, cache, _ = forward.decode_step_cached(
+            params, mcfg, jnp.asarray(row[pos:pos + 1], jnp.int32), cache,
+            table, jnp.asarray([pos]), live)
         np.testing.assert_allclose(np.asarray(logits)[0], want[pos],
                                    atol=2e-4, err_msg=f"position {pos}")
 
@@ -131,12 +132,12 @@ def test_rows_of_a_padded_launch_do_not_disturb_each_other(model):
     rng = np.random.default_rng(1)
     rows = rng.integers(1, 96, (3, 16)).astype(np.int32)
     plens = jnp.asarray([16, 5, 9])
-    x, kept, _ = inference._prefill_hidden(params, mcfg, jnp.asarray(rows),
+    x, kept, _ = forward._prefill_hidden(params, mcfg, jnp.asarray(rows),
                                            plens)
     for r, ln in enumerate((16, 5, 9)):
         alone = rows[r:r + 1].copy()
         alone[0, ln:] = 0
-        x1, kept1, _ = inference._prefill_hidden(
+        x1, kept1, _ = forward._prefill_hidden(
             params, mcfg, jnp.asarray(alone), jnp.asarray([ln]))
         np.testing.assert_allclose(np.asarray(x)[r, :ln],
                                    np.asarray(x1)[0, :ln], atol=1e-5)
@@ -152,7 +153,7 @@ def test_gated_attention_without_positions(model):
     a = params["layer_0"]["Attention_0"]
     h = jnp.asarray(np.random.default_rng(2).normal(size=(2, 12, 64)),
                     jnp.float32)
-    out, k, v = inference._prefill_attention(a, mcfg, h, jnp.arange(12)[None])
+    out, k, v = forward._prefill_attention(a, mcfg, h, jnp.arange(12)[None])
     s = W.dims(TINY)
     for r in range(2):
         want = ref.gated_attention(a, h[r], s, "f32")
@@ -160,7 +161,7 @@ def test_gated_attention_without_positions(model):
                                    atol=1e-5)
     assert k.shape == v.shape == (2, 12, 2, 16)
     # no rotation: a key does not depend on its position
-    _, k2, _ = inference._prefill_attention(a, mcfg, h[:, ::-1],
+    _, k2, _ = forward._prefill_attention(a, mcfg, h[:, ::-1],
                                             jnp.arange(12)[None])
     np.testing.assert_allclose(np.asarray(k2)[:, ::-1], np.asarray(k),
                                atol=1e-6)
@@ -174,11 +175,11 @@ def test_long_rows_never_hold_their_scores(model):
     h = jnp.asarray(np.random.default_rng(3).normal(size=(1, 640, 64)),
                     jnp.float32)
     pos = jnp.arange(640)[None]
-    out, _, _ = inference._prefill_attention(a, mcfg, h, pos)
+    out, _, _ = forward._prefill_attention(a, mcfg, h, pos)
     want = ref.gated_attention(a, h[0], W.dims(TINY), "f32")
     np.testing.assert_allclose(np.asarray(out)[0], np.asarray(want),
                                atol=2e-5)
-    text = jax.jit(lambda h: inference._prefill_attention(
+    text = jax.jit(lambda h: forward._prefill_attention(
         a, mcfg, h, pos)[0]).lower(h).as_text()
     assert "640x640" not in text
 
@@ -190,9 +191,9 @@ def test_delta_rule_segments_carry_state_and_tail(model, monkeypatch, n):
     h = jnp.asarray(np.random.default_rng(4).normal(size=(n, 256, 64)),
                     jnp.float32)
     plens = jnp.asarray([200, 256][:n])
-    whole = inference._prefill_delta_rule(a, mcfg, h, plens)
-    monkeypatch.setattr(inference, "_DELTA_RULE_SEGMENT", 64 * n)
-    parts = inference._prefill_delta_rule(a, mcfg, h, plens)
+    whole = forward._prefill_delta_rule(a, mcfg, h, plens)
+    monkeypatch.setattr(forward, "_DELTA_RULE_SEGMENT", 64 * n)
+    parts = forward._prefill_delta_rule(a, mcfg, h, plens)
     for got, want in zip(parts, whole):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5)
@@ -270,12 +271,30 @@ def test_engine_counters_agree_with_the_ring(served):
     assert stats["pool_tokens"] == 39 * 4
 
 
-def test_pools_only_for_layers_with_keys_and_values(served):
-    eng = served[4]
-    assert len(eng._k_pages) == len(eng._v_pages) == 1
-    assert len(eng._state) == 3
-    assert eng._state[0][0].shape == (3, 4, 16, 16)
-    assert eng._state[0][0].dtype == jnp.float32
+def test_the_cache_has_one_entry_a_layer(served, model):
+    """``init_cache``: pages for the layer with keys and values, a
+    float32 state and a convolution tail a slot for the other three;
+    the engine holds exactly that, and ``state_bytes`` is its leaves'."""
+    mcfg, _ = model
+    _, _, stats, _, eng = served
+    cache = forward.init_cache(mcfg, eng.cfg)
+    assert len(cache) == len(mcfg.layers) == 4
+    assert all(len(entry) == 2 for entry in cache)
+    for pool in cache[0]:
+        assert pool.shape == (40, 2, 4, 16) and pool.dtype == jnp.float32
+    for i in mcfg.state_layers:
+        state, tail = cache[i]
+        assert state.shape == (3, 4, 16, 16) and state.dtype == jnp.float32
+        assert tail.shape == (3, 3, 4 * 48) and tail.dtype == mcfg.dtype
+    assert cache[0][0] is not cache[0][1]       # donated: two buffers
+    shapes = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: (a.shape, a.dtype), tree)
+    assert shapes(eng._cache) == shapes(cache)
+    assert stats["state_bytes"] == sum(
+        a.nbytes for i in mcfg.state_layers for a in cache[i])
+    dense = forward.init_cache(describe(TransformerConfig.tiny()), eng.cfg)
+    assert [tuple(a.shape for a in entry) for entry in dense] == \
+        [((40, 2, 4, 16),) * 2] * 2
 
 
 @pytest.mark.parametrize("mode", ["prefill", "decode"])
@@ -328,66 +347,8 @@ def test_served_through_serve_run(model):
 
 
 # ----------------------------------------------------------------------
-# the dense decoder's programs are what they were
+# one family of programs, whatever the description
 # ----------------------------------------------------------------------
-
-# sha256 (16 hex digits) of the programs' lowered text at the commit
-# before the layer-by-layer description (84b28e3), locations and the
-# module's name stripped: prefill, split and export are still the
-# parent's to the letter. The two decode programs are not: their
-# ``append_token_kv`` is a Pallas kernel that writes one cell a slot
-# in place (PR 28; before it a one-hot product over the whole pool,
-# PR 27's with the parking cell overwritten), lowered here in interpret
-# mode; nothing of the description shows in them either. Their hashes
-# are this commit's.
-PARENT_PROGRAMS = {
-    "prefill_b8": "ab1c7c908e0a4268",
-    "prefill_b16": "d5cefb7ddf632494",
-    "split_packed": "d1b0bdb325ef069d",
-    "export_b8": "b3bca5d09d7192d8",
-    "export_b16": "9f3f5b3beffab62a",
-}
-WITH_THE_IN_PLACE_APPEND = {
-    "decode_n1": "5204b95cc5dd9c5c",
-    "decode_n2": "02b49d64d90d4998",
-}
-
-
-def lowered_programs(params, cfg):
-    icfg = InferenceConfig(batch_size=3, page_size=4, max_pages_per_seq=8,
-                           num_pages=32, prefill_buckets=(8, 16),
-                           max_new_tokens=8, decode_chunk=2)
-    eng = InferenceEngine(params, cfg, icfg)
-    out = {}
-
-    def keep(name, lowered):
-        text = re.sub(r"loc\([^)]*\)", "", lowered.as_text())
-        text = "\n".join(l for l in text.splitlines()
-                         if not l.startswith("#loc"))
-        text = re.sub(r"module @\S+", "module", text)
-        out[name] = hashlib.sha256(text.encode()).hexdigest()[:16]
-
-    try:
-        for b, fn in eng._prefill_many.items():
-            packed = jnp.zeros((eng._prefill_rows[b], 2 + b + -(-b // 4)),
-                               jnp.int32)
-            keep(f"prefill_b{b}", fn.lower(eng.params, packed, eng._k_pages,
-                                           eng._v_pages, eng._dev_toks))
-        table = jnp.zeros((3, 8), jnp.int32)
-        lens = jnp.zeros((3,), jnp.int32)
-        for n, fn in eng._decode_chunks.items():
-            keep(f"decode_n{n}", fn.lower(eng.params, eng._dev_toks,
-                                          eng._k_pages, eng._v_pages, table,
-                                          lens))
-        keep("split_packed", eng._split_packed.lower(
-            jnp.zeros((3, 9), jnp.int32)))
-        for b, fn in eng._export_jits.items():
-            keep(f"export_b{b}", fn.lower(eng.params,
-                                          jnp.zeros((1, b), jnp.int32)))
-    finally:
-        eng.shutdown()
-    return out
-
 
 @pytest.fixture(scope="module")
 def dense():
@@ -399,10 +360,80 @@ def dense():
     return cfg, params["params"]
 
 
-def test_dense_decoder_programs_lower_to_the_parents_text(dense):
+RAGGED = [([7], 5), ([1, 2, 3, 4, 5, 6, 7, 8, 9], 9), ([9, 9, 9], 1),
+          ([5] * 16, 7), ([3, 4], 6), ([2] * 11, 3)]
+
+
+def dense_logits(dense, rows):
+    """The flax module's full forward over the rows, float32."""
     cfg, params = dense
-    assert lowered_programs(params, cfg) == {**PARENT_PROGRAMS,
-                                             **WITH_THE_IN_PLACE_APPEND}
+    return np.asarray(Transformer(cfg).apply({"params": params},
+                                             jnp.asarray(rows)))
+
+
+@pytest.mark.parametrize("kind", ["dense", "hybrid"])
+def test_one_program_family_for_every_description(kind, dense, model):
+    """An engine built from the dense decoder's description and one
+    built from the hybrid's expose the SAME programs: each lowers with
+    one argument list (parameters, the cache as one donated pytree, the
+    packed rows or the burst's table, lengths and live mask), and hands
+    the cache back in the structure it took it. And what they serve for
+    ragged prompts (dummy rows in the launches, idle slots beside live
+    ones, slots reused) is, token by token, the argmax of the model's
+    full forward: the flax ``Transformer``'s for the dense decoder, the
+    plain reference's for the hybrid."""
+    if kind == "dense":
+        mcfg, params = dense
+        full_forward, vocab = lambda rows: dense_logits(dense, rows), 64
+    else:
+        mcfg, params = model
+        full_forward, vocab = reference_logits, 96
+    icfg = InferenceConfig(batch_size=3, page_size=4, max_pages_per_seq=8,
+                           num_pages=32, prefill_buckets=(8, 16),
+                           max_new_tokens=8, decode_chunk=2)
+    eng = InferenceEngine(params, mcfg, icfg)
+    try:
+        cache = jax.tree_util.tree_structure(eng._cache)
+        assert cache.num_leaves == 2 * len(eng.mcfg.layers)
+        rows = icfg.batch_size
+        ints = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+        split = eng._split_packed.lower(ints(rows, 9))
+        assert [o.dtype for o in split.out_info] == [jnp.int32, jnp.int32,
+                                                     jnp.bool_]
+        lowered = {"jit_engine_split_packed": split}
+        for n, fn in eng._decode_chunks.items():
+            low = lowered[f"jit_engine_decode_n{n}"] = fn.lower(
+                eng.params, eng._dev_toks, eng._cache, ints(rows, 8),
+                ints(rows), jnp.zeros((rows,), bool))
+            outs, toks, lens, kept, picks = low.out_info
+            assert outs.shape == (n, rows) and toks.shape == lens.shape
+            assert jax.tree_util.tree_structure(kept) == cache
+            assert (picks is None) == (not eng.mcfg.moe_layers)
+        for b, fn in eng._prefill_many.items():
+            low = lowered[f"jit_engine_prefill_b{b}"] = fn.lower(
+                eng.params, ints(eng._prefill_rows[b], 2 + b + -(-b // 4)),
+                eng._cache, eng._dev_toks)
+            first, toks, kept = low.out_info
+            assert first.shape == (eng._prefill_rows[b]
+                                   + eng.mcfg.n_experts_held,)
+            assert jax.tree_util.tree_structure(kept) == cache
+        assert sorted(lowered) == sorted(
+            ["jit_engine_split_packed", "jit_engine_decode_n1",
+             "jit_engine_decode_n2", "jit_engine_prefill_b8",
+             "jit_engine_prefill_b16"])
+        for name, low in lowered.items():
+            assert low.as_text().startswith(f"module @{name} ")
+        prompts = [([t % (vocab - 1) + 1 for t in p], m) for p, m in RAGGED]
+        outs = [f.result(300) for f in
+                [eng.submit(p, m) for p, m in prompts]]
+    finally:
+        eng.shutdown()
+    for (prompt, max_new), out in zip(prompts, outs):
+        assert len(out) == max_new
+        logits = full_forward(np.asarray([prompt + out], np.int32))[0]
+        start = len(prompt) - 1
+        assert out == [int(np.argmax(logits[start + t]))
+                       for t in range(max_new)]
 
 
 def test_untied_head_is_the_only_difference_it_makes(dense):
