@@ -16,9 +16,9 @@ independent:
                  three optimizer steps, loss finite and falling, first
                  loss against a float32 einsum-attention forward.
   C  serving     the 127 M decode model behind serve.llm.build_llm_app
-                 (twice: the XLA-gather geometry and the Pallas-kernel
-                 geometry) and run_disagg_llm; streamed tokens against
-                 a naive full-context float32 forward.
+                 (twice: 16 and 128 pages a sequence, both read by
+                 the Pallas kernel) and run_disagg_llm; streamed tokens
+                 against a naive full-context float32 forward.
 
     python chip_smoke.py               # one chip: A, B, C
     python chip_smoke.py --four-chips  # only the sharded train step on
@@ -510,11 +510,11 @@ def _decode_program_has_kernel(params, mcfg, icfg, shape=None) -> bool:
     this function (inference.decode_chunk) with its pools donated, and
     so does this: not donated, the compiler copies each pool first, and
     where that copy fits VMEM its memory assignment aborts on the
-    kernel's HBM pin (append_token_kv's docstring).
-    paged_attention_auto picks the path at trace time from
-    max_pages_per_seq * page_size. ``shape(dims, dtype)`` describes an
-    argument; tests/test_chip_compile.py places them on a described
-    chip."""
+    kernel's HBM pin (append_token_kv's docstring). Every geometry
+    runs the kernel (paged_attention_auto chooses nothing); a decode
+    program without it would be running something else.
+    ``shape(dims, dtype)`` describes an argument;
+    tests/test_chip_compile.py places them on a described chip."""
     import jax
     import jax.numpy as jnp
 
@@ -600,7 +600,7 @@ def phase_serving(clock: CompileClock, *,
                   slots: int = 64, page_size: int = 16,
                   geometries: Sequence[Tuple[int, int]] = (
                       (16, 1024), (128, 64 * 128 + 1)),
-                  expect_kernel: Optional[Sequence[bool]] = (False, True),
+                  expect_kernel: Optional[Sequence[bool]] = (True, True),
                   prompt_lens: Sequence[int] = (5, 16, 23, 40, 64),
                   buckets: Tuple[int, ...] = (16, 64),
                   max_new: int = 24, seed: int = 0) -> None:
@@ -681,7 +681,7 @@ def phase_serving(clock: CompileClock, *,
             check(f"C.mono[max_pages_per_seq={mp}]", streams,
                   context=mp * page_size, num_pages=pages, slots=slots,
                   decode_path=("pallas kernel" if has_kernel
-                               else "xla gather"
+                               else "no kernel under attn"
                                if jax.default_backend() == "tpu"
                                else "pallas kernel, interpret mode"),
                   first_pass_s=first_s, warm_pass_s=warm_s,

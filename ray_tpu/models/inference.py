@@ -197,6 +197,7 @@ class InferenceEngine:
         # decode tokens that stayed in a request's output, bursts, and
         # by prefill bucket [launches, rows, useful rows, prompt tokens]
         self._decode_steps = 0
+        self._decode_pages_live = 0
         self._decode_tokens_kept = 0
         self._bursts = 0
         self._prefill_counts: Dict[int, List[int]] = {}
@@ -450,8 +451,13 @@ class InferenceEngine:
         of which runs all ``batch_size`` slots (``decode_slot_steps``),
         of whose tokens ``decode_tokens_kept`` reached a request's
         output: the rest fell to idle slots and to steps past a
-        request's ``max_new``. A burst is one round of the loop that
-        dispatched something and fetched once. A prefill launch of
+        request's ``max_new``. ``decode_pages_live`` sums, over the
+        steps, the pages the live slots owned when their burst began,
+        and ``decode_pages_tabled`` the page table's entries
+        (``batch_size`` x ``max_pages_per_seq`` a step): the share of
+        the table a step's attention has to read. A burst is one round
+        of the loop that dispatched something and fetched once. A
+        prefill launch of
         bucket ``b`` runs ``largest_bucket // b`` rows (at most
         ``batch_size``, at least 1), so every launch computes about the
         positions of one prompt in the largest bucket; summed over the
@@ -489,6 +495,10 @@ class InferenceEngine:
                 "decode_slot_steps": (self._decode_steps
                                       * self.cfg.batch_size),
                 "decode_tokens_kept": self._decode_tokens_kept,
+                "decode_pages_live": self._decode_pages_live,
+                "decode_pages_tabled": (self._decode_steps
+                                        * self.cfg.batch_size
+                                        * self.cfg.max_pages_per_seq),
                 **prefill,
                 "prefill_by_bucket": by_bucket,
                 "moe_picks_total": self._moe_picks_total,
@@ -730,9 +740,10 @@ class InferenceEngine:
             self._wake.clear()
             return
         self.max_concurrent = max(self.max_concurrent, len(active))
+        live_pages = sum(len(s.pages) for s in active)
         with spans.span("engine.dispatch", live_slots=len(active),
-                        live_ctx_tokens=sum(s.seq_len for s in active)
-                        ) as burst:
+                        live_ctx_tokens=sum(s.seq_len for s in active),
+                        live_pages=live_pages) as burst:
             if self.mcfg.state_layers:
                 burst.fields["state_slots_live"] = len(active)
             pending = self._dispatch_burst(active)
@@ -762,6 +773,7 @@ class InferenceEngine:
         with self._lock:      # a burst's counts land together
             self._bursts += 1
             self._decode_steps += steps
+            self._decode_pages_live += live_pages * steps
             self._decode_tokens_kept += kept
 
     def _count_picks(self, counts: np.ndarray, tokens: int

@@ -9,16 +9,24 @@ granularity instead of max-seq granularity. Reference-framework analog:
 the serving stack's attention kernels (the reference runs vLLM-style
 paged attention on GPU); here it is a Pallas TPU kernel.
 
-Two implementations of the read, parity-tested, and the two writes:
+The read, its oracle, and the two writes:
 
-  - ``paged_attention_reference``: pure-XLA gather over the page table
-    (the short-context path and the numerics oracle);
-  - ``paged_attention``: Pallas flash-decoding kernel. Grid =
-    (batch, pages); the page table rides scalar prefetch and
-    the K/V BlockSpec index_maps select each sequence's physical page,
-    so the kernel only ever DMAs pages the sequence actually owns.
-    Online softmax state (m, l, acc) persists in VMEM scratch across
-    the page axis of the grid (the flash-attention recurrence);
+  - ``paged_attention``: the read of every decode program, a Pallas
+    flash-decoding kernel after the paper's. Grid = (sequences,); the
+    pools stay in HBM in their own layout and a cell walks its
+    sequence a BLOCK of pages at a time (``BLOCK_TOKENS`` tokens: 16
+    pages of 16, 2 of 128), up to the sequence's length and no
+    further: one DMA a page it owns, K and V, into a double-buffered
+    VMEM scratch laid out [KV, tokens, D], the next block (the
+    sequence's own, or the next live sequence's first) in flight while
+    this one is computed. An idle slot moves nothing. The page table,
+    the lengths and the next live sequence ride scalar prefetch. Online
+    softmax state (m, l, acc) in float32, as are scores and
+    probabilities; K and V reach the products as the pool holds them;
+  - ``paged_attention_reference``: pure-XLA gather of every page of
+    every slot over the page table. The numerics oracle of the tests;
+    no program calls it (until PR 30 contexts under 2,048 tokens ran
+    it: PERF.md section 6);
   - ``append_token_kv``: a decode step's write, one cell a sequence.
     A Pallas kernel too: each sequence's tail page goes through VMEM
     and back to where it lay, the rest of the pool is not touched;
@@ -27,8 +35,8 @@ Two implementations of the read, parity-tested, and the two writes:
 
 Layout: K/V pages are [n_pages, n_kv_heads, page_size, head_dim];
 queries are single decode tokens [B, n_heads, head_dim] (GQA: n_heads =
-G * n_kv_heads, grouped so each (batch, kv_head) grid cell computes its
-G query heads against one shared KV stream).
+G * n_kv_heads, grouped so each kv head's G query heads run against
+one shared KV stream).
 """
 
 from __future__ import annotations
@@ -43,7 +51,7 @@ NEG_INF = -1e30
 
 
 # ----------------------------------------------------------------------
-# reference implementation (XLA gather; numerics oracle + short contexts)
+# reference implementation (XLA gather; the tests' numerics oracle)
 # ----------------------------------------------------------------------
 
 def paged_attention_reference(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -78,63 +86,118 @@ def paged_attention_reference(q: jnp.ndarray, k_pages: jnp.ndarray,
 # Pallas flash-decoding kernel
 # ----------------------------------------------------------------------
 
-def _decode_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, page_size: int,
-                   max_pages: int):
-    """One grid cell = (sequence, page): ALL kv-heads of one page (the
-    KV axis stays inside the cell — a (B, KV, MP) grid would multiply
-    the per-cell fixed cost by KV for no reuse win)."""
+# tokens a block of the walk holds: the pages a DMA wave brings and the
+# width of the score matrix a head. PERF.md section 6, PR 30 has the
+# measurement behind the number.
+BLOCK_TOKENS = 256
+
+
+def _read_kernel(table_ref, lens_ref, next_ref, q_ref, k_hbm, v_hbm, o_ref,
+                 k_buf, v_buf, sems, m_ref, l_ref, acc_ref, blocks_ref, *,
+                 page: int, pages_a_block: int, max_pages: int):
+    """One grid cell = one sequence, walked a block of ``pages_a_block``
+    pages at a time up to its length. The pools stay in HBM: a block's
+    pages come by one DMA each, K and V, into one of two VMEM buffers
+    laid out [KV, tokens, D], so that a head's product runs over the
+    whole block. While a block is computed the next one is in flight:
+    the sequence's own next block, or the first block of the next live
+    sequence (``next_ref``), which that cell then finds arriving. An
+    idle slot starts and waits for nothing."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    bi = pl.program_id(0)
-    p = pl.program_id(1)
+    b = pl.program_id(0)
+    n_seqs = pl.num_programs(0)
+    T = pages_a_block * page
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def wave(what, seq, block, slot):
+        """``what`` = "start" or "wait": the DMAs of the pages that
+        ``seq`` owns in its ``block``, into buffer ``slot``"""
+        first = block * pages_a_block
+        owned = jnp.minimum(pl.cdiv(lens_ref[seq], page), max_pages)
 
-    seq_len = lens_ref[bi]
-    # tokens this page contributes: positions [p*page, p*page + valid)
-    start = p * page_size
-    valid = jnp.clip(seq_len - start, 0, page_size)
+        def one(i, carry):
+            pid = table_ref[seq, first + i]
+            rows = pl.ds(pl.multiple_of(i * page, page), page)
+            for pool, buf, which in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                getattr(pltpu.make_async_copy(
+                    pool.at[pid], buf.at[slot, :, rows],
+                    sems.at[which, slot]), what)()
+            return carry
 
-    @pl.when(valid > 0)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)          # [KV, G, D]
-        k = k_ref[0].astype(jnp.float32)          # [KV, page, D]
-        v = v_ref[0].astype(jnp.float32)          # [KV, page, D]
-        d = q.shape[-1]
-        s = jnp.einsum("kgd,kpd->kgp", q, k,
+        jax.lax.fori_loop(
+            0, jnp.clip(owned - first, 0, pages_a_block), one, None)
+
+    @pl.when(b == 0)
+    def _first():
+        blocks_ref[0] = 0
+
+        @pl.when(next_ref[0] < n_seqs)
+        def _():
+            wave("start", next_ref[0], 0, 0)
+
+    seq_len = jnp.minimum(lens_ref[b], max_pages * page)
+    n_blocks = pl.cdiv(seq_len, T)
+    done = blocks_ref[0]      # blocks walked so far: its parity is the buffer
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(j, carry):
+        slot = (done + j) % 2
+        last = j + 1 == n_blocks
+        seq_next = jnp.where(last, next_ref[b + 1], b)
+
+        @pl.when(seq_next < n_seqs)
+        def _():
+            wave("start", seq_next, jnp.where(last, 0, j + 1), 1 - slot)
+
+        wave("wait", b, j, slot)
+        q = q_ref[0]                                # [KV, G, D]
+        k = k_buf[slot]                             # [KV, T, D]
+        dtype = jnp.promote_types(q.dtype, k.dtype)
+        s = jnp.einsum("kgd,ktd->kgt", q.astype(dtype), k.astype(dtype),
                        preferred_element_type=jnp.float32) / jnp.sqrt(
-                           d * 1.0)               # [KV, G, page]
-        mask = jnp.arange(page_size)[None, None, :] < valid
-        s = jnp.where(mask, s, NEG_INF)
+                           q.shape[-1] * 1.0)       # [KV, G, T]
+        # past the length a buffer holds what an earlier block left
+        # there, or nothing yet: a score there counts for nothing, and a
+        # value row there must not reach the product (0 x NaN)
+        def live(shape, axis):
+            return j * T + jax.lax.broadcasted_iota(
+                jnp.int32, shape, axis) < seq_len
 
-        m_prev = m_ref[...]                       # [KV, G, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        s = jnp.where(live((1, 1, T), 2), s, NEG_INF)
+        v = jnp.where(live((1, T, 1), 1), v_buf[slot].astype(jnp.float32),
+                      0.0)
+
+        m_prev = m_ref[...]                         # [KV, G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(s - m_new)                # [KV, G, page]
+        probs = jnp.exp(s - m_new)                  # [KV, G, T]
         l_ref[...] = l_ref[...] * alpha + probs.sum(-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
-            "kgp,kpd->kgd", probs, v,
-            preferred_element_type=jnp.float32)
+            "kgt,ktd->kgd", probs, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
+        return carry
 
-    @pl.when(p == max_pages - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_blocks, block, None)
+    blocks_ref[0] = done + n_blocks
+    # an idle slot's row is zeros: finite, and discarded by the caller
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+        o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("block_tokens", "interpret"))
 def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                     v_pages: jnp.ndarray, page_table: jnp.ndarray,
                     seq_lens: jnp.ndarray, *,
+                    block_tokens: int = BLOCK_TOKENS,
                     interpret: bool = False) -> jnp.ndarray:
     """Pallas flash-decoding over paged KV (see module docstring);
-    interpret=True runs the kernel body off-TPU for testing."""
+    interpret=True runs the kernel body off-TPU for testing. Jitted on
+    its own, so that a decode program traces the kernel once and not
+    once a layer, and the engine's six programs once between them
+    (tracing it costs what a whole layer's einsums do)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -142,57 +205,59 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     P, KV, page, _D = k_pages.shape
     MP = page_table.shape[1]
     G = H // KV
-    qg = q.reshape(B, KV, G, D)
+    pages_a_block = max(1, min(block_tokens // page, MP))
+    T = pages_a_block * page
+    lens = seq_lens.astype(jnp.int32)
+    # as in append_token_kv: an id outside the pool is clipped, so that
+    # no DMA leaves it
+    table = jnp.clip(page_table, 0, P - 1).astype(jnp.int32)
+    # next_live[0] the first live sequence, next_live[b + 1] the first
+    # after b; B where there is none
+    ids = jnp.where(lens > 0, jnp.arange(B, dtype=jnp.int32), B)
+    next_live = jnp.concatenate([
+        jax.lax.cummin(ids, reverse=True), jnp.full((1,), B, jnp.int32)])
 
-    kernel = functools.partial(_decode_kernel, page_size=page,
-                               max_pages=MP)
+    kernel = functools.partial(_read_kernel, page=page,
+                               pages_a_block=pages_a_block, max_pages=MP)
+    head_rows = pl.BlockSpec((1, KV, G, D),
+                             lambda b, table, lens, nxt: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,   # page_table, seq_lens
-        grid=(B, MP),
-        in_specs=[
-            # q: one sequence's query heads, all kv groups
-            pl.BlockSpec((1, KV, G, D),
-                         lambda b, p, table, lens: (b, 0, 0, 0)),
-            # K/V: the physical page the table names for (b, p)
-            pl.BlockSpec((1, KV, page, D),
-                         lambda b, p, table, lens: (table[b, p], 0,
-                                                    0, 0)),
-            pl.BlockSpec((1, KV, page, D),
-                         lambda b, p, table, lens: (table[b, p], 0,
-                                                    0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, KV, G, D),
-                               lambda b, p, table, lens: (b, 0, 0, 0)),
+        num_scalar_prefetch=3,   # page_table, seq_lens, next_live
+        grid=(B,),
+        # the pools are read where they lie: left to the compiler, one
+        # that fits VMEM may be fetched there whole, every step
+        in_specs=[head_rows,
+                  pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=head_rows,
         scratch_shapes=[
+            pltpu.VMEM((2, KV, T, D), k_pages.dtype),
+            pltpu.VMEM((2, KV, T, D), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),        # (K or V, buffer)
             pltpu.VMEM((KV, G, 1), jnp.float32),    # m (running max)
             pltpu.VMEM((KV, G, 1), jnp.float32),    # l (running denom)
             pltpu.VMEM((KV, G, D), jnp.float32),    # acc
+            pltpu.SMEM((1,), jnp.int32),            # blocks walked
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      qg, k_pages, v_pages)
+    )(table, lens, next_live, q.reshape(B, KV, G, D), k_pages, v_pages)
     return out.reshape(B, H, D)
 
 
 def paged_attention_auto(q, k_pages, v_pages, page_table, seq_lens):
-    """Path choice at trace time: the Pallas kernel amortizes at LONG
-    max contexts (it reads only the pages each sequence owns); at short
-    contexts the XLA gather reference is faster (the kernel's per-cell
-    fixed cost dominates tiny reads). Off-TPU the kernel runs in
-    interpret mode so tests exercise the real kernel logic. A kernel
-    that fails to lower raises: the gather is a choice, never a
-    fallback."""
-    MP, page = page_table.shape[1], k_pages.shape[2]
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and MP * page < 2048:
-        return paged_attention_reference(q, k_pages, v_pages, page_table,
-                                         seq_lens)
+    """The read of every decode program: the Pallas kernel, at every
+    geometry (PERF.md section 6, PR 30: it beats the gather at short
+    contexts too). Off-TPU it runs in interpret mode so tests exercise
+    the real kernel logic. A kernel that fails to lower raises: nothing
+    falls back to the gather."""
     return paged_attention(q, k_pages, v_pages, page_table, seq_lens,
-                           interpret=not on_tpu)
+                           interpret=jax.default_backend() != "tpu")
 
 
 # ----------------------------------------------------------------------
@@ -229,16 +294,15 @@ def append_token_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     blocks of one page, chosen by the physical page ids that ride
     scalar prefetch, so a step reads and writes back B pages and
     touches nothing else of the pool. A Pallas operand also pins the
-    pool's default layout, the one the gather and the paged kernel
-    read: the one-hot product this replaces, an XLA scatter and a loop
-    of ``dynamic_update_slice`` each make the compiler choose a layout
-    of their own for the pool and re-lay it out around the update,
-    whole, every step (PERF.md, PR 28). The outputs, and through the
-    aliases the inputs, are pinned to HBM: left free, the compiler
-    fetched a pool that fits VMEM (50 MB) there for the kernel and the
-    gather after it and wrote it back, whole, every step. Off the TPU
-    the kernel runs in interpret mode, as ``paged_attention_auto``'s
-    does.
+    pool's default layout, the one the paged kernel reads: the one-hot
+    product this replaces, an XLA scatter and a loop of
+    ``dynamic_update_slice`` each make the compiler choose a layout of
+    their own for the pool and re-lay it out around the update, whole,
+    every step (PERF.md, PR 28). The outputs, and through the aliases
+    the inputs, are pinned to HBM: left free, the compiler fetched a
+    pool that fits VMEM (50 MB) there for the kernel and its reader
+    and wrote it back, whole, every step. Off the TPU the kernel runs
+    in interpret mode, as ``paged_attention_auto``'s does.
 
     A live sequence's cell gets exactly its token (the page allocator
     never shares a page between live sequences). Idle slots all name

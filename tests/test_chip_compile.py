@@ -65,8 +65,8 @@ def _compile(fn, *args):
 @pytest.mark.parametrize("max_pages", [16, 128])
 def test_paged_attention_kernel(chip, max_pages):
     """Decode width of chip_smoke phase C: 64 slots, 8 heads / 4 KV
-    heads of 128, pages of 16; 16 pages per sequence is what the bench
-    runs, 128 is where the engine takes the kernel."""
+    heads of 128, pages of 16, at both its geometries: 16 pages a
+    sequence (one block of the walk) and 128 (eight)."""
     from ray_tpu.ops.paged_attention import paged_attention
 
     pool = 64 * max_pages + 1
@@ -213,24 +213,54 @@ def _pool_shaped(text, pool_dims):
     return found
 
 
-def _no_pool_moves(whole):
+def _no_pool_moves(text, pool_dims):
     """No copy yields a whole pool (a ``copy`` re-lays it out, a
     ``copy-start`` moves it between HBM and VMEM: with the kernel's
     pools not pinned to HBM the compiler fetched a 50 MB pool into
-    VMEM for the gather and wrote it back, every step), and no fusion
-    under ``kv_append`` does."""
+    VMEM for its reader and wrote it back, every step), no fusion
+    under ``kv_append`` does, and no ``copy-start`` or ``slice-start``
+    has a pool among its operands either (a pool fetched by slices)."""
+    whole = _pool_shaped(text, pool_dims)
     assert [n for n, op, _l in whole if op in ("copy", "copy-start")] == []
     assert [n for n, op, line in whole
             if op == "fusion" and "kv_append" in line] == []
+    shape = f"bf16[{','.join(map(str, pool_dims))}]"
+    assert [line for line in text.splitlines() if shape in line
+            and re.search(r" (copy|slice)-start\(", line)] == []
+
+
+def _no_gathered_copy(text, slots, pages_a_seq, kv, page, d):
+    """No instruction has the shape of a sequence-major copy of the
+    pages: ``[slots, pages a sequence, KV, page, D]`` in any order of
+    those dimensions (the gather's result and its transpose), or with
+    the pages of a sequence run together."""
+    import itertools
+
+    shapes = {f"bf16[{','.join(map(str, dims))}]"
+              for base in ((slots, pages_a_seq, kv, page, d),
+                           (slots, kv, pages_a_seq * page, d))
+              for dims in itertools.permutations(base)}
+    assert [line for line in text.splitlines()
+            if any(sh in line for sh in shapes)] == []
+
+
+def _attention_kernels(text, scope):
+    """The Pallas custom calls under a mixer's scope: the paged
+    attention kernel's (the append's lie under ``kv_append``)."""
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and re.search(rf'op_name="[^"]*/{scope}/', line)]
 
 
 def test_chip_smoke_tells_the_attention_kernel_from_the_append(
         chip, monkeypatch):
-    """chip_smoke.py phase C holds ``paged_attention_auto`` to its
-    choice: the gather at 16 pages a sequence, the kernel at 128. Every
-    decode program holds a Pallas custom call since the append is one,
-    so "a custom call" says nothing: the check has to read False, True
-    at the phase's own two geometries (2 of the model's 8 layers)."""
+    """chip_smoke.py phase C holds every decode program to the paged
+    attention kernel: at 16 pages a sequence, where the gather ran
+    until PR 30, as at 128. Every decode program holds a Pallas custom
+    call since the append is one, so "a custom call" says nothing: the
+    check reads the one under the ``attn`` scope, at the phase's own
+    two geometries (2 of the model's 8 layers), and finds none once
+    the read is the gather again."""
     import os
     import sys
 
@@ -248,13 +278,22 @@ def test_chip_smoke_tells_the_attention_kernel_from_the_append(
         jax.eval_shape(Transformer(mcfg).init, jax.random.PRNGKey(0),
                        jnp.zeros((1, 8), jnp.int32))["params"])
     defaults = inspect.signature(chip_smoke.phase_serving).parameters
-    found = [chip_smoke._decode_program_has_kernel(
-        params, mcfg, InferenceConfig(
-            batch_size=defaults["slots"].default,
-            page_size=defaults["page_size"].default,
-            max_pages_per_seq=mp, num_pages=pages), shape=chip)
-        for mp, pages in defaults["geometries"].default]
-    assert found == list(defaults["expect_kernel"].default) == [False, True]
+    geometries = defaults["geometries"].default
+
+    def has_kernel(mp, pages):
+        return chip_smoke._decode_program_has_kernel(
+            params, mcfg, InferenceConfig(
+                batch_size=defaults["slots"].default,
+                page_size=defaults["page_size"].default,
+                max_pages_per_seq=mp, num_pages=pages), shape=chip)
+
+    found = [has_kernel(mp, pages) for mp, pages in geometries]
+    assert found == list(defaults["expect_kernel"].default) == [True, True]
+    from ray_tpu.models import decoder_forward
+    from ray_tpu.ops.paged_attention import paged_attention_reference
+    monkeypatch.setattr(decoder_forward, "paged_attention_auto",
+                        paged_attention_reference)
+    assert not has_kernel(*geometries[0])
 
 
 @pytest.mark.parametrize("num_pages, pages_a_seq", [(1537, 48), (2177, 68)])
@@ -264,11 +303,14 @@ def test_dense_decode_chunk(chip, monkeypatch, capsys, num_pages,
     widths, 2 of its layers, 32 slots, pages of 16: 1,537 pages and 48
     a sequence in ``chat-steady``, 2,177 and 68 in ``decode-heavy``),
     4 steps, pools donated. A step appends one cell a slot IN PLACE and
-    in the layout the gather reads: the compiled text may hold no
-    ``copy`` that yields a whole pool, in the scan's body or in
-    ``main``, and nothing pool-shaped may come out of a fusion under
+    reads the pages its live sequences own where they lie: the compiled
+    text may hold no ``copy`` that yields a whole pool, in the scan's
+    body or in ``main``, nothing pool-shaped out of a fusion under
     ``kv_append`` (the one-hot form left six such copies and four such
-    fusions a layer). The compiler's analysis, not a chip reading."""
+    fusions a layer), no pool fetched into VMEM whole or by slices, no
+    sequence-major copy of the pages (the gather left one a pool a
+    layer, and its transpose), and one Pallas call a layer under
+    ``attn``. The compiler's analysis, not a chip reading."""
     from ray_tpu.models import decoder_forward
     from ray_tpu.models.decoder import describe
 
@@ -295,12 +337,17 @@ def test_dense_decode_chunk(chip, monkeypatch, capsys, num_pages,
               f"{pool_bytes / 1e9:.3f}), temporaries "
               f"{m.temp_size_in_bytes / 1e9:.3f} GB, aliased "
               f"{m.alias_size_in_bytes / 1e9:.3f} GB")
-    assert "tpu_custom_call" in text    # the append is the kernel
-    _no_pool_moves(_pool_shaped(text, pool_dims))
+    assert len(_attention_kernels(text, "attn")) == layers
+    _no_pool_moves(text, pool_dims)
+    _no_gathered_copy(text, slots, pages_a_seq, 8, 16, 128)
     # the pools go through the scan in their own buffers
     assert m.alias_size_in_bytes >= pool_bytes
-    # what is left is the gather's: the one-hot form held 1.5 pools
-    assert m.temp_size_in_bytes < pool_bytes
+    # the gather held 156 and 247 MB here. What is left, 105 MB at both
+    # geometries, is no attention's: ``main`` re-lays ``wq``, ``wk``
+    # and ``wv`` out for their products once a chunk (PERF.md section
+    # 5), 100.7 MB for two layers
+    relaid = 2 * layers * 4096 * (32 + 8 + 8) * 128
+    assert m.temp_size_in_bytes < relaid + (8 << 20)
 
 
 def test_kda_chunk_scan(chip):
@@ -400,7 +447,10 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
             chip((slots, pages_a_seq), jnp.int32),
             chip((slots,), jnp.int32), chip((slots,), jnp.bool_)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and "ragged-dot" in text
+    assert "ragged-dot" in text
+    # the one attention layer reads its pages through the one kernel
+    assert len(_attention_kernels(text, "gqa")) == 1
+    _no_gathered_copy(text, slots, pages_a_seq, 8, page, hd)
     m = compiled.memory_analysis()
     with capsys.disabled():
         print(f"\n[hybrid decode chunk, 32 steps] arguments "
@@ -415,5 +465,5 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
     # ``kv_append``, and the temporaries hold no K and V pair of 2.15 GB
     # (4.01 GB with the one-hot append, 0.83 GB since; the compiler's
     # analysis, not a chip reading)
-    _no_pool_moves(_pool_shaped(text, (4097, 8, page, hd)))
+    _no_pool_moves(text, (4097, 8, page, hd))
     assert m.temp_size_in_bytes < 1.9e9

@@ -137,9 +137,18 @@ def test_ring_and_counters_agree_to_the_unit(run):
     assert len(delivers) == stats["bursts"]
     assert sum(r[5]["kept_tokens"] for r in delivers) == \
         stats["decode_tokens_kept"]
+    # the share of the page table a step has to read
+    assert sum(f["live_pages"] * f["steps"] for f in dispatches) == \
+        stats["decode_pages_live"]
+    assert stats["decode_pages_tabled"] == (
+        stats["decode_steps"] * ICFG.batch_size * ICFG.max_pages_per_seq)
+    assert 0 < stats["decode_pages_live"] <= stats["decode_pages_tabled"]
     for f in dispatches:
         assert 1 <= f["live_slots"] <= ICFG.batch_size
         assert f["live_ctx_tokens"] >= f["live_slots"]
+        # a live slot owns a page at least and its table's at most
+        assert f["live_slots"] <= f["live_pages"] <= (
+            f["live_slots"] * ICFG.max_pages_per_seq)
         assert f["chunks"] <= 4 and f["steps"] <= 4 * ICFG.decode_chunk
 
 
@@ -230,6 +239,34 @@ def watch_prefill_shapes(engine):
             return _fn(p, packed, *rest)
         engine._prefill_many[bucket] = call
     return seen
+
+
+def test_live_pages_agree_with_a_hand_count(tiny_model):
+    """Two requests admitted in one round: 3 tokens + 6 new own
+    ceil(9 / 4) = 3 pages, 8 + 13 new own ceil(21 / 4) = 6, from
+    admission to the end (the engine reserves ``max_new`` up front).
+    Bursts run both until the shorter finishes, then the longer
+    alone."""
+    cfg, _model, params = tiny_model
+    t0 = time.perf_counter()
+    engine = InferenceEngine(params, cfg, ICFG)
+    try:
+        with one_admission_round(engine):
+            futs = [engine.submit([1, 2, 3], max_new_tokens=6),
+                    engine.submit([5] * 8, max_new_tokens=13)]
+        assert [len(f.result(timeout=300)) for f in futs] == [6, 13]
+    finally:
+        engine.shutdown()
+    stats = engine.stats()
+    dispatches = [r[5] for r in named(spans.since(t0), "engine.dispatch")]
+    pages = [f["live_pages"] for f in dispatches]
+    assert pages[0] == 3 + 6 and pages[-1] == 6
+    assert pages == sorted(pages, reverse=True) and set(pages) == {9, 6}
+    both = sum(f["steps"] for f in dispatches if f["live_pages"] == 9)
+    alone = sum(f["steps"] for f in dispatches if f["live_pages"] == 6)
+    assert both >= 5 and both + alone >= 12      # 5 and 12 decode tokens
+    assert stats["decode_pages_live"] == 9 * both + 6 * alone
+    assert stats["decode_pages_tabled"] == (both + alone) * 3 * 16
 
 
 @pytest.mark.parametrize("batch_size, buckets, want", [

@@ -41,21 +41,73 @@ def naive_greedy(model, params, prompt, n_new):
     return toks[len(prompt):]
 
 
+def _paged_case(page, kv, g, d, mp, lens, dtype, seed=0):
+    """q, pools, table, lens with physical page 0 as the parking page,
+    full of NaN: every table entry past a sequence's length names it,
+    an idle slot's whole row does."""
+    rng = np.random.default_rng(seed)
+    b, pool = len(lens), 1 + len(lens) * mp
+    q = jnp.asarray(rng.normal(size=(b, kv * g, d)), dtype)
+    kp = rng.normal(size=(pool, kv, page, d)).astype(np.float32)
+    vp = rng.normal(size=(pool, kv, page, d)).astype(np.float32)
+    kp[0] = vp[0] = np.nan
+    table = 1 + rng.permutation(pool - 1).reshape(b, mp).astype(np.int32)
+    for i, n in enumerate(lens):
+        table[i, -(-n // page):] = 0
+    return (q, jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+            jnp.asarray(table), jnp.asarray(lens, jnp.int32))
+
+
 class TestPagedAttention:
-    def test_kernel_matches_reference(self):
-        rng = np.random.default_rng(0)
-        B, H, KV, D, page, P, MP = 3, 8, 4, 32, 8, 16, 4
-        q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(P, KV, page, D)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(P, KV, page, D)), jnp.float32)
-        table = jnp.asarray(rng.integers(0, P, size=(B, MP)), jnp.int32)
-        lens = jnp.asarray([5, 17, 32], jnp.int32)
-        ref = pa.paged_attention_reference(q, kp, vp, table, lens)
-        ker = pa.paged_attention(q, kp, vp, table, lens, interpret=True)
-        np.testing.assert_allclose(ref, ker, atol=1e-5)
+    # (page, KV, G, D, pages a sequence, tokens a block, lengths, dtype)
+    @pytest.mark.parametrize("page, kv, g, d, mp, block, lens, dtype", [
+        # the kernel's first test: pages of 8, two a block
+        pytest.param(8, 4, 2, 32, 4, 16, [5, 17, 32], jnp.float32,
+                     id="pages-of-8"),
+        # 7 pages in blocks of 2: an idle slot, one token, exactly a
+        # page, exactly a block, one past a block, the whole table
+        pytest.param(4, 2, 2, 16, 7, 8, [0, 1, 4, 8, 9, 28], jnp.float32,
+                     id="pages-of-4-table-not-a-multiple-of-the-block"),
+        # the dense cells' page and group, bfloat16 pools
+        pytest.param(16, 2, 4, 128, 5, 32, [0, 16, 32, 33, 80],
+                     jnp.bfloat16, id="pages-of-16-G4-bf16"),
+        # the hybrid cell's page, two a block
+        pytest.param(128, 2, 4, 128, 3, 256, [0, 128, 256, 300, 384],
+                     jnp.bfloat16, id="pages-of-128-G4-bf16"),
+        # the block the programs run (256 tokens = 16 pages of 16)
+        pytest.param(16, 2, 4, 128, 20, pa.BLOCK_TOKENS,
+                     [320, 257, 256, 0, 1], jnp.bfloat16,
+                     id="the-default-block"),
+        # a table shorter than a block: the block shrinks to the table
+        pytest.param(4, 2, 2, 16, 3, pa.BLOCK_TOKENS, [12, 0, 5],
+                     jnp.float32, id="table-shorter-than-a-block"),
+        # a length past the table reads the table and nothing else
+        pytest.param(4, 2, 2, 16, 3, 8, [13, 40, 12], jnp.float32,
+                     id="length-past-the-table"),
+        # every slot idle
+        pytest.param(4, 2, 2, 16, 3, 8, [0, 0], jnp.float32,
+                     id="all-idle"),
+    ])
+    def test_kernel_matches_reference(self, page, kv, g, d, mp, block,
+                                      lens, dtype):
+        """Live rows equal the gather's on the same pools; the parking
+        page's NaN, which every table entry past a length names, reaches
+        no row (in the gather it would: its oracle reads a parking page
+        of zeros); an idle slot's row is finite."""
+        q, kp, vp, table, lens = _paged_case(page, kv, g, d, mp, lens,
+                                             dtype)
+        ref = pa.paged_attention_reference(
+            q, kp.at[0].set(0), vp.at[0].set(0), table, lens)
+        ker = np.asarray(pa.paged_attention(q, kp, vp, table, lens,
+                                            block_tokens=block,
+                                            interpret=True))
+        assert np.isfinite(ker).all()
+        live = np.asarray(lens) > 0
+        np.testing.assert_allclose(np.asarray(ref)[live], ker[live],
+                                   atol=1e-5)
 
     def test_auto_raises_when_the_kernel_raises(self, monkeypatch):
-        """paged_attention_auto chooses a path; it never swaps a
+        """paged_attention_auto runs the kernel; it never swaps a
         failing kernel for the XLA gather (on the chip that recorded a
         kernel that did not lower as a slow pass)."""
         def boom(*a, **kw):
