@@ -4,8 +4,9 @@
 every layer is the same. The models people serve mix kinds: a softmax
 attention layer, then three with a recurrent state, experts in every
 feed-forward, an untied head. ``DecoderConfig`` names, for each layer,
-its mixer (``attention`` or ``delta_rule``) and its feed-forward
-(``dense`` or ``experts``), and beside them what the kinds need. The
+its mixer (``attention``, ``delta_rule`` or ``latent``) and its
+feed-forward (``dense`` or ``experts``), and beside them what the kinds
+need; a model may mix feed-forward kinds as well as mixers. The
 dense decoder is the one-kind case: ``describe`` lowers a
 ``TransformerConfig`` to it. ``models/decoder_forward.py`` runs a
 description (the functional forward, and the cache of what each kind of
@@ -16,13 +17,21 @@ The parameter tree a description stands for (``layer_<i>`` under the
 root, beside ``embedding``, ``final_norm/scale`` and, untied,
 ``lm_head`` [V, d]):
 
-- ``RMSNorm_0/scale``, ``RMSNorm_1/scale``;
+- ``RMSNorm_0/scale``, ``RMSNorm_1/scale`` (before the mixer, before
+  the feed-forward) and, with ``sandwich_norm``, ``PostNorm_0/scale``,
+  ``PostNorm_1/scale`` (on each branch before its residual add);
 - mixer ``attention``: ``Attention_0/{wq [d,H,hd], wk, wv [d,KV,hd], wo
   [H,hd,d]}`` and, gated, ``w_gate [d,H,hd]``;
 - mixer ``delta_rule`` (ops/kda.py): ``DeltaRule_0/{wq, wk [d,H,dk], wv
   [d,H,dv], conv_q, conv_k [K,H,dk], conv_v [K,H,dv], w_f_down [d,r],
   w_f_up [r,H,dk], dt_bias [H,dk], A_log [H], w_beta [d,H], w_g_down
   [d,r], w_g_up [r,H,dv], o_norm [dv], wo [H,dv,d]}``;
+- mixer ``latent`` (multi-head latent attention; r = ``kv_rank``, n =
+  ``nope_dim``, p = ``rope_dim``): ``LatentAttention_0/{w_qa [d,
+  q_rank], q_norm [q_rank], w_qb [q_rank,H,n+p], w_kva [d,r+p], kv_norm
+  [r], w_kvb [r,H,n+v_dim], wo [H,v_dim,d]}``; a head's keys are ``[the
+  first n columns of w_kvb over the normed latent | the ONE rotated
+  p-wide key all heads share]``, its values the other ``v_dim``;
 - feed-forward ``dense``: ``MLP_0/{w_gate, w_up [d,f], w_down [f,d]}``;
 - feed-forward ``experts`` (ops/moe.py): ``MoE_0/{router [d,E], w_gate,
   w_up [E_held,d,fe], w_down [E_held,fe,d]}`` and, with a shared expert,
@@ -36,7 +45,7 @@ from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 
-MIXERS = ("attention", "delta_rule")
+MIXERS = ("attention", "delta_rule", "latent")
 FFNS = ("dense", "experts")
 
 
@@ -72,14 +81,27 @@ class DecoderConfig:
     dr_value_dim: int = 0
     dr_conv: int = 4
     dr_rank: int = 0
+    # latent layers: rank of the query's and of the keys-and-values'
+    # compression, a head's key width without and with positions, a
+    # head's value width
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    # four norms a layer: each branch is normed again before its
+    # residual add
+    sandwich_norm: bool = False
     # experts layers: the router's width, the range of ids held here
     # [first, last), picks a token, an expert's width, the shared
-    # expert's (0: none)
+    # expert's (0: none), the factor on the picked experts' normalised
+    # weights
     n_routed_experts: int = 0
     experts_held: Tuple[int, int] = (0, 0)
     experts_per_token: int = 0
     d_expert: int = 0
     d_shared: int = 0
+    routed_scale: float = 1.0
 
     def __post_init__(self):
         lo, hi = self.experts_held
@@ -100,6 +122,22 @@ class DecoderConfig:
         """Layers that keep keys and values in the paged pool."""
         return tuple(i for i, l in enumerate(self.layers)
                      if l.mixer == "attention")
+
+    @property
+    def latent_layers(self) -> Tuple[int, ...]:
+        """Layers that keep one latent row a token in a paged pool of
+        their own."""
+        return tuple(i for i, l in enumerate(self.layers)
+                     if l.mixer == "latent")
+
+    @property
+    def latent_width(self) -> int:
+        """What a latent layer keeps a token, in numbers: the normed
+        latent and the rotated shared key side by side, padded with
+        zeros to whole lanes of 128 (the chip's memory holds a row of
+        576 as 640 anyway, and a whole-lane row is one the kernel's
+        products take as it lies)."""
+        return -(-(self.kv_rank + self.rope_dim) // 128) * 128
 
     @property
     def state_layers(self) -> Tuple[int, ...]:

@@ -9,7 +9,12 @@ while tracing.
 
 It owns the cache, one entry a layer: ``(k_pages, v_pages)`` for an
 attention layer (its own pool, [P,KV,page,D]), ``(state, tail)`` a slot
-for a delta-rule layer. A pytree, never stacked: each layer's append
+for a delta-rule layer, ``(latent_pages,)`` for a latent layer (one
+pool [P,1,page,W] whose row a token is the normed latent and the
+rotated shared key). What a kind of mixer keeps and does is one entry
+of ``MIXERS_BY_KIND`` (``init``, ``write``, ``prefill``, ``decode``);
+the layer around it (norms, residual, feed-forward) is the same for
+all. A pytree, never stacked: each layer's append
 kernel takes its own pool as input and output of one buffer under
 jit/scan, and one [L,...] array would be copied whole every step. The
 serving engine (models/inference.py) holds it as one donated value and
@@ -19,7 +24,7 @@ engine's.
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -27,14 +32,22 @@ import jax.numpy as jnp
 from ray_tpu.models.decoder import DecoderConfig, LayerSpec, describe
 from ray_tpu.ops import kda
 from ray_tpu.ops.flash import flash_attention_bshk, flash_supported
+from ray_tpu.ops.mla_prefill import mla_prefill_attention
 from ray_tpu.ops.moe import experts_held, route_topk
-from ray_tpu.ops.paged_attention import (append_token_kv,
+from ray_tpu.ops.paged_attention import (append_token, append_token_kv,
                                          paged_attention_auto,
-                                         write_prefill_kv)
+                                         paged_latent_attention,
+                                         write_prefill_kv,
+                                         write_prefill_pages)
 from ray_tpu.ops.rope import rope
 
 # a row longer than this never materialises its [S,S] scores
 _SCORES_MAX_SEQ = 512
+# numbers in the sorted copy of one block of tokens' picks (tokens x
+# picks a token x d_model): the expert layer goes a block at a time,
+# the largest power of two of tokens that stays within this (8,192
+# tokens at 8 picks and a width of 4,096; 4,096 at a width of 7,680)
+_EXPERT_BLOCK_NUMBERS = 1 << 28
 
 
 def _rms(x, scale, eps):
@@ -57,12 +70,15 @@ def _experts(m, cfg: DecoderConfig, x, valid):
     t = x.reshape(-1, x.shape[-1])
     dt = cfg.dtype
     with jax.named_scope("moe_route"):
-        ids, weights = route_topk(t, m["router"], cfg.experts_per_token)
+        ids, weights = route_topk(t, m["router"], cfg.experts_per_token,
+                                  cfg.routed_scale)
     with jax.named_scope("moe_experts"):
+        block = _EXPERT_BLOCK_NUMBERS // (cfg.experts_per_token
+                                          * cfg.d_model)
         y, counts = experts_held(
             t, ids, weights, m["w_gate"].astype(dt), m["w_up"].astype(dt),
             m["w_down"].astype(dt), cfg.experts_held[0],
-            valid.reshape(-1))
+            valid.reshape(-1), 1 << (block.bit_length() - 1))
     if cfg.d_shared:
         with jax.named_scope("moe_shared"):
             y = y + _mlp(m["shared"], t, dt)
@@ -70,32 +86,31 @@ def _experts(m, cfg: DecoderConfig, x, valid):
 
 
 def _feed_forward(p, cfg: DecoderConfig, spec: LayerSpec, x, valid):
-    """x + FFN(norm(x)) of one layer; the picks a held expert, or
-    None."""
+    """x + FFN(norm(x)) of one layer (the branch normed once more before
+    the add, of a model with sandwich norms); the picks a held expert,
+    or None."""
+    h = _rms(x, p["RMSNorm_1"]["scale"], cfg.norm_eps)
     if spec.ffn == "dense":
         with jax.named_scope("mlp"):
-            return x + _mlp(p["MLP_0"], _rms(x, p["RMSNorm_1"]["scale"],
-                                             cfg.norm_eps), cfg.dtype), None
-    y, counts = _experts(p["MoE_0"], cfg,
-                         _rms(x, p["RMSNorm_1"]["scale"], cfg.norm_eps),
-                         valid)
+            y, counts = _mlp(p["MLP_0"], h, cfg.dtype), None
+    else:
+        y, counts = _experts(p["MoE_0"], cfg, h, valid)
+    if cfg.sandwich_norm:
+        y = _rms(y, p["PostNorm_1"]["scale"], cfg.norm_eps)
     return x + y, counts
 
 
-def _blockwise_attention(q, kr, vr, block: int = _SCORES_MAX_SEQ):
-    """Causal attention over [N,S,H,D] (heads repeated) without the
-    [S,S] scores: the flash kernel where it runs (ops/flash.py), else
-    the scores of one block of query rows at a time."""
-    if flash_supported(q.shape[-1]):
-        return flash_attention_bshk(q, kr, vr)
-    n, s, h, d = q.shape
+def _blockwise_scores_attention(q, kr, vr, scale, block=_SCORES_MAX_SEQ):
+    """Causal attention over [N,S,H,D] (heads repeated; values of any
+    width) with the scores of one block of query rows at a time."""
+    n, s, h, _ = q.shape
     pad = (-s) % block
     qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
 
     def rows(i):
         qb = jax.lax.dynamic_slice_in_dim(qp, i * block, block, axis=1)
         scores = (jnp.einsum("bshk,bthk->bhst", qb, kr)
-                  / jnp.sqrt(d)).astype(jnp.float32)
+                  * scale).astype(jnp.float32)
         seen = (jnp.arange(s)[None, :]
                 <= i * block + jnp.arange(block)[:, None])
         probs = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30),
@@ -103,7 +118,16 @@ def _blockwise_attention(q, kr, vr, block: int = _SCORES_MAX_SEQ):
         return jnp.einsum("bhst,bthk->bshk", probs, vr)
 
     out = jax.lax.map(rows, jnp.arange((s + pad) // block))
-    return jnp.moveaxis(out, 0, 1).reshape(n, s + pad, h, d)[:, :s]
+    return jnp.moveaxis(out, 0, 1).reshape(n, s + pad, h, -1)[:, :s]
+
+
+def _blockwise_attention(q, kr, vr):
+    """Causal attention over [N,S,H,D] (heads repeated) without the
+    [S,S] scores: the flash kernel where it runs (ops/flash.py), else
+    the scores of one block of query rows at a time."""
+    if flash_supported(q.shape[-1]):
+        return flash_attention_bshk(q, kr, vr)
+    return _blockwise_scores_attention(q, kr, vr, q.shape[-1] ** -0.5)
 
 
 def _prefill_attention(a, cfg: DecoderConfig, h, positions):
@@ -227,9 +251,13 @@ def _prefill_delta_rule(a, cfg: DecoderConfig, h, plens):
         return out, state, tail
 
 
-def _decode_delta_rule(a, cfg: DecoderConfig, h, state, tail, live):
-    """One position a slot: h [B,Dm] (normed). Slots that are not
-    ``live`` keep their state and tail as they are."""
+def _decode_delta_rule(p, cfg: DecoderConfig, h, kept, page_table,
+                       seq_lens, live):
+    """One position a slot: h [B,Dm] (normed), ``kept`` the layer's
+    (state, tail). Slots that are not ``live`` keep their state and
+    tail as they are."""
+    a = p["DeltaRule_0"]
+    state, tail = kept
     with jax.named_scope("kda"):
         flat, w = _delta_rule_projections(a, cfg, h)
         mixed, new_tail = kda.short_conv_step(
@@ -243,71 +271,284 @@ def _decode_delta_rule(a, cfg: DecoderConfig, h, state, tail, live):
         state = jnp.where(live[:, None, None, None], new_state, state)
         tail = jnp.where(live[:, None, None], new_tail.astype(tail.dtype),
                          tail)
-    return out, state, tail
+    return out, (state, tail)
+
+
+# ----------------------------------------------------------------------
+# latent attention: a prompt in the published, expanded form; a decode
+# step in the absorbed form against what the layer keeps
+# ----------------------------------------------------------------------
+
+def _latent_row(a, cfg: DecoderConfig, h, positions):
+    """The row a latent layer keeps a token, h [...,S,d] (normed) at
+    ``positions`` [...,S] -> [...,S,W]: the normed latent, the rotated
+    key all heads share, zeros up to whole lanes."""
+    kva = h @ a["w_kva"].astype(cfg.dtype)
+    c = _rms(kva[..., :cfg.kv_rank], a["kv_norm"], cfg.norm_eps)
+    k_rope = rope(kva[..., None, cfg.kv_rank:], positions,
+                  cfg.rope_theta)[..., 0, :]
+    pad = cfg.latent_width - cfg.kv_rank - cfg.rope_dim
+    return jnp.concatenate(
+        [c, k_rope, jnp.zeros(c.shape[:-1] + (pad,), c.dtype)], axis=-1)
+
+
+def _latent_queries(cfg: DecoderConfig, cq, w_qb, positions):
+    """The heads of ``w_qb`` [q_rank,h,n+p] over the normed compressed
+    query cq [...,S,q_rank] -> (q_nope [...,S,h,n], q_rope [...,S,h,p]
+    rotated)."""
+    q = jnp.einsum("...r,rhk->...hk", cq, w_qb)
+    return (q[..., :cfg.nope_dim],
+            rope(q[..., cfg.nope_dim:], positions, cfg.rope_theta))
+
+
+def _latent_prefill_attention(q, k, v, scale):
+    """Causal attention over [N,H,S,*] with keys and values of two
+    widths at the true ``scale``, never the [S,S] scores of a long row:
+    the kernel of ops/mla_prefill.py where the flash kernel would run,
+    else the scores of a block of query rows at a time."""
+    s = q.shape[2]
+    if s > _SCORES_MAX_SEQ and flash_supported(128):
+        return mla_prefill_attention(q, k, v, scale=scale)
+    if s > _SCORES_MAX_SEQ:
+        out = _blockwise_scores_attention(
+            *(jnp.moveaxis(t, 1, 2) for t in (q, k, v)), scale)
+        return jnp.moveaxis(out, 2, 1)
+    scores = jnp.einsum("bhsk,bhtk->bhst", q, k) * scale
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool))[None, None],
+                       scores.astype(jnp.float32), -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhst,bhtv->bhsv", probs, v)
+
+
+# positions x heads of a launch that the expanded form holds at a time:
+# its per-head queries, keys and values (704 numbers a position and
+# head at the published widths, lanes padded) exist for one group of
+# heads, not for all 128 (8,192 positions x 32 heads)
+_LATENT_GROUP_NUMBERS = 1 << 18
+
+
+def _prefill_latent(p, cfg: DecoderConfig, h, positions, plens):
+    """Latent attention over a bucket in the expanded form: h [N,S,Dm]
+    (normed) -> (out [N,S,Dm], (rows [N,S,W],) that the layer keeps).
+    Every head gets its own keys [w_kvb's first n columns over the
+    latent | the shared rotated key] and values, as published; the
+    heads go a group at a time, each group's part of the output
+    projection summed in float32."""
+    a = p["LatentAttention_0"]
+    dt = cfg.dtype
+    n, s, _ = h.shape
+    heads = cfg.n_heads
+    hg = max(1, min(heads, _LATENT_GROUP_NUMBERS // (n * s)))
+    while heads % hg:
+        hg -= 1
+    with jax.named_scope("mla"):
+        row = _latent_row(a, cfg, h, positions)
+        cq = _rms(h @ a["w_qa"].astype(dt), a["q_norm"], cfg.norm_eps)
+        c = row[..., :cfg.kv_rank]
+        k_rope = row[:, None, :, cfg.kv_rank:cfg.kv_rank + cfg.rope_dim]
+
+        def group(out, g):
+            def mine(w, axis):
+                return jax.lax.dynamic_slice_in_dim(
+                    w.astype(dt), g * hg, hg, axis=axis)
+
+            q = jnp.concatenate(_latent_queries(
+                cfg, cq, mine(a["w_qb"], 1), positions), axis=-1)
+            kv = jnp.einsum("bsr,rhk->bhsk", c, mine(a["w_kvb"], 1))
+            k = jnp.concatenate(
+                [kv[..., :cfg.nope_dim],
+                 jnp.broadcast_to(k_rope, kv.shape[:3] + (cfg.rope_dim,))],
+                axis=-1)
+            attn = _latent_prefill_attention(
+                jnp.moveaxis(q, 1, 2), k, kv[..., cfg.nope_dim:],
+                (cfg.nope_dim + cfg.rope_dim) ** -0.5)
+            return out + jnp.einsum(
+                "bhsv,hvd->bsd", attn, mine(a["wo"], 0),
+                preferred_element_type=jnp.float32), None
+
+        out, _ = jax.lax.scan(group, jnp.zeros(h.shape, jnp.float32),
+                              jnp.arange(heads // hg))
+    return out.astype(dt), (row,)
+
+
+def _decode_latent(p, cfg: DecoderConfig, h, kept, page_table, seq_lens,
+                   live):
+    """One position a slot in the absorbed form: the token's row is
+    appended to the layer's pool, the query goes through ``w_kvb``'s key
+    half to the latent's width and is scored against the rows as they
+    lie, and the weighted rows come back through its value half. The
+    two halves are views of ``w_kvb`` taken while tracing."""
+    a = p["LatentAttention_0"]
+    dt = cfg.dtype
+    (pages,) = kept
+    w_kvb = a["w_kvb"].astype(dt)
+    # a length-1 "sequence" per slot, as the other kinds rotate a step
+    h, positions = h[:, None], seq_lens[:, None]
+    with jax.named_scope("mla_absorb"):
+        cq = _rms(h @ a["w_qa"].astype(dt), a["q_norm"], cfg.norm_eps)
+        q_nope, q_rope = _latent_queries(cfg, cq, a["w_qb"].astype(dt),
+                                         positions)
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0],
+                           w_kvb[..., :cfg.nope_dim])
+        pad = cfg.latent_width - cfg.kv_rank - cfg.rope_dim
+        q = jnp.concatenate(
+            [q_lat, q_rope[:, 0],
+             jnp.zeros(q_lat.shape[:2] + (pad,), q_lat.dtype)], axis=-1)
+        # [B,1,W]: the one position stands where the pool has its one
+        # "KV head"
+        row = _latent_row(a, cfg, h, positions)
+    with jax.named_scope("latent_append"):
+        (pages,) = append_token((pages,), (row,), page_table, seq_lens)
+    with jax.named_scope("mla_absorb"):
+        mixed = paged_latent_attention(
+            q, pages, page_table, seq_lens + 1,
+            score_width=cfg.nope_dim + cfg.rope_dim,
+            value_width=cfg.kv_rank,
+            interpret=jax.default_backend() != "tpu")
+        o = jnp.einsum("bhr,rhv->bhv", mixed.astype(dt),
+                       w_kvb[..., cfg.nope_dim:])
+        out = jnp.einsum("bhv,hvd->bd", o, a["wo"].astype(dt))
+    return out, (pages,)
+
+
+def _init_latent(cfg: DecoderConfig, icfg):
+    return (jnp.zeros((icfg.num_pages, 1, icfg.page_size,
+                       cfg.latent_width), cfg.dtype),)
+
+
+def _write_latent(entry, kept, slots, pages):
+    (pool,), (rows,) = entry, kept
+    with jax.named_scope("latent_append"):
+        for r in range(pages.shape[0]):
+            pool = write_prefill_pages(pool, rows[r][:, None], pages[r])
+    return (pool,)
+
+
+# ----------------------------------------------------------------------
+# the other two kinds, in the table's form
+# ----------------------------------------------------------------------
+
+def _prefill_attention_kind(p, cfg: DecoderConfig, h, positions, plens):
+    with jax.named_scope("gqa" if cfg.attn_gate else "attn"):
+        out, k, v = _prefill_attention(p["Attention_0"], cfg, h, positions)
+    return out, (k, v)
+
+
+def _decode_attention(p, cfg: DecoderConfig, h, kept, page_table, seq_lens,
+                      live):
+    """``kept`` is the layer's (k_pages, v_pages), to which this token's
+    K/V are appended (seq_lens = cache length BEFORE the token = the
+    token's position)."""
+    a = p["Attention_0"]
+    k_pages, v_pages = kept
+    scope = "gqa" if cfg.attn_gate else "attn"
+    with jax.named_scope(scope):
+        q = jnp.einsum("bd,dhk->bhk", h, a["wq"].astype(cfg.dtype))
+        k = jnp.einsum("bd,dhk->bhk", h, a["wk"].astype(cfg.dtype))
+        v = jnp.einsum("bd,dhk->bhk", h, a["wv"].astype(cfg.dtype))
+        if cfg.rope_theta is not None:
+            # rope over a length-1 "sequence" per slot
+            q = rope(q[:, None], seq_lens[:, None], cfg.rope_theta)[:, 0]
+            k = rope(k[:, None], seq_lens[:, None], cfg.rope_theta)[:, 0]
+    with jax.named_scope("kv_append"):
+        k_pages, v_pages = append_token_kv(k_pages, v_pages, k, v,
+                                           page_table, seq_lens)
+    with jax.named_scope(scope):
+        out = paged_attention_auto(q, k_pages, v_pages, page_table,
+                                   seq_lens + 1)
+        if cfg.attn_gate:
+            out = out * jax.nn.sigmoid(jnp.einsum(
+                "bd,dhk->bhk", h, a["w_gate"].astype(cfg.dtype)))
+        out = jnp.einsum("bhk,hkd->bd", out.astype(cfg.dtype),
+                         a["wo"].astype(cfg.dtype))
+    return out, (k_pages, v_pages)
+
+
+def _init_attention(cfg: DecoderConfig, icfg):
+    pool = (icfg.num_pages, cfg.n_kv_heads, icfg.page_size, cfg.head_dim)
+    return jnp.zeros(pool, cfg.dtype), jnp.zeros(pool, cfg.dtype)
+
+
+def _write_attention(entry, kept, slots, pages):
+    k_pages, v_pages = entry
+    with jax.named_scope("kv_append"):
+        for r in range(pages.shape[0]):
+            k_pages, v_pages = write_prefill_kv(
+                k_pages, v_pages, kept[0][r], kept[1][r], pages[r])
+    return k_pages, v_pages
+
+
+def _prefill_delta_rule_kind(p, cfg: DecoderConfig, h, positions, plens):
+    out, state, tail = _prefill_delta_rule(p["DeltaRule_0"], cfg, h, plens)
+    return out, (state, tail)
+
+
+def _init_delta_rule(cfg: DecoderConfig, icfg):
+    return (jnp.zeros((icfg.batch_size, cfg.dr_heads, cfg.dr_key_dim,
+                       cfg.dr_value_dim), jnp.float32),
+            jnp.zeros((icfg.batch_size, cfg.dr_conv - 1,
+                       cfg.dr_heads * cfg.dr_channels), cfg.dtype))
+
+
+def _write_delta_rule(entry, kept, slots, pages):
+    with jax.named_scope("kda_state"):
+        return tuple(held.at[slots].set(new)
+                     for held, new in zip(entry, kept))
+
+
+class _Mixer(NamedTuple):
+    """What a kind of mixer brings. ``init(cfg, icfg)`` -> the layer's
+    empty cache entry; ``write(entry, kept, slots, pages)`` -> the entry
+    with what a launch's rows kept: row r's keys and values [S,KV,D]
+    or latent rows [S,W] go to the pages ``pages[r]``, its final state
+    and tail to slot ``slots[r]`` whole (nothing of the slot's previous
+    tenant survives; a dummy row's slot is out of bounds and its
+    scatter is dropped); ``prefill(p, cfg, h, positions, plens)`` -> (out
+    [N,S,Dm] before the residual, kept); ``decode(p, cfg, h, entry,
+    page_table, seq_lens, live)`` -> (out [B,Dm], entry)."""
+    init: Callable
+    write: Callable
+    prefill: Callable
+    decode: Callable
+
+
+MIXERS_BY_KIND = {
+    "attention": _Mixer(_init_attention, _write_attention,
+                        _prefill_attention_kind, _decode_attention),
+    "delta_rule": _Mixer(_init_delta_rule, _write_delta_rule,
+                         _prefill_delta_rule_kind, _decode_delta_rule),
+    "latent": _Mixer(_init_latent, _write_latent, _prefill_latent,
+                     _decode_latent),
+}
+
+
+def _mix(p, cfg: DecoderConfig, x, run):
+    """x + mixer(norm(x)), the branch normed once more before the add
+    of a model with sandwich norms; ``run(h)`` -> (out, kept)."""
+    out, kept = run(_rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps))
+    if cfg.sandwich_norm:
+        out = _rms(out, p["PostNorm_0"]["scale"], cfg.norm_eps)
+    return x + out, kept
 
 
 def _prefill_layer(p, cfg: DecoderConfig, spec: LayerSpec, x, positions,
                    plens, valid):
     """One layer over a bucket [N,S,Dm]. Returns (x_out, what the layer
-    keeps for decoding: (k, v) [N,S,KV,D] or (state, tail); picks a
-    held expert or None)."""
-    if spec.mixer == "attention":
-        with jax.named_scope("gqa" if cfg.attn_gate else "attn"):
-            h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-            out, k, v = _prefill_attention(p["Attention_0"], cfg, h,
-                                           positions)
-            x = x + out
-        kept = (k, v)
-    else:
-        h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-        out, state, tail = _prefill_delta_rule(p["DeltaRule_0"], cfg, h,
-                                               plens)
-        x = x + out
-        kept = (state, tail)
+    keeps for decoding, in its kind's form; picks a held expert or
+    None)."""
+    x, kept = _mix(p, cfg, x, lambda h: MIXERS_BY_KIND[spec.mixer].prefill(
+        p, cfg, h, positions, plens))
     x, counts = _feed_forward(p, cfg, spec, x, valid)
     return x, kept, counts
 
 
 def _decode_layer(p, cfg: DecoderConfig, spec: LayerSpec, x, kept,
                   page_table, seq_lens, live):
-    """Single-token decode for one layer over [B,Dm]. ``kept`` is the
-    layer's (k_pages, v_pages), to which this token's K/V are appended
-    (seq_lens = cache length BEFORE the token = the token's position),
-    or its (state, tail). Returns (x_out, kept, picks a held expert or
+    """Single-token decode for one layer over [B,Dm]; ``kept`` is the
+    layer's cache entry. Returns (x_out, kept, picks a held expert or
     None)."""
-    if spec.mixer == "attention":
-        a = p["Attention_0"]
-        k_pages, v_pages = kept
-        scope = "gqa" if cfg.attn_gate else "attn"
-        with jax.named_scope(scope):
-            h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-            q = jnp.einsum("bd,dhk->bhk", h, a["wq"].astype(cfg.dtype))
-            k = jnp.einsum("bd,dhk->bhk", h, a["wk"].astype(cfg.dtype))
-            v = jnp.einsum("bd,dhk->bhk", h, a["wv"].astype(cfg.dtype))
-            if cfg.rope_theta is not None:
-                # rope over a length-1 "sequence" per slot
-                q = rope(q[:, None], seq_lens[:, None],
-                         cfg.rope_theta)[:, 0]
-                k = rope(k[:, None], seq_lens[:, None],
-                         cfg.rope_theta)[:, 0]
-        with jax.named_scope("kv_append"):
-            k_pages, v_pages = append_token_kv(k_pages, v_pages, k, v,
-                                               page_table, seq_lens)
-        with jax.named_scope(scope):
-            out = paged_attention_auto(q, k_pages, v_pages, page_table,
-                                       seq_lens + 1)
-            if cfg.attn_gate:
-                out = out * jax.nn.sigmoid(jnp.einsum(
-                    "bd,dhk->bhk", h, a["w_gate"].astype(cfg.dtype)))
-            x = x + jnp.einsum("bhk,hkd->bd", out.astype(cfg.dtype),
-                               a["wo"].astype(cfg.dtype))
-        kept = (k_pages, v_pages)
-    else:
-        h = _rms(x, p["RMSNorm_0"]["scale"], cfg.norm_eps)
-        out, state, tail = _decode_delta_rule(p["DeltaRule_0"], cfg, h,
-                                              *kept, live)
-        x = x + out
-        kept = (state, tail)
+    x, kept = _mix(p, cfg, x, lambda h: MIXERS_BY_KIND[spec.mixer].decode(
+        p, cfg, h, kept, page_table, seq_lens, live))
     valid = live if live is not None else jnp.ones(x.shape[:1], bool)
     x, counts = _feed_forward(p, cfg, spec, x, valid)
     return x, kept, counts
@@ -365,36 +606,10 @@ def init_cache(cfg: DecoderConfig, icfg) -> tuple:
     [num_pages, KV, page_size, D] in the model's dtype; a delta-rule
     layer keeps, for each slot, ``(state, tail)``: its state [B, H, dk,
     dv] in float32 and the last K-1 inputs of its short convolution
-    [B, K-1, channels]."""
-    def entry(spec):
-        if spec.mixer == "attention":
-            pool = (icfg.num_pages, cfg.n_kv_heads, icfg.page_size,
-                    cfg.head_dim)
-            return jnp.zeros(pool, cfg.dtype), jnp.zeros(pool, cfg.dtype)
-        return (jnp.zeros((icfg.batch_size, cfg.dr_heads, cfg.dr_key_dim,
-                           cfg.dr_value_dim), jnp.float32),
-                jnp.zeros((icfg.batch_size, cfg.dr_conv - 1,
-                           cfg.dr_heads * cfg.dr_channels), cfg.dtype))
-
-    return tuple(entry(spec) for spec in cfg.layers)
-
-
-def _write_kept(spec: LayerSpec, entry, kept, slots, pages):
-    """What a layer kept of a launch's rows into its cache entry: row
-    r's keys and values [S,KV,D] go to the pages ``pages[r]``, its final
-    state and tail to slot ``slots[r]`` whole (nothing of the slot's
-    previous tenant survives; a dummy row's slot is out of bounds and
-    its scatter is dropped)."""
-    if spec.mixer == "attention":
-        k_pages, v_pages = entry
-        with jax.named_scope("kv_append"):
-            for r in range(pages.shape[0]):
-                k_pages, v_pages = write_prefill_kv(
-                    k_pages, v_pages, kept[0][r], kept[1][r], pages[r])
-        return k_pages, v_pages
-    with jax.named_scope("kda_state"):
-        return tuple(held.at[slots].set(new)
-                     for held, new in zip(entry, kept))
+    [B, K-1, channels]; a latent layer keeps ``(latent_pages,)``
+    [num_pages, 1, page_size, ``cfg.latent_width``]."""
+    return tuple(MIXERS_BY_KIND[spec.mixer].init(cfg, icfg)
+                 for spec in cfg.layers)
 
 
 def prefill_cached(params, cfg: DecoderConfig, cache, tokens, plens, slots,
@@ -407,8 +622,9 @@ def prefill_cached(params, cfg: DecoderConfig, cache, tokens, plens, slots,
     [N,V] f32 at each row's last position, cache, picks a held expert
     summed over the layers or None)."""
     x, kept, counts = _prefill_hidden(params, cfg, tokens, plens, requests)
-    cache = tuple(_write_kept(spec, entry, keep, slots, pages)
-                  for spec, entry, keep in zip(cfg.layers, cache, kept))
+    cache = tuple(
+        MIXERS_BY_KIND[spec.mixer].write(entry, keep, slots, pages)
+        for spec, entry, keep in zip(cfg.layers, cache, kept))
     last = x[jnp.arange(tokens.shape[0]), plens - 1]
     return _head(params, cfg, last, "bd,vd->bv"), cache, counts
 
@@ -472,15 +688,16 @@ def decode_chunk_cached(params, cfg: DecoderConfig, tokens, cache, page_table,
 # ----------------------------------------------------------------------
 # the same forward for callers that hold the keys and values themselves
 # (chip_smoke.py, the benchmark's compile checks): models whose every
-# mixer is attention, since one with recurrent state has more to hand
-# on than keys and values, and goes through the engine
+# mixer is attention, since one with recurrent state or a latent cache
+# has other things to hand on than keys and values, and goes through
+# the engine
 # ----------------------------------------------------------------------
 
 def _attention_only(cfg, who: str) -> DecoderConfig:
     cfg = describe(cfg)
-    if cfg.state_layers:
+    if cfg.state_layers or cfg.latent_layers:
         raise ValueError(f"{who} carries keys and values only; this model "
-                         f"keeps recurrent state too")
+                         f"keeps recurrent state or latent rows too")
     return cfg
 
 

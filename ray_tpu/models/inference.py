@@ -178,11 +178,12 @@ class InferenceEngine:
         # a TransformerConfig or a DecoderConfig; the engine reads the
         # layer-by-layer description either way
         self.mcfg = mcfg = describe(model_cfg)
-        if mcfg.state_layers and mode != "both":
+        if (mcfg.state_layers or mcfg.latent_layers) and mode != "both":
             raise ValueError(
                 f"engine mode {mode!r} hands keys and values from a "
                 f"prefill replica to a decode replica; this model keeps "
-                f"recurrent state in layers {mcfg.state_layers}, "
+                f"recurrent state in layers {mcfg.state_layers} and "
+                f"latent rows in layers {mcfg.latent_layers}, "
                 f"which the handoff does not carry: serve it in mode "
                 f"'both'")
         self.cfg = cfg
@@ -348,11 +349,11 @@ class InferenceEngine:
             raise RuntimeError(
                 f"engine is in {self.mode!r} mode; this entry point "
                 f"needs {wants!r}")
-        if self.mcfg.state_layers:
+        if self.mcfg.state_layers or self.mcfg.latent_layers:
             raise RuntimeError(
                 "the KV handoff carries keys and values only; this model "
-                "keeps recurrent state too and is served whole (submit, "
-                "submit_stream)")
+                "keeps recurrent state or latent rows too and is served "
+                "whole (submit, submit_stream)")
 
     def submit(self, prompt: Sequence[int],
                max_new_tokens: Optional[int] = None) -> Future:
@@ -472,7 +473,10 @@ class InferenceEngine:
         ``moe_picks_local`` those that fell on an expert held here and
         were computed, ``moe_load_by_expert`` the same by held expert.
         ``state_bytes`` is what the recurrent layers keep for all slots
-        and ``pool_tokens`` the tokens the page pool can hold."""
+        and ``pool_tokens`` the tokens the page pool can hold;
+        ``latent_bytes_per_token`` is what the latent layers together
+        hold for a token (their rows as they lie in memory, padded to
+        whole lanes) and ``latent_pool_bytes`` their pools whole."""
         with self._lock:
             by_bucket = {
                 b: {"launches": n, "rows": rows, "useful_rows": useful,
@@ -507,6 +511,12 @@ class InferenceEngine:
                 "state_bytes": sum(
                     a.nbytes for i in self.mcfg.state_layers
                     for a in jax.tree_util.tree_leaves(self._cache[i])),
+                "latent_bytes_per_token": (
+                    len(self.mcfg.latent_layers) * self.mcfg.latent_width
+                    * jnp.dtype(self.mcfg.dtype).itemsize),
+                "latent_pool_bytes": sum(
+                    a.nbytes for i in self.mcfg.latent_layers
+                    for a in self._cache[i]),
                 "pool_tokens": (max(0, self.cfg.num_pages - 1)
                                 * self.cfg.page_size
                                 if self.mode != "prefill" else 0),

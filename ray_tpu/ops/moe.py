@@ -143,17 +143,21 @@ def moe_ffn_sharded(tokens, w_router, w_in, w_out, mesh,
 # the deployed expert layer: top-k of all, dropless, a held share
 # ----------------------------------------------------------------------
 
-def route_topk(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int
-               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def route_topk(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int,
+               scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x [T,d], w_router [d,E] -> (ids [T,k] int32, weights [T,k] f32).
     The router runs in float32 whatever the model's type: a near-tie
     between the k-th and the next expert decides which experts a token
-    sees. Scores are sigmoids; the weights of the k picked sum to 1."""
+    sees. Scores are sigmoids; the weights of the k picked sum to
+    ``scale`` (a model's routed scaling factor; 1 multiplies
+    nothing)."""
     scores = jax.nn.sigmoid(jnp.einsum(
         "td,de->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     picked, ids = jax.lax.top_k(scores, top_k)
-    return ids.astype(jnp.int32), picked / picked.sum(-1, keepdims=True)
+    weights = picked / picked.sum(-1, keepdims=True)
+    return ids.astype(jnp.int32), (weights if scale == 1.0
+                                   else weights * scale)
 
 
 def _experts_held_block(x, ids, weights, valid, w_gate, w_up, w_down,

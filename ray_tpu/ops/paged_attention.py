@@ -23,15 +23,22 @@ The read, its oracle, and the two writes:
     the lengths and the next live sequence ride scalar prefetch. Online
     softmax state (m, l, acc) in float32, as are scores and
     probabilities; K and V reach the products as the pool holds them;
+  - ``paged_latent_attention``: the same walk over ONE pool whose row
+    a token serves as key and as value (latent attention in its
+    absorbed form: one shared "KV head", every query head against it,
+    the value a row's first ``value_width`` columns): one DMA a page,
+    scores over the whole row, values from the same VMEM buffer. The
+    trace keeps its name, ``mla_paged_read``;
   - ``paged_attention_reference``: pure-XLA gather of every page of
     every slot over the page table. The numerics oracle of the tests;
     no program calls it (until PR 30 contexts under 2,048 tokens ran
     it: PERF.md section 6);
-  - ``append_token_kv``: a decode step's write, one cell a sequence.
-    A Pallas kernel too: each sequence's tail page goes through VMEM
+  - ``append_token`` (``append_token_kv`` for K and V): a decode
+    step's write, one cell a sequence in each pool it is given. A
+    Pallas kernel too: each sequence's tail page goes through VMEM
     and back to where it lay, the rest of the pool is not touched;
-  - ``write_prefill_kv``: a prompt's write, whole pages by an XLA
-    scatter.
+  - ``write_prefill_pages`` (``write_prefill_kv``): a prompt's write,
+    whole pages by an XLA scatter.
 
 Layout: K/V pages are [n_pages, n_kv_heads, page_size, head_dim];
 queries are single decode tokens [B, n_heads, head_dim] (GQA: n_heads =
@@ -57,10 +64,13 @@ NEG_INF = -1e30
 def paged_attention_reference(q: jnp.ndarray, k_pages: jnp.ndarray,
                               v_pages: jnp.ndarray,
                               page_table: jnp.ndarray,
-                              seq_lens: jnp.ndarray) -> jnp.ndarray:
-    """q [B,H,D]; k_pages/v_pages [P,KV,page,D]; page_table [B,MP]
-    (physical page per logical page, 0-padded); seq_lens [B] = valid
-    cache tokens per sequence. Returns [B,H,D] (f32)."""
+                              seq_lens: jnp.ndarray,
+                              score_width: int = 0) -> jnp.ndarray:
+    """q [B,H,D]; k_pages [P,KV,page,D], v_pages [P,KV,page,Dv];
+    page_table [B,MP] (physical page per logical page, 0-padded);
+    seq_lens [B] = valid cache tokens per sequence. Scores are divided
+    by the root of ``score_width`` (D when 0). Returns [B,H,Dv]
+    (f32)."""
     B, H, D = q.shape
     _P, KV, page, _D = k_pages.shape
     MP = page_table.shape[1]
@@ -70,16 +80,18 @@ def paged_attention_reference(q: jnp.ndarray, k_pages: jnp.ndarray,
     k = k_pages[page_table]  # [B, MP, KV, page, D]
     v = v_pages[page_table]
     k = k.transpose(0, 2, 1, 3, 4).reshape(B, KV, MP * page, D)
-    v = v.transpose(0, 2, 1, 3, 4).reshape(B, KV, MP * page, D)
+    v = v.transpose(0, 2, 1, 3, 4).reshape(B, KV, MP * page, -1)
 
     qg = q.reshape(B, KV, G, D).astype(jnp.float32)
     scores = jnp.einsum("bkgd,bktd->bkgt", qg,
-                        k.astype(jnp.float32)) / jnp.sqrt(D)
+                        k.astype(jnp.float32)) / jnp.sqrt(score_width or D)
     valid = jnp.arange(MP * page)[None, :] < seq_lens[:, None]  # [B,T]
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgt,bktd->bkgd", probs, v.astype(jnp.float32))
-    return out.reshape(B, H, D)
+    # a masked row may hold anything (a parking page's NaN): 0 x NaN
+    v = jnp.where(valid[:, None, :, None], v.astype(jnp.float32), 0.0)
+    out = jnp.einsum("bkgt,bktd->bkgd", probs, v)
+    return out.reshape(B, H, -1)
 
 
 # ----------------------------------------------------------------------
@@ -90,22 +102,39 @@ def paged_attention_reference(q: jnp.ndarray, k_pages: jnp.ndarray,
 # width of the score matrix a head. PERF.md section 6, PR 30 has the
 # measurement behind the number.
 BLOCK_TOKENS = 256
+# the same for the latent read, whose block is one pool's pages and 128
+# query heads' scores: 1,024 tokens a block took 0.49 ms where 256 took
+# 0.73 and 512 took 0.57 (32 slots, 170 k live tokens; PERF.md section
+# 6, PR 31 has the table)
+LATENT_BLOCK_TOKENS = 1024
 
 
-def _read_kernel(table_ref, lens_ref, next_ref, q_ref, k_hbm, v_hbm, o_ref,
-                 k_buf, v_buf, sems, m_ref, l_ref, acc_ref, blocks_ref, *,
-                 page: int, pages_a_block: int, max_pages: int):
+def _read_kernel(table_ref, lens_ref, next_ref, q_ref, *refs,
+                 page: int, pages_a_block: int, max_pages: int,
+                 n_pools: int, score_width: int, value_width: int):
     """One grid cell = one sequence, walked a block of ``pages_a_block``
     pages at a time up to its length. The pools stay in HBM: a block's
-    pages come by one DMA each, K and V, into one of two VMEM buffers
+    pages come by one DMA each and pool, into one of two VMEM buffers
     laid out [KV, tokens, D], so that a head's product runs over the
     whole block. While a block is computed the next one is in flight:
     the sequence's own next block, or the first block of the next live
     sequence (``next_ref``), which that cell then finds arriving. An
-    idle slot starts and waits for nothing."""
+    idle slot starts and waits for nothing.
+
+    ``refs``: the ``n_pools`` pools, the output, a buffer a pool, the
+    semaphores and the softmax state. Two pools are keys and values.
+    One pool is both: scores over a row's whole width, values its first
+    ``value_width`` columns out of the same buffer, and the
+    probabilities go to that product in the pool's type (128 query
+    heads against one row make it a matrix product worth the MXU's
+    rate; in float32 it would take several passes)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    pools, o_ref = refs[:n_pools], refs[n_pools]
+    bufs = refs[n_pools + 1:2 * n_pools + 1]
+    sems, m_ref, l_ref, acc_ref, blocks_ref = refs[2 * n_pools + 1:]
+    k_buf = bufs[0]
     b = pl.program_id(0)
     n_seqs = pl.num_programs(0)
     T = pages_a_block * page
@@ -119,7 +148,7 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, k_hbm, v_hbm, o_ref,
         def one(i, carry):
             pid = table_ref[seq, first + i]
             rows = pl.ds(pl.multiple_of(i * page, page), page)
-            for pool, buf, which in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+            for which, (pool, buf) in enumerate(zip(pools, bufs)):
                 getattr(pltpu.make_async_copy(
                     pool.at[pid], buf.at[slot, :, rows],
                     sems.at[which, slot]), what)()
@@ -158,7 +187,7 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, k_hbm, v_hbm, o_ref,
         dtype = jnp.promote_types(q.dtype, k.dtype)
         s = jnp.einsum("kgd,ktd->kgt", q.astype(dtype), k.astype(dtype),
                        preferred_element_type=jnp.float32) / jnp.sqrt(
-                           q.shape[-1] * 1.0)       # [KV, G, T]
+                           score_width * 1.0)       # [KV, G, T]
         # past the length a buffer holds what an earlier block left
         # there, or nothing yet: a score there counts for nothing, and a
         # value row there must not reach the product (0 x NaN)
@@ -167,8 +196,12 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, k_hbm, v_hbm, o_ref,
                 jnp.int32, shape, axis) < seq_len
 
         s = jnp.where(live((1, 1, T), 2), s, NEG_INF)
-        v = jnp.where(live((1, T, 1), 1), v_buf[slot].astype(jnp.float32),
-                      0.0)
+        if n_pools == 2:
+            v = jnp.where(live((1, T, 1), 1),
+                          bufs[1][slot].astype(jnp.float32), 0.0)
+        else:
+            v = k_buf[slot, :, :, :value_width]
+            v = jnp.where(live((1, T, 1), 1), v, jnp.zeros_like(v))
 
         m_prev = m_ref[...]                         # [KV, G, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -176,7 +209,8 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, k_hbm, v_hbm, o_ref,
         probs = jnp.exp(s - m_new)                  # [KV, G, T]
         l_ref[...] = l_ref[...] * alpha + probs.sum(-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
-            "kgt,ktd->kgd", probs, v, preferred_element_type=jnp.float32)
+            "kgt,ktd->kgd", probs.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
         return carry
 
@@ -185,6 +219,66 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, k_hbm, v_hbm, o_ref,
     # an idle slot's row is zeros: finite, and discarded by the caller
     o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
         o_ref.dtype)
+
+
+def _paged_read(q, pools, page_table, seq_lens, *, block_tokens: int,
+                interpret: bool, score_width: int, value_width: int,
+                name=None) -> jnp.ndarray:
+    """The walk's ``pallas_call``: q [B,KV,G,D] against ``pools`` ([P,
+    KV,page,D] each; two are keys and values, one is both). Returns
+    [B,KV,G,value_width] float32."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, KV, G, D = q.shape
+    P, _KV, page, _D = pools[0].shape
+    MP = page_table.shape[1]
+    pages_a_block = max(1, min(block_tokens // page, MP))
+    T = pages_a_block * page
+    lens = seq_lens.astype(jnp.int32)
+    # as in append_token: an id outside the pool is clipped, so that
+    # no DMA leaves it
+    table = jnp.clip(page_table, 0, P - 1).astype(jnp.int32)
+    # next_live[0] the first live sequence, next_live[b + 1] the first
+    # after b; B where there is none
+    ids = jnp.where(lens > 0, jnp.arange(B, dtype=jnp.int32), B)
+    next_live = jnp.concatenate([
+        jax.lax.cummin(ids, reverse=True), jnp.full((1,), B, jnp.int32)])
+
+    kernel = functools.partial(
+        _read_kernel, page=page, pages_a_block=pages_a_block, max_pages=MP,
+        n_pools=len(pools), score_width=score_width,
+        value_width=value_width)
+
+    def head_rows(width):
+        return pl.BlockSpec((1, KV, G, width),
+                            lambda b, table, lens, nxt: (b, 0, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,   # page_table, seq_lens, next_live
+        grid=(B,),
+        # the pools are read where they lie: left to the compiler, one
+        # that fits VMEM may be fetched there whole, every step
+        in_specs=[head_rows(D)] + [pl.BlockSpec(memory_space=pltpu.HBM)
+                                   for _ in pools],
+        out_specs=head_rows(value_width),
+        scratch_shapes=[
+            *[pltpu.VMEM((2, KV, T, D), pool.dtype) for pool in pools],
+            pltpu.SemaphoreType.DMA((len(pools), 2)),   # (pool, buffer)
+            pltpu.VMEM((KV, G, 1), jnp.float32),    # m (running max)
+            pltpu.VMEM((KV, G, 1), jnp.float32),    # l (running denom)
+            pltpu.VMEM((KV, G, value_width), jnp.float32),    # acc
+            pltpu.SMEM((1,), jnp.int32),            # blocks walked
+        ],
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, value_width),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name=name,
+    )(table, lens, next_live, q, *pools)
 
 
 @functools.partial(jax.jit, static_argnames=("block_tokens", "interpret"))
@@ -198,56 +292,33 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     its own, so that a decode program traces the kernel once and not
     once a layer, and the engine's six programs once between them
     (tracing it costs what a whole layer's einsums do)."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     B, H, D = q.shape
-    P, KV, page, _D = k_pages.shape
-    MP = page_table.shape[1]
-    G = H // KV
-    pages_a_block = max(1, min(block_tokens // page, MP))
-    T = pages_a_block * page
-    lens = seq_lens.astype(jnp.int32)
-    # as in append_token_kv: an id outside the pool is clipped, so that
-    # no DMA leaves it
-    table = jnp.clip(page_table, 0, P - 1).astype(jnp.int32)
-    # next_live[0] the first live sequence, next_live[b + 1] the first
-    # after b; B where there is none
-    ids = jnp.where(lens > 0, jnp.arange(B, dtype=jnp.int32), B)
-    next_live = jnp.concatenate([
-        jax.lax.cummin(ids, reverse=True), jnp.full((1,), B, jnp.int32)])
-
-    kernel = functools.partial(_read_kernel, page=page,
-                               pages_a_block=pages_a_block, max_pages=MP)
-    head_rows = pl.BlockSpec((1, KV, G, D),
-                             lambda b, table, lens, nxt: (b, 0, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,   # page_table, seq_lens, next_live
-        grid=(B,),
-        # the pools are read where they lie: left to the compiler, one
-        # that fits VMEM may be fetched there whole, every step
-        in_specs=[head_rows,
-                  pl.BlockSpec(memory_space=pltpu.HBM),
-                  pl.BlockSpec(memory_space=pltpu.HBM)],
-        out_specs=head_rows,
-        scratch_shapes=[
-            pltpu.VMEM((2, KV, T, D), k_pages.dtype),
-            pltpu.VMEM((2, KV, T, D), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),        # (K or V, buffer)
-            pltpu.VMEM((KV, G, 1), jnp.float32),    # m (running max)
-            pltpu.VMEM((KV, G, 1), jnp.float32),    # l (running denom)
-            pltpu.VMEM((KV, G, D), jnp.float32),    # acc
-            pltpu.SMEM((1,), jnp.int32),            # blocks walked
-        ],
-    )
-    out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(table, lens, next_live, q.reshape(B, KV, G, D), k_pages, v_pages)
+    KV = k_pages.shape[1]
+    out = _paged_read(q.reshape(B, KV, H // KV, D), (k_pages, v_pages),
+                      page_table, seq_lens, block_tokens=block_tokens,
+                      interpret=interpret, score_width=D, value_width=D)
     return out.reshape(B, H, D)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "score_width", "value_width", "block_tokens", "interpret"))
+def paged_latent_attention(q: jnp.ndarray, pages: jnp.ndarray,
+                           page_table: jnp.ndarray, seq_lens: jnp.ndarray,
+                           *, score_width: int, value_width: int,
+                           block_tokens: int = LATENT_BLOCK_TOKENS,
+                           interpret: bool = False) -> jnp.ndarray:
+    """The walk of ``paged_attention`` over ONE pool [P,1,page,W] whose
+    row a token is its key and, in the first ``value_width`` columns,
+    its value: q [B,H,W] (every head against the one row) -> [B,H,
+    value_width] float32. Scores are divided by the root of
+    ``score_width`` (the width of the keys the row stands for, which is
+    not W). A page is fetched once."""
+    B, H, W = q.shape
+    out = _paged_read(q.reshape(B, 1, H, W), (pages,), page_table,
+                      seq_lens, block_tokens=block_tokens,
+                      interpret=interpret, score_width=score_width,
+                      value_width=value_width, name="mla_paged_read")
+    return out.reshape(B, H, value_width)
 
 
 def paged_attention_auto(q, k_pages, v_pages, page_table, seq_lens):
@@ -264,21 +335,23 @@ def paged_attention_auto(q, k_pages, v_pages, page_table, seq_lens):
 # page-cache update helpers (functional; jit-friendly)
 # ----------------------------------------------------------------------
 
-def _append_kernel(phys_ref, slot_ref, k_new_ref, v_new_ref, k_in_ref,
-                   v_in_ref, k_out_ref, v_out_ref):
-    """One grid cell = one sequence: its tail page comes in, the row
-    ``slot_ref[b]`` is replaced by the token, the page goes back to
-    where it came from. The row is picked by a ``where`` over an iota
-    (a dynamic sublane store into packed bfloat16 need not lower); a
-    slot of -1 picks none and the page goes back as it came."""
+def _append_kernel(phys_ref, slot_ref, *refs):
+    """One grid cell = one sequence: its tail page of each pool comes
+    in, the row ``slot_ref[b]`` is replaced by the token, the page goes
+    back to where it came from. ``refs``: a token, then a page in, then
+    a page out for each pool. The row is picked by a ``where`` over an
+    iota (a dynamic sublane store into packed bfloat16 need not lower);
+    a slot of -1 picks none and the page goes back as it came."""
     import jax.experimental.pallas as pl
 
     del phys_ref  # read by the index maps
+    n = len(refs) // 3
     slot = slot_ref[pl.program_id(0)]
-    hit = jax.lax.broadcasted_iota(jnp.int32, k_in_ref.shape[1:],
+    hit = jax.lax.broadcasted_iota(jnp.int32, refs[n].shape[1:],
                                    1) == slot
-    k_out_ref[0] = jnp.where(hit, k_new_ref[0][:, None, :], k_in_ref[0])
-    v_out_ref[0] = jnp.where(hit, v_new_ref[0][:, None, :], v_in_ref[0])
+    for new_ref, in_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                        refs[2 * n:]):
+        out_ref[0] = jnp.where(hit, new_ref[0][:, None, :], in_ref[0])
 
 
 def append_token_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
@@ -286,12 +359,21 @@ def append_token_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                     page_table: jnp.ndarray,
                     seq_lens: jnp.ndarray
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Write one decode token's K/V [B,KV,D] into each sequence's tail
-    cell (page_table[b, seq_len // page], seq_len % page), in place.
+    """``append_token`` for K and V in one call."""
+    return append_token((k_pages, v_pages), (k_new, v_new), page_table,
+                        seq_lens)
 
-    A Pallas kernel over grid (B,), K and V in one call: the pools are
-    input AND output of the same buffers (``input_output_aliases``) in
-    blocks of one page, chosen by the physical page ids that ride
+
+def append_token(pools: Tuple[jnp.ndarray, ...],
+                 news: Tuple[jnp.ndarray, ...], page_table: jnp.ndarray,
+                 seq_lens: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
+    """Write one decode token's rows ``news`` ([B,KV,D] each) into each
+    sequence's tail cell (page_table[b, seq_len // page], seq_len %
+    page) of ``pools`` ([P,KV,page,D] each, of one shape), in place.
+
+    A Pallas kernel over grid (B,), every pool in one call: the pools
+    are input AND output of the same buffers (``input_output_aliases``)
+    in blocks of one page, chosen by the physical page ids that ride
     scalar prefetch, so a step reads and writes back B pages and
     touches nothing else of the pool. A Pallas operand also pins the
     pool's default layout, the one the paged kernel reads: the one-hot
@@ -324,8 +406,9 @@ def append_token_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    P, KV, page, D = k_pages.shape
+    P, KV, page, D = pools[0].shape
     B, MP = page_table.shape
+    n = len(pools)
     logical = seq_lens // page
     phys = jnp.take_along_axis(page_table,
                                jnp.minimum(logical, MP - 1)[:, None],
@@ -340,39 +423,38 @@ def append_token_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,   # phys, slot
         grid=(B,),
-        in_specs=[token, token, tail_page, tail_page],
-        out_specs=[tail_page, tail_page],
+        in_specs=[token] * n + [tail_page] * n,
+        out_specs=[tail_page] * n,
     )
     return tuple(pl.pallas_call(
         _append_kernel, grid_spec=grid_spec,
-        out_shape=[pltpu.HBM(k_pages.shape, k_pages.dtype),
-                   pltpu.HBM(v_pages.shape, v_pages.dtype)],
-        # operands count the two prefetched scalars: 4, 5 are the pools
-        input_output_aliases={4: 0, 5: 1},
+        out_shape=[pltpu.HBM(pool.shape, pool.dtype) for pool in pools],
+        # operands count the two prefetched scalars and the tokens
+        input_output_aliases={2 + n + i: i for i in range(n)},
         interpret=jax.default_backend() != "tpu",
-    )(phys, slot, k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype),
-      k_pages, v_pages))
+    )(phys, slot, *[new.astype(pool.dtype)
+                    for new, pool in zip(news, pools)], *pools))
+
+
+def write_prefill_pages(pool: jnp.ndarray, seq: jnp.ndarray,
+                        pages: jnp.ndarray) -> jnp.ndarray:
+    """Write a prefilled sequence's rows [S,KV,D] into its pages ([n]
+    physical ids; S must be <= n*page_size — the tail page may be
+    partially filled, trailing slots are don't-care)."""
+    page = pool.shape[2]
+    n = pages.shape[0]
+    pad = n * page - seq.shape[0]
+    fill = jnp.concatenate(
+        [seq, jnp.zeros((pad,) + seq.shape[1:], seq.dtype)])
+    fill = fill.reshape(n, page, -1, seq.shape[-1]).transpose(
+        0, 2, 1, 3)  # [n, KV, page, D]
+    return pool.at[pages].set(fill.astype(pool.dtype))
 
 
 def write_prefill_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                      k_seq: jnp.ndarray, v_seq: jnp.ndarray,
                      pages: jnp.ndarray,
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Write a prefilled sequence's K/V [S,KV,D] into its pages
-    ([n] physical ids; S must be <= n*page_size — the tail page may be
-    partially filled, trailing slots are don't-care)."""
-    page = k_pages.shape[2]
-    n = pages.shape[0]
-    S = k_seq.shape[0]
-    pad = n * page - S
-    k_fill = jnp.concatenate(
-        [k_seq, jnp.zeros((pad,) + k_seq.shape[1:], k_seq.dtype)])
-    v_fill = jnp.concatenate(
-        [v_seq, jnp.zeros((pad,) + v_seq.shape[1:], v_seq.dtype)])
-    k_fill = k_fill.reshape(n, page, -1, k_seq.shape[-1]).transpose(
-        0, 2, 1, 3)  # [n, KV, page, D]
-    v_fill = v_fill.reshape(n, page, -1, v_seq.shape[-1]).transpose(
-        0, 2, 1, 3)
-    k_pages = k_pages.at[pages].set(k_fill.astype(k_pages.dtype))
-    v_pages = v_pages.at[pages].set(v_fill.astype(v_pages.dtype))
-    return k_pages, v_pages
+    """``write_prefill_pages`` for K and V."""
+    return (write_prefill_pages(k_pages, k_seq, pages),
+            write_prefill_pages(v_pages, v_seq, pages))

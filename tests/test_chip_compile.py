@@ -467,3 +467,120 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
     # analysis, not a chip reading)
     _no_pool_moves(text, (4097, 8, page, hd))
     assert m.temp_size_in_bytes < 1.9e9
+
+
+def _openpangu_two_layers(chip):
+    """The benchmark's latent-attention configuration at every
+    published width, its dense layer and ONE of its four expert layers
+    (16 held experts of a router of 256), an eighth of the vocabulary:
+    (description, parameter tree as shapes on the described chip)."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import weights_openpangu_ultra as W
+
+    with open(os.path.join(root, "benchmark", "configs",
+                           "openpangu-ultra-moe-718b-serve-L5-ep16.json")
+              ) as f:
+        config = dict(json.load(f), num_hidden_layers=2)
+    params = jax.eval_shape(
+        lambda k: W.init_params(config, k, jnp.bfloat16), W.seed_key(1))
+    return W.description(config), jax.tree_util.tree_map(
+        lambda x: chip(x.shape, x.dtype), params)
+
+
+def test_latent_decode_chunk(chip, monkeypatch, capsys):
+    """The decode program of the latent-attention cell at its own
+    geometry (32 slots, 2,561 pages of 128, 80 a sequence), 4 steps,
+    the dense layer and one expert layer, pools donated. A step appends
+    one row a slot IN PLACE and reads the rows its live sequences own
+    where they lie, each page ONCE: one ``mla_paged_read`` call a layer
+    (no second pool, no second read for the values), no copy that
+    yields a whole pool, no pool fetched into VMEM, no sequence-major
+    copy of the pages. The compiler's analysis, not a chip reading."""
+    from ray_tpu.models import decoder_forward
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mcfg, params = _openpangu_two_layers(chip)
+    slots, pages_a_seq = 32, 80
+    pool_dims = (2561, 1, 128, mcfg.latent_width)
+    assert mcfg.latent_width == 640
+    cache = tuple((chip(pool_dims, jnp.bfloat16),) for _ in mcfg.layers)
+    compiled = jax.jit(
+        lambda p, t, cache, table, lens, live:
+        decoder_forward.decode_chunk_cached(
+            p, mcfg, t, cache, table, lens, live, n_steps=4),
+        donate_argnums=(2,)).lower(
+            params, chip((slots,), jnp.int32), cache,
+            chip((slots, pages_a_seq), jnp.int32),
+            chip((slots,), jnp.int32), chip((slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    pool_bytes = len(mcfg.layers) * 2 * 2561 * 128 * 640
+    with capsys.disabled():
+        print(f"\n[latent decode chunk, 4 steps, 2 layers] arguments "
+              f"{m.argument_size_in_bytes / 1e9:.3f} GB (pools "
+              f"{pool_bytes / 1e9:.3f}), temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} GB")
+    reads = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.match(r"\s*%?mla_paged_read", line)]
+    assert len(reads) == len(mcfg.layers)
+    assert all(re.search(r'op_name="[^"]*/mla_absorb/', r) for r in reads)
+    appends = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and re.search(r'op_name="[^"]*/latent_append/', line)]
+    assert len(appends) == len(mcfg.layers)
+    _no_pool_moves(text, pool_dims)
+    assert [n for n, op, line in _pool_shaped(text, pool_dims)
+            if op == "fusion" and "latent_append" in line] == []
+    _no_gathered_copy(text, slots, pages_a_seq, 1, 128, 640)
+    assert m.alias_size_in_bytes >= pool_bytes
+    # w_kvb's two halves are views taken while tracing: what a chunk
+    # re-lays out of the weights stays far under a second copy of them
+    assert m.temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("bucket, rows", [(8192, 1), (1024, 8)])
+def test_latent_prefill_program(chip, monkeypatch, capsys, bucket, rows):
+    """A prefill launch of the latent-attention cell (the dense layer
+    and one expert layer): 8,192 positions in its largest bucket and
+    in its smallest, the expanded form through the kernel of
+    ops/mla_prefill.py a group of heads at a time. No [S,S] scores, no
+    copy of a pool, and temporaries that leave the chip room for the
+    other three expert layers' weights (the five-layer program:
+    1.6 GB)."""
+    from ray_tpu.models import decoder_forward
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mcfg, params = _openpangu_two_layers(chip)
+    pool_dims = (2561, 1, 128, mcfg.latent_width)
+    cache = tuple((chip(pool_dims, jnp.bfloat16),) for _ in mcfg.layers)
+    compiled = jax.jit(
+        lambda p, cache, toks, plens, slots, pages, req:
+        decoder_forward.prefill_cached(p, mcfg, cache, toks, plens, slots,
+                                       pages, req),
+        donate_argnums=(1,)).lower(
+            params, cache, chip((rows, bucket), jnp.int32),
+            chip((rows,), jnp.int32), chip((rows,), jnp.int32),
+            chip((rows, bucket // 128), jnp.int32),
+            chip((rows,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[latent prefill b{bucket}, {rows} rows, 2 layers] "
+              f"temporaries {m.temp_size_in_bytes / 1e9:.3f} GB")
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and re.match(r"\s*%?mla_prefill_attention", line)]
+    assert len(kernels) == len(mcfg.layers)       # one a layer, in a loop
+    assert all(re.search(r'op_name="[^"]*/mla/', k) for k in kernels)
+    # no scores of a whole row: [.., S, S] in any type
+    assert not re.search(rf"\[(?:\d+,)*{bucket},{bucket}\]", text)
+    _no_pool_moves(text, pool_dims)
+    assert m.alias_size_in_bytes >= len(mcfg.layers) * 2 * 2561 * 128 * 640
+    assert m.temp_size_in_bytes < 2 << 30

@@ -152,6 +152,78 @@ def test_ring_and_counters_agree_to_the_unit(run):
         assert f["chunks"] <= 4 and f["steps"] <= 4 * ICFG.decode_chunk
 
 
+def _openpangu(tiny: bool):
+    """(description, configuration) of the benchmark's latent-attention
+    model: as its cell runs it, or with the sizes of the benchmark's
+    tiny rehearsal laid over it."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import weights_openpangu_ultra as W
+
+    def load(*parts):
+        with open(os.path.join(root, "benchmark", *parts)) as f:
+            return json.load(f)
+
+    config = load("configs", "openpangu-ultra-moe-718b-serve-L5-ep16.json")
+    if tiny:
+        config.update(load("tests", "tiny_serve_described.json")["config"])
+    return W.description(config), config
+
+
+def test_a_latent_models_ring_and_counters_agree():
+    """The latent-attention model (a dense layer, then expert layers)
+    through the same engine: the ring's launches and deliveries carry
+    the picks and sum to ``stats()``'s; the dispatches carry the live
+    pages and tokens its read's roofline is counted from; the two
+    latent keys say what the pools hold."""
+    mcfg, tiny = _openpangu(tiny=True)
+    from benchmark import weights_openpangu_ultra as W
+
+    params = jax.jit(lambda k: W.init_params(tiny, k, jnp.float32))(
+        W.seed_key(3))
+    stats, outs, records = serve((mcfg, None, params))
+    assert [len(o) for o in outs] == MAX_NEW
+    counted = [r[5] for r in records if r[0] in (
+        "engine.prefill_launch", "engine.deliver")
+        and "moe_picks_total" in r[5]]
+    for key in ("moe_picks_total", "moe_picks_local"):
+        assert sum(f[key] for f in counted) == stats[key] > 0
+    # 4 picks a token in each of the two expert layers
+    tokens = sum(map(len, PROMPTS)) + sum(len(o) - 1 for o in outs)
+    assert stats["moe_picks_total"] == tokens * 4 * 2
+    dispatches = named(records, "engine.dispatch")
+    assert all({"live_slots", "live_ctx_tokens", "live_pages", "steps",
+                "chunks"} <= set(r[5]) for r in dispatches)
+    assert not any("state_slots_live" in r[5] for r in dispatches)
+    launches = named(records, "engine.prefill_launch")
+    assert all(len(r[5]["prompt_lens"]) == r[5]["useful_rows"]
+               for r in launches)
+    assert stats["latent_bytes_per_token"] == 3 * 128 * 4
+    assert stats["latent_pool_bytes"] == (
+        3 * ICFG.num_pages * ICFG.page_size * 128 * 4)
+    assert stats["pool_tokens"] == (ICFG.num_pages - 1) * ICFG.page_size
+
+
+def test_latent_bytes_a_token_at_the_published_widths():
+    """512 + 64 numbers a token and layer are 1,152 B in bfloat16; the
+    row is held as 640 columns (whole lanes of 128: the chip's memory
+    tiles it so anyway, and the kernel reads whole lanes), 1,280 B, so
+    the cell's five layers hold 6,400 B a token where the arithmetic of
+    the widths alone says 5,760."""
+    mcfg, _ = _openpangu(tiny=False)
+    assert mcfg.kv_rank + mcfg.rope_dim == 576 and mcfg.latent_width == 640
+    assert len(mcfg.latent_layers) == 5
+    per_token = len(mcfg.latent_layers) * mcfg.latent_width * 2
+    assert per_token == 6400 and 5 * 576 * 2 == 5760
+    # at most 1,280 B a token and layer (ISSUE 31's bound)
+    assert mcfg.latent_width * 2 <= 1280
+
+
 def test_request_spans_share_an_ident_and_are_ordered(run):
     _, _, records = run
     by_ident = collections.defaultdict(dict)

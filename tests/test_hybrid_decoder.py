@@ -20,7 +20,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from benchmark import weights_openpangu_ultra as WL  # noqa: E402
 from benchmark import weights_solar_open2 as W  # noqa: E402
+from benchmark.reference import openpangu_ultra as ref_latent  # noqa: E402
 from benchmark.reference import solar_open2 as ref  # noqa: E402
 from ray_tpu._private import spans  # noqa: E402
 from ray_tpu.models import decoder_forward as forward  # noqa: E402
@@ -360,6 +362,34 @@ def dense():
     return cfg, params["params"]
 
 
+# the third family: latent attention in every layer, sandwich norms, a
+# dense feed-forward then expert layers with a scaled router
+TINY_LATENT = {
+    "hidden_size": 64, "num_attention_heads": 4, "vocab_size": 96,
+    "num_hidden_layers": 3, "first_k_dense_replace": 1,
+    "q_lora_rank": 24, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 12, "intermediate_size": 80,
+    "router_width": 16, "experts_held": [0, 8], "num_experts_per_tok": 4,
+    "moe_intermediate_size": 32, "n_shared_experts": 1,
+    "routed_scaling_factor": 2.5, "sandwich_norm": True,
+    "rms_norm_eps": 1e-5, "rope_theta": 25600000,
+    "tie_word_embeddings": False,
+    "run": {"dtype": "float32", "param_dtype": "float32"},
+}
+
+
+@pytest.fixture(scope="module")
+def latent():
+    params = jax.jit(lambda k: WL.init_params(TINY_LATENT, k, jnp.float32))(
+        WL.seed_key(SEED))
+    return WL.description(TINY_LATENT), params
+
+
+def latent_logits(rows):
+    return np.asarray(ref_latent.teacher_forced_logits(
+        TINY_LATENT, SEED, np.asarray(rows, np.int32), "f32", jnp.float32))
+
+
 RAGGED = [([7], 5), ([1, 2, 3, 4, 5, 6, 7, 8, 9], 9), ([9, 9, 9], 1),
           ([5] * 16, 7), ([3, 4], 6), ([2] * 11, 3)]
 
@@ -371,30 +401,37 @@ def dense_logits(dense, rows):
                                              jnp.asarray(rows)))
 
 
-@pytest.mark.parametrize("kind", ["dense", "hybrid"])
-def test_one_program_family_for_every_description(kind, dense, model):
-    """An engine built from the dense decoder's description and one
-    built from the hybrid's expose the SAME programs: each lowers with
+@pytest.mark.parametrize("kind", ["dense", "hybrid", "latent"])
+def test_one_program_family_for_every_description(kind, dense, model,
+                                                  latent):
+    """An engine built from the dense decoder's description, one built
+    from the hybrid's and one from the latent-attention model's (a dense
+    layer then expert layers, one pool a layer) expose the SAME
+    programs: each lowers with
     one argument list (parameters, the cache as one donated pytree, the
     packed rows or the burst's table, lengths and live mask), and hands
     the cache back in the structure it took it. And what they serve for
     ragged prompts (dummy rows in the launches, idle slots beside live
     ones, slots reused) is, token by token, the argmax of the model's
     full forward: the flax ``Transformer``'s for the dense decoder, the
-    plain reference's for the hybrid."""
+    plain references' for the other two."""
     if kind == "dense":
         mcfg, params = dense
         full_forward, vocab = lambda rows: dense_logits(dense, rows), 64
-    else:
+    elif kind == "hybrid":
         mcfg, params = model
         full_forward, vocab = reference_logits, 96
+    else:
+        mcfg, params = latent
+        full_forward, vocab = latent_logits, 96
     icfg = InferenceConfig(batch_size=3, page_size=4, max_pages_per_seq=8,
                            num_pages=32, prefill_buckets=(8, 16),
                            max_new_tokens=8, decode_chunk=2)
     eng = InferenceEngine(params, mcfg, icfg)
     try:
         cache = jax.tree_util.tree_structure(eng._cache)
-        assert cache.num_leaves == 2 * len(eng.mcfg.layers)
+        assert cache.num_leaves == (1 if kind == "latent" else 2) * len(
+            eng.mcfg.layers)
         rows = icfg.batch_size
         ints = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
         split = eng._split_packed.lower(ints(rows, 9))

@@ -89,6 +89,35 @@ def test_the_shares_add_up_to_the_uncut_layer(layer):
     assert np.abs(np.asarray(parts[0][0]) - whole).max() > 1e-3
 
 
+def test_sixteen_shares_with_a_routed_scale_add_up(layer):
+    """The latent-attention model's deployment at a small size: a
+    router of 32, 16 chips of 2 experts each, the picked experts'
+    normalised weights scaled by 2.5, the shared expert unscaled and
+    counted ONCE: the parts add up to the uncut layer."""
+    p = dict(layer, router=layer["router"][:, :32],
+             **{w: layer[w][:32] for w in ("w_gate", "w_up", "w_down")})
+    x = p["x"]
+    ids, w = route_topk(x, p["router"], K, 2.5)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 2.5, atol=1e-6)
+    ids1, w1 = route_topk(x, p["router"], K)
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids1))
+    np.testing.assert_allclose(np.asarray(w), 2.5 * np.asarray(w1),
+                               rtol=1e-6)
+    parts = [experts_held(x, ids, w, p["w_gate"][lo:lo + 2],
+                          p["w_up"][lo:lo + 2], p["w_down"][lo:lo + 2], lo)
+             for lo in range(0, 32, 2)]
+    assert len(parts) == 16
+    sh = p["shared"]
+    shared = np.asarray(swiglu(x, sh["w_gate"], sh["w_up"], sh["w_down"]))
+    whole, _ = loop_over_experts(p, 0, 32)
+    np.testing.assert_allclose(
+        np.asarray(sum(y for y, _ in parts)) + shared,
+        2.5 * whole + shared, atol=1e-5)
+    assert sum(int(c.sum()) for _, c in parts) == 50 * K
+    # the shared expert scaled too would be another layer
+    assert np.abs(2.5 * shared - shared).max() > 1e-3
+
+
 def test_no_capacity_every_pick_on_one_expert_is_computed(layer):
     """All tokens alike: all 50 pick the same experts; nothing drops."""
     p = dict(layer, x=jnp.tile(layer["x"][:1], (50, 1)))
