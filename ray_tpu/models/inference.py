@@ -84,6 +84,14 @@ def _program(name: str, fn, **jit_kwargs):
     return jax.jit(call, **jit_kwargs)
 
 
+# positions of a prefill launch past which further rows buy nothing: a
+# launch has a fixed cost (every weight read once, the program's own
+# overhead), and rows exist so that short prompts share it. A row this
+# long takes five times that cost by itself (PERF.md, PR 32: launch
+# times on the chip), so a dummy companion would cost more than a real
+# one could save, and the row runs alone.
+_LAUNCH_POSITIONS = 2048
+
 _STREAM_END = object()
 
 
@@ -283,12 +291,14 @@ class InferenceEngine:
             first = nxt if counts is None else jnp.concatenate([nxt, counts])
             return first, toks_vec, cache
 
-        # every launch computes one budget of positions, that of a
-        # single prompt in the largest bucket: a bucket b runs
-        # largest // b rows (at most batch_size, at least 1)
-        largest = max(cfg.prefill_buckets)
+        # every launch has one budget of positions: a single prompt in
+        # the largest bucket, but no more than it takes to amortise a
+        # launch's fixed cost (_LAUNCH_POSITIONS). A bucket b runs
+        # budget // b rows (at most batch_size, at least 1), so a
+        # bucket as long as the budget or longer runs its one row alone
+        budget = min(max(cfg.prefill_buckets), _LAUNCH_POSITIONS)
         self._prefill_rows = {
-            b: max(1, min(cfg.batch_size, largest // b))
+            b: max(1, min(cfg.batch_size, budget // b))
             for b in cfg.prefill_buckets}
         self._prefill_many = ({} if mode == "decode" else {
             b: _program(
@@ -458,10 +468,11 @@ class InferenceEngine:
         (``batch_size`` x ``max_pages_per_seq`` a step): the share of
         the table a step's attention has to read. A burst is one round
         of the loop that dispatched something and fetched once. A
-        prefill launch of
-        bucket ``b`` runs ``largest_bucket // b`` rows (at most
-        ``batch_size``, at least 1), so every launch computes about the
-        positions of one prompt in the largest bucket; summed over the
+        prefill launch of bucket ``b`` runs ``budget // b`` rows (at
+        most ``batch_size``, at least 1), where the budget is the
+        largest bucket or ``_LAUNCH_POSITIONS`` if that is less: short
+        buckets share a launch of about that many positions, a bucket
+        that long or longer runs its one row alone; summed over the
         launches these are ``prefill_rows`` and ``prefill_positions``
         (rows x bucket), run for the ``prefill_useful_rows`` requests
         admitted in them and their ``prefill_prompt_tokens``;
@@ -615,7 +626,8 @@ class InferenceEngine:
         # serving, whenever a size came up for the first time. The
         # dummy rows run the whole forward like any other, so a bucket
         # gets as many rows as fit a launch's budget of positions
-        # (_prefill_rows) and a larger group takes more launches.
+        # (_prefill_rows: one row from _LAUNCH_POSITIONS up) and a
+        # larger group takes more launches.
         n = self._prefill_rows[bucket]
         packed = np.zeros((n, width), np.int32)
         # dummy pad rows: scatter target out of bounds (dropped), pages

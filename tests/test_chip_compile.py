@@ -469,11 +469,9 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
     assert m.temp_size_in_bytes < 1.9e9
 
 
-def _openpangu_two_layers(chip):
-    """The benchmark's latent-attention configuration at every
-    published width, its dense layer and ONE of its four expert layers
-    (16 held experts of a router of 256), an eighth of the vocabulary:
-    (description, parameter tree as shapes on the described chip)."""
+def _benchmark_config(name, **over):
+    """A configuration file of the benchmark with ``over`` laid over
+    it; puts the checkout on the path, for the weights modules."""
     import json
     import os
     import sys
@@ -481,12 +479,75 @@ def _openpangu_two_layers(chip):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
+    with open(os.path.join(root, "benchmark", "configs",
+                           name + ".json")) as f:
+        return dict(json.load(f), **over)
+
+
+def test_hybrid_prefill_launch_of_a_long_bucket(chip, monkeypatch, capsys):
+    """The long-document cell's ``engine_prefill_b4096`` at every width
+    of its configuration, cut to the gated-GQA layer and one delta-rule
+    layer (40 held experts each), 8 slots and 265 pages of 128 (slots
+    and pages are donated arguments, not temporaries): the engine gives
+    a bucket of 2,048 positions and more ONE row, and its own jitted
+    program compiles for the described chip. Beside it the same program
+    at the four rows PR 26's rule gave it (16,384 positions whatever
+    arrived). The compiler's analysis, not a chip reading."""
+    from ray_tpu.models.decoder import DecoderConfig, LayerSpec
+    from ray_tpu.models.inference import InferenceConfig, InferenceEngine
+
+    config = _benchmark_config("solar-open2-250b-serve-L4-ep8",
+                               num_hidden_layers=2)
+    from benchmark import weights_solar_open2 as W
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mcfg = DecoderConfig(
+        layers=(LayerSpec("attention", "experts"),
+                LayerSpec("delta_rule", "experts")),
+        **W.decoder_kwargs(config))
+    params = jax.eval_shape(
+        lambda k: W.init_params(config, k, jnp.bfloat16), W.seed_key(1))
+    icfg = InferenceConfig(batch_size=8, page_size=128, max_pages_per_seq=132,
+                           num_pages=265,
+                           prefill_buckets=(1024, 2048, 4096, 8192, 16384))
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: chip(x.shape, x.dtype), tree)
+    bucket, temporaries = 4096, {}
+    engine = InferenceEngine(params, mcfg, icfg)
+    try:
+        assert engine._prefill_rows == {1024: 2, 2048: 1, 4096: 1, 8192: 1,
+                                        16384: 1}
+        for rows in (engine._prefill_rows[bucket], 4):
+            compiled = engine._prefill_many[bucket].lower(
+                on_chip(params),
+                chip((rows, 2 + bucket + bucket // 128), jnp.int32),
+                on_chip(engine._cache), on_chip(engine._dev_toks)).compile()
+            text = compiled.as_text()
+            assert text.startswith(f"HloModule jit_engine_prefill_b{bucket}")
+            assert "ragged-dot" in text and "tpu_custom_call" in text
+            temporaries[rows] = compiled.memory_analysis().temp_size_in_bytes
+    finally:
+        engine.shutdown()
+    with capsys.disabled():
+        print(f"\n[hybrid engine_prefill_b{bucket}, 2 layers] temporaries "
+              f"{temporaries[1] / 1e9:.3f} GB at 1 row, "
+              f"{temporaries[4] / 1e9:.3f} GB at the 4 rows of PR 26's rule")
+    # a lone prompt's launch holds less than the four-row launch did
+    # (0.90 against 1.77 GB: the delta rule's segment is 2,048
+    # positions either way, the rest follows the positions)
+    assert temporaries[1] < 0.75 * temporaries[4]
+    assert temporaries[1] < 1 << 30
+
+
+def _openpangu_two_layers(chip):
+    """The benchmark's latent-attention configuration at every
+    published width, its dense layer and ONE of its four expert layers
+    (16 held experts of a router of 256), an eighth of the vocabulary:
+    (description, parameter tree as shapes on the described chip)."""
+    config = _benchmark_config("openpangu-ultra-moe-718b-serve-L5-ep16",
+                               num_hidden_layers=2)
     from benchmark import weights_openpangu_ultra as W
 
-    with open(os.path.join(root, "benchmark", "configs",
-                           "openpangu-ultra-moe-718b-serve-L5-ep16.json")
-              ) as f:
-        config = dict(json.load(f), num_hidden_layers=2)
     params = jax.eval_shape(
         lambda k: W.init_params(config, k, jnp.bfloat16), W.seed_key(1))
     return W.description(config), jax.tree_util.tree_map(
@@ -545,11 +606,12 @@ def test_latent_decode_chunk(chip, monkeypatch, capsys):
     assert m.temp_size_in_bytes < 1 << 30
 
 
-@pytest.mark.parametrize("bucket, rows", [(8192, 1), (1024, 8)])
+@pytest.mark.parametrize("bucket, rows", [(8192, 1), (1024, 2)])
 def test_latent_prefill_program(chip, monkeypatch, capsys, bucket, rows):
     """A prefill launch of the latent-attention cell (the dense layer
-    and one expert layer): 8,192 positions in its largest bucket and
-    in its smallest, the expanded form through the kernel of
+    and one expert layer): the one row of its largest bucket and the
+    two of its smallest (2,048 positions a launch since PR 32; eight
+    until then), the expanded form through the kernel of
     ops/mla_prefill.py a group of heads at a time. No [S,S] scores, no
     copy of a pool, and temporaries that leave the chip room for the
     other three expert layers' weights (the five-layer program:

@@ -18,6 +18,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu._private import spans, trace_plane  # noqa: E402
+from ray_tpu.models import inference  # noqa: E402
 from ray_tpu.models import train_step as ts  # noqa: E402
 from ray_tpu.models.inference import (InferenceConfig,  # noqa: E402
                                       InferenceEngine, decode_step,
@@ -90,9 +91,11 @@ def test_stats_keeps_its_six_keys(run):
 
 
 def rows_of(icfg, bucket):
-    """Rows of a prefill launch: the positions of one prompt in the
-    largest bucket, at most ``batch_size`` rows and at least one."""
-    return max(1, min(icfg.batch_size, max(icfg.prefill_buckets) // bucket))
+    """Rows of a prefill launch: a budget of positions (one prompt in
+    the largest bucket, at most ``_LAUNCH_POSITIONS``) over the bucket,
+    at most ``batch_size`` rows and at least one."""
+    budget = min(max(icfg.prefill_buckets), inference._LAUNCH_POSITIONS)
+    return max(1, min(icfg.batch_size, budget // bucket))
 
 
 def test_counters_conserve(run):
@@ -152,10 +155,10 @@ def test_ring_and_counters_agree_to_the_unit(run):
         assert f["chunks"] <= 4 and f["steps"] <= 4 * ICFG.decode_chunk
 
 
-def _openpangu(tiny: bool):
-    """(description, configuration) of the benchmark's latent-attention
-    model: as its cell runs it, or with the sizes of the benchmark's
-    tiny rehearsal laid over it."""
+def _benchmark_config(name: str, tiny: str = ""):
+    """A configuration file of the benchmark: as its cell runs it, or
+    with the sizes of the benchmark's tiny rehearsal ``tiny`` laid over
+    it. Puts the checkout on the path, for the weights modules."""
     import json
     import os
     import sys
@@ -163,16 +166,61 @@ def _openpangu(tiny: bool):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
-    from benchmark import weights_openpangu_ultra as W
 
     def load(*parts):
         with open(os.path.join(root, "benchmark", *parts)) as f:
             return json.load(f)
 
-    config = load("configs", "openpangu-ultra-moe-718b-serve-L5-ep16.json")
+    config = load("configs", name + ".json")
     if tiny:
-        config.update(load("tests", "tiny_serve_described.json")["config"])
+        config.update(load("tests", tiny + ".json")["config"])
+    return config
+
+
+def _openpangu(tiny: bool):
+    """(description, configuration) of the benchmark's latent-attention
+    model."""
+    config = _benchmark_config("openpangu-ultra-moe-718b-serve-L5-ep16",
+                               "tiny_serve_described" if tiny else "")
+    from benchmark import weights_openpangu_ultra as W
+
     return W.description(config), config
+
+
+@pytest.fixture(scope="module")
+def hybrid_model():
+    """The benchmark's delta-rule + experts model at its rehearsal's
+    sizes (one attention layer, three ``delta_rule`` layers, every
+    feed-forward 4 picks of 8 held experts), in ``tiny_model``'s form."""
+    from ray_tpu.models.decoder import DecoderConfig, LayerSpec
+
+    config = _benchmark_config("solar-open2-250b-serve-L4-ep8",
+                               "tiny_serve_decoder")
+    from benchmark import weights_solar_open2 as W
+
+    s = W.dims(config)
+    layers = tuple(LayerSpec("attention" if i in s["gqa"] else "delta_rule",
+                             "experts") for i in range(s["layers"]))
+    mcfg = DecoderConfig(layers=layers, **W.decoder_kwargs(config))
+    assert mcfg.state_layers == (1, 2, 3) and len(mcfg.moe_layers) == 4
+    params = jax.jit(lambda k: W.init_params(config, k, jnp.float32))(
+        W.seed_key(3))
+    return mcfg, None, params
+
+
+@pytest.fixture
+def served(request):
+    """``tiny_model`` or ``hybrid_model``, by the parameter's name"""
+    return request.getfixturevalue(request.param)
+
+
+@pytest.fixture
+def launch_positions(request, monkeypatch):
+    """The engine's ``_LAUNCH_POSITIONS`` at the parameter for the test;
+    None leaves the program's own."""
+    if request.param:
+        monkeypatch.setattr(inference, "_LAUNCH_POSITIONS", request.param)
+    return request.param
 
 
 def test_a_latent_models_ring_and_counters_agree():
@@ -341,17 +389,57 @@ def test_live_pages_agree_with_a_hand_count(tiny_model):
     assert stats["decode_pages_tabled"] == (both + alone) * 3 * 16
 
 
+LONGDOC = (1024, 2048, 4096, 8192, 16384)
+
+
 @pytest.mark.parametrize("batch_size, buckets, want", [
-    (3, (8, 16), {8: 2, 16: 1}),                    # ICFG's
-    (4, (8, 16, 32, 64), {8: 4, 16: 4, 32: 2, 64: 1}),   # 8 rows clamp to 4
-    (2, (16,), {16: 1}),
-    (32, (64, 128, 256, 512), {64: 8, 128: 4, 256: 2, 512: 1}),  # the cell's
+    # the two hybrid cells': a bucket of _LAUNCH_POSITIONS and more
+    # runs its one row alone, whatever the largest bucket
+    (64, LONGDOC, {1024: 2, 2048: 1, 4096: 1, 8192: 1, 16384: 1}),
+    (32, LONGDOC[:4], {1024: 2, 2048: 1, 4096: 1, 8192: 1}),
+    # the dense cells': under the constant, PR 26's rule to the letter
+    (32, (64, 128, 256, 512), {64: 8, 128: 4, 256: 2, 512: 1}),
+    # the largest bucket IS the constant
+    (32, (256, 512, 1024, 2048), {256: 8, 512: 4, 1024: 2, 2048: 1}),
+    # rows still clamp to the slots
+    (2, (512, 4096), {512: 2, 4096: 1}),
 ])
-def test_a_launch_has_the_rows_its_bucket_gives_it(tiny_model, batch_size,
-                                                   buckets, want):
-    """rows(b) = clamp(largest bucket // b, 1, batch_size): the program
-    is called with that many rows, and the launch's span and stats()
-    report that many."""
+def test_the_budget_of_a_launch_stops_at_launch_positions(
+        tiny_model, batch_size, buckets, want):
+    """budget = min(largest bucket, _LAUNCH_POSITIONS), rows(b) =
+    clamp(budget // b, 1, batch_size): read off ``_prefill_rows`` of an
+    engine with the cell's buckets and slots around the tiny model (no
+    program is built until a request comes)."""
+    assert inference._LAUNCH_POSITIONS == 2048
+    cfg, _model, params = tiny_model
+    icfg = InferenceConfig(batch_size=batch_size, page_size=128,
+                           max_pages_per_seq=-(-max(buckets) // 128) + 1,
+                           num_pages=8, prefill_buckets=buckets)
+    engine = InferenceEngine(params, cfg, icfg)
+    try:
+        assert engine._prefill_rows == want
+        assert {b: rows_of(icfg, b) for b in buckets} == want
+        assert sorted(engine._prefill_many) == sorted(buckets)
+    finally:
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("launch_positions, batch_size, buckets, want", [
+    (None, 3, (8, 16), {8: 2, 16: 1}),                    # ICFG's
+    # 8 rows clamp to 4
+    (None, 4, (8, 16, 32, 64), {8: 4, 16: 4, 32: 2, 64: 1}),
+    (None, 2, (16,), {16: 1}),
+    # the cell's
+    (None, 32, (64, 128, 256, 512), {64: 8, 128: 4, 256: 2, 512: 1}),
+    # the constant under the largest bucket: one row from there up
+    (16, 4, (8, 16, 32, 64), {8: 2, 16: 1, 32: 1, 64: 1}),
+    (32, 8, (8, 16, 32, 64), {8: 4, 16: 2, 32: 1, 64: 1}),
+], indirect=["launch_positions"])
+def test_a_launch_has_the_rows_its_bucket_gives_it(
+        tiny_model, launch_positions, batch_size, buckets, want):
+    """rows(b) = clamp(min(largest bucket, _LAUNCH_POSITIONS) // b, 1,
+    batch_size): the program is called with that many rows, and the
+    launch's span and stats() report that many."""
     cfg, _model, params = tiny_model
     largest = max(buckets)
     icfg = InferenceConfig(batch_size=batch_size, page_size=4,
@@ -395,14 +483,24 @@ def group_prompts(sizes):
     return prompts
 
 
-@pytest.mark.parametrize("sizes", [{8: 5, 16: 3, 32: 2}, {8: 9, 16: 1},
-                                   {16: 4, 32: 3, 8: 1}])
+@pytest.mark.parametrize("served, launch_positions, sizes", [
+    ("tiny_model", None, {8: 5, 16: 3, 32: 2}),
+    ("tiny_model", None, {8: 9, 16: 1}),
+    ("tiny_model", None, {16: 4, 32: 3, 8: 1}),
+    # rows 2, 1, 1: a group of a long bucket is a launch a request
+    ("tiny_model", 16, {8: 5, 16: 3, 32: 2}),
+    ("hybrid_model", 16, {8: 3, 16: 3, 32: 2}),
+    ("hybrid_model", None, {8: 5, 16: 3, 32: 1}),
+], indirect=["served", "launch_positions"])
 def test_a_group_larger_than_a_launch_is_split_and_answers_as_alone(
-        tiny_model, sizes):
+        served, launch_positions, sizes):
     """One admission round with more requests of a bucket than a launch
     has rows: ceil(group / rows) launches, the last one padded, and every
-    request answers with the tokens it gets when it is served alone."""
-    cfg, _model, params = tiny_model
+    request answers with the tokens it gets when it is served alone;
+    for an attention-only model and for one with ``delta_rule`` layers
+    and experts, with the launch's budget at the largest bucket and
+    under it."""
+    cfg, _model, params = served
     prompts = group_prompts(sizes)
     max_new = [3 + i % 5 for i in range(len(prompts))]
     engine = InferenceEngine(params, cfg, GROUPS)
@@ -435,14 +533,16 @@ def test_a_group_larger_than_a_launch_is_split_and_answers_as_alone(
     assert sum(f["prompt_tokens"] for f in launches) == ring["prompt_tokens"]
 
 
-def test_each_prefill_program_compiles_once_whatever_the_group(tiny_model):
+@pytest.mark.parametrize("launch_positions", [None, 16], indirect=True)
+def test_each_prefill_program_compiles_once_whatever_the_group(
+        tiny_model, launch_positions):
     """Groups of every size from 1 to batch_size in every bucket: a
     bucket's program is always called with one shape, so jit has
     specialised it once."""
     cfg, _model, params = tiny_model
     icfg = InferenceConfig(batch_size=4, page_size=4, max_pages_per_seq=10,
                            num_pages=4 * 10 + 1, prefill_buckets=(8, 16, 32),
-                           decode_chunk=2)      # rows: 4, 2, 1
+                           decode_chunk=2)      # rows: 4, 2, 1 (or 2, 1, 1)
     engine = InferenceEngine(params, cfg, icfg)
     try:
         programs = dict(engine._prefill_many)
