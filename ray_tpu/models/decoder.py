@@ -4,9 +4,9 @@
 every layer is the same. The models people serve mix kinds: a softmax
 attention layer, then three with a recurrent state, experts in every
 feed-forward, an untied head. ``DecoderConfig`` names, for each layer,
-its mixer (``attention``, ``delta_rule`` or ``latent``) and its
-feed-forward (``dense`` or ``experts``), and beside them what the kinds
-need; a model may mix feed-forward kinds as well as mixers. The
+its mixer (``attention``, ``window``, ``delta_rule`` or ``latent``) and
+its feed-forward (``dense`` or ``experts``), and beside them what the
+kinds need; a model may mix feed-forward kinds as well as mixers. The
 dense decoder is the one-kind case: ``describe`` lowers a
 ``TransformerConfig`` to it. ``models/decoder_forward.py`` runs a
 description (the functional forward, and the cache of what each kind of
@@ -21,7 +21,14 @@ root, beside ``embedding``, ``final_norm/scale`` and, untied,
   the feed-forward) and, with ``sandwich_norm``, ``PostNorm_0/scale``,
   ``PostNorm_1/scale`` (on each branch before its residual add);
 - mixer ``attention``: ``Attention_0/{wq [d,H,hd], wk, wv [d,KV,hd], wo
-  [H,hd,d]}`` and, gated, ``w_gate [d,H,hd]``;
+  [H,hd,d]}`` and, gated, ``w_gate [d,H,hd]``, and, with ``qk_norm``,
+  ``q_norm``, ``k_norm`` [hd] (an RMSNorm over each head's width, one
+  scale for all query heads and one for all key heads, before any
+  rotation);
+- mixer ``window`` (softmax attention over the last ``window``
+  positions, itself among them): the same leaves under the same name,
+  ``Attention_0``. It keeps at most a ring of ``window / page_size +
+  1`` pages a sequence (``models/decoder_forward.py``);
 - mixer ``delta_rule`` (ops/kda.py): ``DeltaRule_0/{wq, wk [d,H,dk], wv
   [d,H,dv], conv_q, conv_k [K,H,dk], conv_v [K,H,dv], w_f_down [d,r],
   w_f_up [r,H,dk], dt_bias [H,dk], A_log [H], w_beta [d,H], w_g_down
@@ -34,8 +41,10 @@ root, beside ``embedding``, ``final_norm/scale`` and, untied,
   p-wide key all heads share]``, its values the other ``v_dim``;
 - feed-forward ``dense``: ``MLP_0/{w_gate, w_up [d,f], w_down [f,d]}``;
 - feed-forward ``experts`` (ops/moe.py): ``MoE_0/{router [d,E], w_gate,
-  w_up [E_held,d,fe], w_down [E_held,fe,d]}`` and, with a shared expert,
-  ``MoE_0/shared/{w_gate, w_up [d,fs], w_down [fs,d]}``.
+  w_up [E_held,d,fe], w_down [E_held,fe,d]}``, with a shared expert
+  ``MoE_0/shared/{w_gate, w_up [d,fs], w_down [fs,d]}`` and, with
+  ``router_bias``, ``MoE_0/bias [E]`` (added to the scores to SELECT
+  the picked, never to weigh them).
 """
 
 from __future__ import annotations
@@ -45,7 +54,7 @@ from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 
-MIXERS = ("attention", "delta_rule", "latent")
+MIXERS = ("attention", "window", "delta_rule", "latent")
 FFNS = ("dense", "experts")
 
 
@@ -68,8 +77,16 @@ class DecoderConfig:
     n_kv_heads: int
     head_dim: int
     d_ff: int = 0                       # dense feed-forward width
-    rope_theta: Optional[float] = None  # None: no rotation at all
+    # what a layer rotates with (None: no rotation at all): window and
+    # latent layers always, attention layers unless rope_attention says
+    # otherwise (a model whose full layers carry no positions beside
+    # window layers that do)
+    rope_theta: Optional[float] = None
+    rope_attention: bool = True
     attn_gate: bool = False             # out = wo(attn * sigmoid(w_gate h))
+    qk_norm: bool = False               # RMSNorm a head on q and on k
+    window: int = 0                     # positions a window layer sees
+    embed_scale: float = 1.0            # factor on the embedding's rows
     tie_embeddings: bool = True
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
@@ -102,8 +119,12 @@ class DecoderConfig:
     d_expert: int = 0
     d_shared: int = 0
     routed_scale: float = 1.0
+    router_bias: bool = False           # picks by score + MoE_0/bias
 
     def __post_init__(self):
+        if self.window_layers and self.window <= 0:
+            raise ValueError("window layers need the window's length "
+                             "(DecoderConfig.window)")
         lo, hi = self.experts_held
         if self.moe_layers and not (
                 0 <= lo < hi <= self.n_routed_experts
@@ -122,6 +143,18 @@ class DecoderConfig:
         """Layers that keep keys and values in the paged pool."""
         return tuple(i for i, l in enumerate(self.layers)
                      if l.mixer == "attention")
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        """Layers that keep keys and values of the last ``window``
+        positions, in a ring of pages a slot."""
+        return tuple(i for i, l in enumerate(self.layers)
+                     if l.mixer == "window")
+
+    def rotates(self, mixer: str) -> bool:
+        """Whether layers of kind ``mixer`` rotate queries and keys."""
+        return self.rope_theta is not None and (
+            mixer != "attention" or self.rope_attention)
 
     @property
     def latent_layers(self) -> Tuple[int, ...]:

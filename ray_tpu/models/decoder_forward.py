@@ -8,11 +8,17 @@ module's output). Every choice between kinds of layer is made here,
 while tracing.
 
 It owns the cache, one entry a layer: ``(k_pages, v_pages)`` for an
-attention layer (its own pool, [P,KV,page,D]), ``(state, tail)`` a slot
-for a delta-rule layer, ``(latent_pages,)`` for a latent layer (one
-pool [P,1,page,W] whose row a token is the normed latent and the
-rotated shared key). What a kind of mixer keeps and does is one entry
-of ``MIXERS_BY_KIND`` (``init``, ``write``, ``prefill``, ``decode``);
+attention layer (its own pool, [P,KV,page,D]), the same pair for a
+window layer but in a pool of ``batch_size`` rings (``window_ring``
+pages a slot, which are the slot's own: a token at position ``t`` lies
+in ring page ``(t // page_size) % ring``, so the layer's cache stops
+growing at the window), ``(state, tail)`` a slot for a delta-rule
+layer, ``(latent_pages,)`` for a latent layer (one pool [P,1,page,W]
+whose row a token is the normed latent and the rotated shared key).
+What a kind of mixer keeps and does is one entry
+of ``MIXERS_BY_KIND`` (``init``, ``write``, ``prefill``, ``decode``,
+and whether what it keeps is keys and values a position that a caller
+may hold itself);
 the layer around it (norms, residual, feed-forward) is the same for
 all. A pytree, never stacked: each layer's append
 kernel takes its own pool as input and output of one buffer under
@@ -24,6 +30,7 @@ engine's.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, NamedTuple
 
 import jax
@@ -32,7 +39,8 @@ import jax.numpy as jnp
 from ray_tpu.models.decoder import DecoderConfig, LayerSpec, describe
 from ray_tpu.ops import kda
 from ray_tpu.ops.flash import flash_attention_bshk, flash_supported
-from ray_tpu.ops.mla_prefill import mla_prefill_attention
+from ray_tpu.ops.mla_prefill import (mla_prefill_attention,
+                                     window_prefill_attention)
 from ray_tpu.ops.moe import experts_held, route_topk
 from ray_tpu.ops.paged_attention import (append_token, append_token_kv,
                                          paged_attention_auto,
@@ -71,7 +79,8 @@ def _experts(m, cfg: DecoderConfig, x, valid):
     dt = cfg.dtype
     with jax.named_scope("moe_route"):
         ids, weights = route_topk(t, m["router"], cfg.experts_per_token,
-                                  cfg.routed_scale)
+                                  cfg.routed_scale,
+                                  m["bias"] if cfg.router_bias else None)
     with jax.named_scope("moe_experts"):
         block = _EXPERT_BLOCK_NUMBERS // (cfg.experts_per_token
                                           * cfg.d_model)
@@ -100,9 +109,20 @@ def _feed_forward(p, cfg: DecoderConfig, spec: LayerSpec, x, valid):
     return x + y, counts
 
 
-def _blockwise_scores_attention(q, kr, vr, scale, block=_SCORES_MAX_SEQ):
+def _seen(rows, cols, window: int = 0):
+    """Which of the positions ``cols`` [T] each of ``rows`` [S] sees:
+    those up to its own and, of a ``window``, the last that many."""
+    seen = cols[None, :] <= rows[:, None]
+    if window:
+        seen &= cols[None, :] > rows[:, None] - window
+    return seen
+
+
+def _blockwise_scores_attention(q, kr, vr, scale, block=_SCORES_MAX_SEQ,
+                                window: int = 0):
     """Causal attention over [N,S,H,D] (heads repeated; values of any
-    width) with the scores of one block of query rows at a time."""
+    width) with the scores of one block of query rows at a time; of a
+    ``window``, over a row's last that many positions."""
     n, s, h, _ = q.shape
     pad = (-s) % block
     qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -111,8 +131,7 @@ def _blockwise_scores_attention(q, kr, vr, scale, block=_SCORES_MAX_SEQ):
         qb = jax.lax.dynamic_slice_in_dim(qp, i * block, block, axis=1)
         scores = (jnp.einsum("bshk,bthk->bhst", qb, kr)
                   * scale).astype(jnp.float32)
-        seen = (jnp.arange(s)[None, :]
-                <= i * block + jnp.arange(block)[:, None])
+        seen = _seen(i * block + jnp.arange(block), jnp.arange(s), window)
         probs = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30),
                                axis=-1).astype(q.dtype)
         return jnp.einsum("bhst,bthk->bshk", probs, vr)
@@ -130,32 +149,77 @@ def _blockwise_attention(q, kr, vr):
     return _blockwise_scores_attention(q, kr, vr, q.shape[-1] ** -0.5)
 
 
-def _prefill_attention(a, cfg: DecoderConfig, h, positions):
-    """Softmax attention over a bucket: h [N,S,Dm] (normed) -> (out
-    [N,S,Dm] before the residual, k, v [N,S,KV,D])."""
-    q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(cfg.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, a["wk"].astype(cfg.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, a["wv"].astype(cfg.dtype))
-    if cfg.rope_theta is not None:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-    rep = cfg.n_heads // cfg.n_kv_heads
-    kr = jnp.repeat(k, rep, axis=2)
-    vr = jnp.repeat(v, rep, axis=2)
-    s = h.shape[1]
-    if s > _SCORES_MAX_SEQ:
-        attn = _blockwise_attention(q, kr, vr)
-    else:
-        mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
-        scores = (jnp.einsum("bshk,bthk->bhst", q, kr)
-                  / jnp.sqrt(cfg.head_dim))
-        scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-        attn = jnp.einsum("bhst,bthk->bshk", probs, vr)
+def _attention_qkv(a, cfg: DecoderConfig, mixer: str, h, positions):
+    """What softmax attention of kind ``mixer`` takes from the normed
+    input h [...,d] at ``positions`` [...]: (q [...,H,D], k, v
+    [...,KV,D]), q and k normed a head and rotated where the model
+    says so."""
+    q, k, v = (jnp.einsum("...d,dhk->...hk", h, a[w].astype(cfg.dtype))
+               for w in ("wq", "wk", "wv"))
+    if cfg.qk_norm:
+        q = _rms(q, a["q_norm"], cfg.norm_eps)
+        k = _rms(k, a["k_norm"], cfg.norm_eps)
+    if cfg.rotates(mixer):
+        if h.ndim == 2:
+            # rope over a length-1 "sequence" per slot
+            q = rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+            k = rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+        else:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def _attention_out(a, cfg: DecoderConfig, h, attn):
+    """attn [...,H,D] -> the mixer's output [...,d]: the gate a element
+    of a gated model, the output projection."""
     if cfg.attn_gate:
         attn = attn * jax.nn.sigmoid(jnp.einsum(
-            "bsd,dhk->bshk", h, a["w_gate"].astype(cfg.dtype)))
-    return jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(cfg.dtype)), k, v
+            "...d,dhk->...hk", h, a["w_gate"].astype(cfg.dtype)))
+    return jnp.einsum("...hk,hkd->...d", attn.astype(cfg.dtype),
+                      a["wo"].astype(cfg.dtype))
+
+
+def _window_attention(q, k, v, window: int):
+    """Attention over a long row's band: q [N,S,H,D], k, v [N,S,KV,D].
+    The kernel of ops/mla_prefill.py where the flash kernel would run
+    (each key head read where it lies by its G query heads), else the
+    scores of a block of query rows at a time."""
+    if flash_supported(q.shape[-1]):
+        out = window_prefill_attention(
+            *(jnp.moveaxis(t, 1, 2) for t in (q, k, v)), window=window)
+        return jnp.moveaxis(out, 2, 1)
+    rep = q.shape[2] // k.shape[2]
+    return _blockwise_scores_attention(
+        q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
+        q.shape[-1] ** -0.5, window=window)
+
+
+def _prefill_attention(a, cfg: DecoderConfig, h, positions,
+                       mixer: str = "attention"):
+    """Softmax attention over a bucket, causal and of a ``window``
+    layer banded: h [N,S,Dm] (normed) -> (out [N,S,Dm] before the
+    residual, k, v [N,S,KV,D])."""
+    q, k, v = _attention_qkv(a, cfg, mixer, h, positions)
+    window = cfg.window if mixer == "window" else 0
+    s = h.shape[1]
+    if s > _SCORES_MAX_SEQ and window:
+        attn = _window_attention(q, k, v, window)
+    else:
+        rep = cfg.n_heads // cfg.n_kv_heads
+        kr = jnp.repeat(k, rep, axis=2)
+        vr = jnp.repeat(v, rep, axis=2)
+        if s > _SCORES_MAX_SEQ:
+            attn = _blockwise_attention(q, kr, vr)
+        else:
+            at = jnp.arange(s)
+            scores = (jnp.einsum("bshk,bthk->bhst", q, kr)
+                      / jnp.sqrt(cfg.head_dim))
+            scores = jnp.where(_seen(at, at, window)[None, None],
+                               scores.astype(jnp.float32), -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+            attn = jnp.einsum("bhst,bthk->bshk", probs, vr)
+    return _attention_out(a, cfg, h, attn), k, v
 
 
 def _delta_rule_inputs(a, cfg: DecoderConfig, h, mixed):
@@ -416,7 +480,7 @@ def _init_latent(cfg: DecoderConfig, icfg):
                        cfg.latent_width), cfg.dtype),)
 
 
-def _write_latent(entry, kept, slots, pages):
+def _write_latent(cfg, entry, kept, slots, pages, plens):
     (pool,), (rows,) = entry, kept
     with jax.named_scope("latent_append"):
         for r in range(pages.shape[0]):
@@ -425,42 +489,65 @@ def _write_latent(entry, kept, slots, pages):
 
 
 # ----------------------------------------------------------------------
-# the other two kinds, in the table's form
+# the other kinds, in the table's form: softmax attention over keys and
+# values a position, whole (under the engine's page table) or of a
+# window (in a ring a slot); the delta rule
 # ----------------------------------------------------------------------
 
-def _prefill_attention_kind(p, cfg: DecoderConfig, h, positions, plens):
-    with jax.named_scope("gqa" if cfg.attn_gate else "attn"):
-        out, k, v = _prefill_attention(p["Attention_0"], cfg, h, positions)
+def window_ring(cfg: DecoderConfig, page_size: int) -> int:
+    """Pages a sequence's ring has in a window layer's pool: as many as
+    ``cfg.window`` consecutive positions can straddle."""
+    return -(-(cfg.window - 1) // page_size) + 1
+
+
+def _scope(cfg: DecoderConfig, mixer: str) -> str:
+    """The named scope of a softmax mixer's products and read."""
+    return "win" if mixer == "window" else (
+        "gqa" if cfg.attn_gate else "attn")
+
+
+def _prefill_attention_kind(p, cfg: DecoderConfig, h, positions, plens,
+                            mixer: str = "attention"):
+    with jax.named_scope(_scope(cfg, mixer)):
+        out, k, v = _prefill_attention(p["Attention_0"], cfg, h, positions,
+                                       mixer)
     return out, (k, v)
 
 
 def _decode_attention(p, cfg: DecoderConfig, h, kept, page_table, seq_lens,
-                      live):
+                      live, mixer: str = "attention"):
     """``kept`` is the layer's (k_pages, v_pages), to which this token's
     K/V are appended (seq_lens = cache length BEFORE the token = the
-    token's position)."""
+    token's position). A ``window`` layer appends into its slot's own
+    ring of pages, wrapping, and reads the last ``cfg.window`` positions
+    (the token among them) from it: the engine's page table is not
+    its."""
     a = p["Attention_0"]
     k_pages, v_pages = kept
-    scope = "gqa" if cfg.attn_gate else "attn"
+    scope = _scope(cfg, mixer)
+    ring, window = 0, {}
+    if mixer == "window":
+        ring = window_ring(cfg, k_pages.shape[2])
+        slots = h.shape[0]
+        page_table = jnp.arange(slots * ring, dtype=jnp.int32).reshape(
+            slots, ring)
+        if live is not None:
+            # an idle slot's dummy token goes to the pool's last page,
+            # as a full layer's goes to the engine's parking page: the
+            # append moves ONE page for all of them, not a page each
+            # (52 us a call against the full layer's 18 with 20 of 32
+            # slots idle: PERF.md section 6, PR 33)
+            page_table = jnp.where(live[:, None], page_table, slots * ring)
+        window = {"first": seq_lens + 1 - cfg.window, "ring": ring}
     with jax.named_scope(scope):
-        q = jnp.einsum("bd,dhk->bhk", h, a["wq"].astype(cfg.dtype))
-        k = jnp.einsum("bd,dhk->bhk", h, a["wk"].astype(cfg.dtype))
-        v = jnp.einsum("bd,dhk->bhk", h, a["wv"].astype(cfg.dtype))
-        if cfg.rope_theta is not None:
-            # rope over a length-1 "sequence" per slot
-            q = rope(q[:, None], seq_lens[:, None], cfg.rope_theta)[:, 0]
-            k = rope(k[:, None], seq_lens[:, None], cfg.rope_theta)[:, 0]
-    with jax.named_scope("kv_append"):
+        q, k, v = _attention_qkv(a, cfg, mixer, h, seq_lens)
+    with jax.named_scope("win_append" if ring else "kv_append"):
         k_pages, v_pages = append_token_kv(k_pages, v_pages, k, v,
-                                           page_table, seq_lens)
+                                           page_table, seq_lens, ring)
     with jax.named_scope(scope):
         out = paged_attention_auto(q, k_pages, v_pages, page_table,
-                                   seq_lens + 1)
-        if cfg.attn_gate:
-            out = out * jax.nn.sigmoid(jnp.einsum(
-                "bd,dhk->bhk", h, a["w_gate"].astype(cfg.dtype)))
-        out = jnp.einsum("bhk,hkd->bd", out.astype(cfg.dtype),
-                         a["wo"].astype(cfg.dtype))
+                                   seq_lens + 1, **window)
+        out = _attention_out(a, cfg, h, out)
     return out, (k_pages, v_pages)
 
 
@@ -469,12 +556,52 @@ def _init_attention(cfg: DecoderConfig, icfg):
     return jnp.zeros(pool, cfg.dtype), jnp.zeros(pool, cfg.dtype)
 
 
-def _write_attention(entry, kept, slots, pages):
+def _write_attention(cfg, entry, kept, slots, pages, plens):
     k_pages, v_pages = entry
     with jax.named_scope("kv_append"):
         for r in range(pages.shape[0]):
             k_pages, v_pages = write_prefill_kv(
                 k_pages, v_pages, kept[0][r], kept[1][r], pages[r])
+    return k_pages, v_pages
+
+
+def _init_window(cfg: DecoderConfig, icfg):
+    """A ring a slot (slot ``s`` owns pages ``s * ring ... s * ring +
+    ring - 1``) and, last, a page for what a launch writes and nothing
+    reads."""
+    pool = (icfg.batch_size * window_ring(cfg, icfg.page_size) + 1,
+            cfg.n_kv_heads, icfg.page_size, cfg.head_dim)
+    return jnp.zeros(pool, cfg.dtype), jnp.zeros(pool, cfg.dtype)
+
+
+def window_prefill_pages(cfg: DecoderConfig, pool_pages: int, page: int,
+                         n_pages: int, slots, plens):
+    """Where a launch's rows write each of their bucket's ``n_pages``
+    logical pages in a window layer's pool of ``pool_pages``: [N,
+    n_pages] physical ids. A page that holds a position decoding can
+    still see (``>= plen - window``, up to the prompt's last) goes to
+    its place in the slot's ring; the pages before the window, the
+    padding pages past the prompt (a bucket has more pages than the
+    ring: written by ``page % ring`` they would land on live tokens)
+    and a dummy row's all go to the pool's last page."""
+    ring = window_ring(cfg, page)
+    logical = jnp.arange(n_pages)[None, :]
+    keep = ((logical >= (jnp.maximum(plens - cfg.window, 0) // page)[:, None])
+            & (logical <= ((plens - 1) // page)[:, None])
+            & (slots < (pool_pages - 1) // ring)[:, None])
+    return jnp.where(keep, slots[:, None] * ring + logical % ring,
+                     pool_pages - 1)
+
+
+def _write_window(cfg, entry, kept, slots, pages, plens):
+    k_pages, v_pages = entry
+    n_pages, page = pages.shape[1], k_pages.shape[2]
+    where = window_prefill_pages(cfg, k_pages.shape[0], page, n_pages,
+                                 slots, plens)
+    with jax.named_scope("win_append"):
+        for r in range(where.shape[0]):
+            k_pages, v_pages = write_prefill_kv(
+                k_pages, v_pages, kept[0][r], kept[1][r], where[r])
     return k_pages, v_pages
 
 
@@ -490,7 +617,7 @@ def _init_delta_rule(cfg: DecoderConfig, icfg):
                        cfg.dr_heads * cfg.dr_channels), cfg.dtype))
 
 
-def _write_delta_rule(entry, kept, slots, pages):
+def _write_delta_rule(cfg, entry, kept, slots, pages, plens):
     with jax.named_scope("kda_state"):
         return tuple(held.at[slots].set(new)
                      for held, new in zip(entry, kept))
@@ -498,28 +625,51 @@ def _write_delta_rule(entry, kept, slots, pages):
 
 class _Mixer(NamedTuple):
     """What a kind of mixer brings. ``init(cfg, icfg)`` -> the layer's
-    empty cache entry; ``write(entry, kept, slots, pages)`` -> the entry
-    with what a launch's rows kept: row r's keys and values [S,KV,D]
-    or latent rows [S,W] go to the pages ``pages[r]``, its final state
+    empty cache entry; ``write(cfg, entry, kept, slots, pages, plens)``
+    -> the entry with what a launch's rows kept: row r's keys and values
+    [S,KV,D] or latent rows [S,W] go to the pages ``pages[r]`` (of a
+    window layer: those of its last ``window`` positions before
+    ``plens[r]``, to the ring of slot ``slots[r]``), its final state
     and tail to slot ``slots[r]`` whole (nothing of the slot's previous
     tenant survives; a dummy row's slot is out of bounds and its
     scatter is dropped); ``prefill(p, cfg, h, positions, plens)`` -> (out
     [N,S,Dm] before the residual, kept); ``decode(p, cfg, h, entry,
-    page_table, seq_lens, live)`` -> (out [B,Dm], entry)."""
+    page_table, seq_lens, live)`` -> (out [B,Dm], entry).
+    ``keeps_beside_kv`` names what the layer keeps where that is NOT
+    keys and values a position under the caller's page table, which a
+    caller may hold itself (``decode_step``) and the disaggregated
+    handoff carries (``import_kv``)."""
     init: Callable
     write: Callable
     prefill: Callable
     decode: Callable
+    keeps_beside_kv: str = ""
 
 
 MIXERS_BY_KIND = {
     "attention": _Mixer(_init_attention, _write_attention,
                         _prefill_attention_kind, _decode_attention),
+    "window": _Mixer(
+        _init_window, _write_window,
+        functools.partial(_prefill_attention_kind, mixer="window"),
+        functools.partial(_decode_attention, mixer="window"),
+        "a ring of the last positions' keys and values a slot"),
     "delta_rule": _Mixer(_init_delta_rule, _write_delta_rule,
-                         _prefill_delta_rule_kind, _decode_delta_rule),
+                         _prefill_delta_rule_kind, _decode_delta_rule,
+                         "recurrent state"),
     "latent": _Mixer(_init_latent, _write_latent, _prefill_latent,
-                     _decode_latent),
+                     _decode_latent, "latent rows"),
 }
+
+
+def kept_beside_kv(cfg) -> str:
+    """What the layers of ``cfg`` keep that is not keys and values
+    under the caller's page table ("" when nothing): the disaggregated
+    handoff and the callers that hold K and V themselves carry none of
+    it."""
+    return ", ".join(sorted(
+        {MIXERS_BY_KIND[l.mixer].keeps_beside_kv
+         for l in describe(cfg).layers} - {""}))
 
 
 def _mix(p, cfg: DecoderConfig, x, run):
@@ -564,6 +714,12 @@ def _head(params, cfg: DecoderConfig, x, spec: str):
         return logits.astype(jnp.float32)
 
 
+def _embed(params, cfg: DecoderConfig, tokens):
+    with jax.named_scope("embed"):
+        x = params["embedding"].astype(cfg.dtype)[tokens]
+        return x if cfg.embed_scale == 1.0 else x * cfg.embed_scale
+
+
 def _sum_counts(counts):
     counts = [c for c in counts if c is not None]
     return sum(counts[1:], counts[0]) if counts else None
@@ -578,8 +734,7 @@ def _prefill_hidden(params, cfg: DecoderConfig, tokens, plens=None,
     marks the rows that are requests: what lies past a length or in a
     dummy row neither touches a state nor counts as a pick."""
     n, s = tokens.shape
-    with jax.named_scope("embed"):
-        x = params["embedding"].astype(cfg.dtype)[tokens]
+    x = _embed(params, cfg, tokens)
     positions = jnp.arange(s)[None, :]
     if plens is None:
         plens = jnp.full((n,), s, jnp.int32)
@@ -623,7 +778,8 @@ def prefill_cached(params, cfg: DecoderConfig, cache, tokens, plens, slots,
     summed over the layers or None)."""
     x, kept, counts = _prefill_hidden(params, cfg, tokens, plens, requests)
     cache = tuple(
-        MIXERS_BY_KIND[spec.mixer].write(entry, keep, slots, pages)
+        MIXERS_BY_KIND[spec.mixer].write(cfg, entry, keep, slots, pages,
+                                         plens)
         for spec, entry, keep in zip(cfg.layers, cache, kept))
     last = x[jnp.arange(tokens.shape[0]), plens - 1]
     return _head(params, cfg, last, "bd,vd->bv"), cache, counts
@@ -647,8 +803,7 @@ def decode_step_cached(params, cfg: DecoderConfig, tokens, cache, page_table,
     before the token, ``live`` [B] bool the slots that hold a request
     (None: every slot). Returns (next_logits [B,V] f32, cache, picks a
     held expert or None)."""
-    with jax.named_scope("embed"):
-        x = params["embedding"].astype(cfg.dtype)[tokens]      # [B, Dm]
+    x = _embed(params, cfg, tokens)                            # [B, Dm]
     new_cache, counts = [], []
     for i, (spec, kept) in enumerate(zip(cfg.layers, cache)):
         x, kept, c = _decode_layer(params[f"layer_{i}"], cfg, spec, x, kept,
@@ -688,16 +843,17 @@ def decode_chunk_cached(params, cfg: DecoderConfig, tokens, cache, page_table,
 # ----------------------------------------------------------------------
 # the same forward for callers that hold the keys and values themselves
 # (chip_smoke.py, the benchmark's compile checks): models whose every
-# mixer is attention, since one with recurrent state or a latent cache
-# has other things to hand on than keys and values, and goes through
-# the engine
+# mixer is attention, since one with recurrent state, a latent cache or
+# a window's ring has other things to hand on than keys and values
+# under one page table, and goes through the engine
 # ----------------------------------------------------------------------
 
 def _attention_only(cfg, who: str) -> DecoderConfig:
     cfg = describe(cfg)
-    if cfg.state_layers or cfg.latent_layers:
-        raise ValueError(f"{who} carries keys and values only; this model "
-                         f"keeps recurrent state or latent rows too")
+    beside = kept_beside_kv(cfg)
+    if beside:
+        raise ValueError(f"{who} carries keys and values under one page "
+                         f"table only; this model keeps {beside} too")
     return cfg
 
 

@@ -15,7 +15,10 @@ Three modules, arrows one way: serve/llm.py (deployments) -> this file
 decode bursts, the one fetch a burst, spans and counters) ->
 models/decoder_forward.py (what the model described in
 models/decoder.py computes, and the cache of what its layers keep
-between steps) -> ops/. The engine builds one family of programs for
+between steps) -> ops/. Pages, the page table and admission are the
+full-context layers'; a window layer keeps a ring of pages that belongs
+to the slot (decoder_forward), which the engine only counts
+(``_ring``). The engine builds one family of programs for
 every description and holds the cache as one donated value it never
 looks into; what a description has no use for (the live-slot mask of a
 dense decoder, the picks of a model without experts) falls away while
@@ -186,14 +189,14 @@ class InferenceEngine:
         # a TransformerConfig or a DecoderConfig; the engine reads the
         # layer-by-layer description either way
         self.mcfg = mcfg = describe(model_cfg)
-        if (mcfg.state_layers or mcfg.latent_layers) and mode != "both":
+        # what the layers keep that the KV handoff does not carry
+        self._beside_kv = forward.kept_beside_kv(mcfg)
+        if self._beside_kv and mode != "both":
             raise ValueError(
-                f"engine mode {mode!r} hands keys and values from a "
-                f"prefill replica to a decode replica; this model keeps "
-                f"recurrent state in layers {mcfg.state_layers} and "
-                f"latent rows in layers {mcfg.latent_layers}, "
-                f"which the handoff does not carry: serve it in mode "
-                f"'both'")
+                f"engine mode {mode!r} hands whole sequences' keys and "
+                f"values from a prefill replica to a decode replica; this "
+                f"model keeps {self._beside_kv}, which the handoff does "
+                f"not carry: serve it in mode 'both'")
         self.cfg = cfg
         self.mode = mode
         self._idents = itertools.count()
@@ -208,6 +211,13 @@ class InferenceEngine:
         self._decode_steps = 0
         self._decode_pages_live = 0
         self._decode_tokens_kept = 0
+        # the live slots' context when a burst began, whole and clipped
+        # to the window a slot, times the burst's steps; a window
+        # layer's ring of pages a slot (0: the model has no such layer)
+        self._decode_ctx_tokens = 0
+        self._decode_window_tokens = 0
+        self._ring = (forward.window_ring(mcfg, cfg.page_size)
+                      if mcfg.window_layers else 0)
         self._bursts = 0
         self._prefill_counts: Dict[int, List[int]] = {}
         # picks of routed experts by the tokens that counted (prompt
@@ -359,11 +369,11 @@ class InferenceEngine:
             raise RuntimeError(
                 f"engine is in {self.mode!r} mode; this entry point "
                 f"needs {wants!r}")
-        if self.mcfg.state_layers or self.mcfg.latent_layers:
+        if self._beside_kv:
             raise RuntimeError(
-                "the KV handoff carries keys and values only; this model "
-                "keeps recurrent state or latent rows too and is served "
-                "whole (submit, submit_stream)")
+                f"the KV handoff carries whole sequences' keys and values "
+                f"only; this model keeps {self._beside_kv} too and is "
+                f"served whole (submit, submit_stream)")
 
     def submit(self, prompt: Sequence[int],
                max_new_tokens: Optional[int] = None) -> Future:
@@ -483,6 +493,14 @@ class InferenceEngine:
         live slot; ``experts_per_token`` a token and expert layer),
         ``moe_picks_local`` those that fell on an expert held here and
         were computed, ``moe_load_by_expert`` the same by held expert.
+        ``decode_ctx_tokens_live`` sums, over the steps, the live slots'
+        context when their burst began, and ``decode_window_tokens_live``
+        the same with each slot's context clipped to the window (what a
+        window layer's read has to fetch; 0 for a model without such a
+        layer); ``window_pool_tokens`` is what ONE window layer's pool
+        holds for all slots (``batch_size`` rings of ``window /
+        page_size + 1`` pages) and ``window_pages_live`` the pages of
+        it that hold the live slots' tokens now.
         ``state_bytes`` is what the recurrent layers keep for all slots
         and ``pool_tokens`` the tokens the page pool can hold;
         ``latent_bytes_per_token`` is what the latent layers together
@@ -514,6 +532,12 @@ class InferenceEngine:
                 "decode_pages_tabled": (self._decode_steps
                                         * self.cfg.batch_size
                                         * self.cfg.max_pages_per_seq),
+                "decode_ctx_tokens_live": self._decode_ctx_tokens,
+                "decode_window_tokens_live": self._decode_window_tokens,
+                "window_pool_tokens": (self.cfg.batch_size * self._ring
+                                       * self.cfg.page_size),
+                "window_pages_live": self._window_pages(
+                    s.seq_len for s in self._slots if s.req is not None),
                 **prefill,
                 "prefill_by_bucket": by_bucket,
                 "moe_picks_total": self._moe_picks_total,
@@ -569,6 +593,11 @@ class InferenceEngine:
     # -- internals ------------------------------------------------------
     def _pages_needed(self, n_tokens: int) -> int:
         return -(-n_tokens // self.cfg.page_size)
+
+    def _window_pages(self, contexts) -> int:
+        """Pages of ONE window layer's rings that hold tokens of
+        sequences as long as ``contexts``."""
+        return sum(min(self._pages_needed(c), self._ring) for c in contexts)
 
     def _try_admit(self) -> None:
         """Admit every admissible queued request, then prefill them in
@@ -648,7 +677,8 @@ class InferenceEngine:
             page_list = (pages + [self._parking_page] * n_prog)[:n_prog]
             packed[r, 2 + bucket:] = page_list
             rows.append((slot, r))
-        prompt_tokens = sum(len(req.prompt) for _, req, _ in group)
+        prompt_lens = [len(req.prompt) for _, req, _ in group]
+        prompt_tokens = sum(prompt_lens)
         with self._lock:
             counts = self._prefill_counts.setdefault(bucket, [0, 0, 0, 0])
             counts[0] += 1
@@ -658,8 +688,14 @@ class InferenceEngine:
         with spans.span("engine.prefill_launch", bucket=bucket, rows=n,
                         useful_rows=len(group),
                         prompt_tokens=prompt_tokens) as launch:
-            launch.fields["prompt_lens"] = [len(req.prompt)
-                                            for _, req, _ in group]
+            launch.fields["prompt_lens"] = prompt_lens
+            if self._ring:
+                # positions a window layer's ring got: the pages that
+                # hold a prompt's last ``window`` positions, whole
+                page, window = self.cfg.page_size, self.mcfg.window
+                launch.fields["window_tokens_kept"] = sum(
+                    n - max(n - window, 0) // page * page
+                    for n in prompt_lens)
             nxt, self._dev_toks, self._cache = self._prefill_many[bucket](
                 self.params, jnp.asarray(packed), self._cache,
                 self._dev_toks)
@@ -763,11 +799,18 @@ class InferenceEngine:
             return
         self.max_concurrent = max(self.max_concurrent, len(active))
         live_pages = sum(len(s.pages) for s in active)
+        live_ctx = [s.seq_len for s in active]
+        live_window = (sum(min(c, self.mcfg.window) for c in live_ctx)
+                       if self._ring else 0)
         with spans.span("engine.dispatch", live_slots=len(active),
-                        live_ctx_tokens=sum(s.seq_len for s in active),
+                        live_ctx_tokens=sum(live_ctx),
                         live_pages=live_pages) as burst:
             if self.mcfg.state_layers:
                 burst.fields["state_slots_live"] = len(active)
+            if self._ring:
+                burst.fields.update(
+                    live_window_tokens=live_window,
+                    window_pages_live=self._window_pages(live_ctx))
             pending = self._dispatch_burst(active)
             steps = sum(chunk for _, chunk, _ in pending)
             burst.fields.update(steps=steps, chunks=len(pending))
@@ -796,6 +839,8 @@ class InferenceEngine:
             self._bursts += 1
             self._decode_steps += steps
             self._decode_pages_live += live_pages * steps
+            self._decode_ctx_tokens += sum(live_ctx) * steps
+            self._decode_window_tokens += live_window * steps
             self._decode_tokens_kept += kept
 
     def _count_picks(self, counts: np.ndarray, tokens: int

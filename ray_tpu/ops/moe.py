@@ -144,18 +144,27 @@ def moe_ffn_sharded(tokens, w_router, w_in, w_out, mesh,
 # ----------------------------------------------------------------------
 
 def route_topk(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int,
-               scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+               scale: float = 1.0, bias: Optional[jnp.ndarray] = None
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x [T,d], w_router [d,E] -> (ids [T,k] int32, weights [T,k] f32).
     The router runs in float32 whatever the model's type: a near-tie
     between the k-th and the next expert decides which experts a token
     sees. Scores are sigmoids; the weights of the k picked sum to
     ``scale`` (a model's routed scaling factor; 1 multiplies
-    nothing)."""
+    nothing). ``bias`` [E] SELECTS and never weighs: the k picked are
+    the largest of ``score + bias``, their weights come from the scores
+    alone (a load-balancing bias that training moves; such a router
+    guards its denominator with 1e-20)."""
     scores = jax.nn.sigmoid(jnp.einsum(
         "td,de->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    picked, ids = jax.lax.top_k(scores, top_k)
-    weights = picked / picked.sum(-1, keepdims=True)
+    if bias is None:
+        picked, ids = jax.lax.top_k(scores, top_k)
+        weights = picked / picked.sum(-1, keepdims=True)
+    else:
+        _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        picked = jnp.take_along_axis(scores, ids, axis=-1)
+        weights = picked / (picked.sum(-1, keepdims=True) + 1e-20)
     return ids.astype(jnp.int32), (weights if scale == 1.0
                                    else weights * scale)
 

@@ -22,7 +22,12 @@ The read, its oracle, and the two writes:
     this one is computed. An idle slot moves nothing. The page table,
     the lengths and the next live sequence ride scalar prefetch. Online
     softmax state (m, l, acc) in float32, as are scores and
-    probabilities; K and V reach the products as the pool holds them;
+    probabilities; K and V reach the products as the pool holds them.
+    A WINDOW layer's read (``ring`` > 0) is the same kernel told each
+    sequence's first visible position: the walk starts at that
+    position's block and finds logical page ``p`` at entry ``p % ring``
+    of the sequence's ring. The trace keeps its name,
+    ``paged_window_read``;
   - ``paged_latent_attention``: the same walk over ONE pool whose row
     a token serves as key and as value (latent attention in its
     absorbed form: one shared "KV head", every query head against it,
@@ -36,7 +41,8 @@ The read, its oracle, and the two writes:
   - ``append_token`` (``append_token_kv`` for K and V): a decode
     step's write, one cell a sequence in each pool it is given. A
     Pallas kernel too: each sequence's tail page goes through VMEM
-    and back to where it lay, the rest of the pool is not touched;
+    and back to where it lay, the rest of the pool is not touched; in
+    a ring the logical page wraps;
   - ``write_prefill_pages`` (``write_prefill_kv``): a prompt's write,
     whole pages by an XLA scatter.
 
@@ -49,7 +55,7 @@ one shared KV stream).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -65,12 +71,15 @@ def paged_attention_reference(q: jnp.ndarray, k_pages: jnp.ndarray,
                               v_pages: jnp.ndarray,
                               page_table: jnp.ndarray,
                               seq_lens: jnp.ndarray,
-                              score_width: int = 0) -> jnp.ndarray:
+                              score_width: int = 0,
+                              first: Optional[jnp.ndarray] = None
+                              ) -> jnp.ndarray:
     """q [B,H,D]; k_pages [P,KV,page,D], v_pages [P,KV,page,Dv];
     page_table [B,MP] (physical page per logical page, 0-padded);
-    seq_lens [B] = valid cache tokens per sequence. Scores are divided
-    by the root of ``score_width`` (D when 0). Returns [B,H,Dv]
-    (f32)."""
+    seq_lens [B] = valid cache tokens per sequence; ``first`` [B] the
+    first position a sequence still sees (a window's lower edge; 0 when
+    None). Scores are divided by the root of ``score_width`` (D when
+    0). Returns [B,H,Dv] (f32)."""
     B, H, D = q.shape
     _P, KV, page, _D = k_pages.shape
     MP = page_table.shape[1]
@@ -86,6 +95,8 @@ def paged_attention_reference(q: jnp.ndarray, k_pages: jnp.ndarray,
     scores = jnp.einsum("bkgd,bktd->bkgt", qg,
                         k.astype(jnp.float32)) / jnp.sqrt(score_width or D)
     valid = jnp.arange(MP * page)[None, :] < seq_lens[:, None]  # [B,T]
+    if first is not None:
+        valid &= jnp.arange(MP * page)[None, :] >= first[:, None]
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     # a masked row may hold anything (a parking page's NaN): 0 x NaN
@@ -109,9 +120,10 @@ BLOCK_TOKENS = 256
 LATENT_BLOCK_TOKENS = 1024
 
 
-def _read_kernel(table_ref, lens_ref, next_ref, q_ref, *refs,
+def _read_kernel(table_ref, lens_ref, next_ref, *refs,
                  page: int, pages_a_block: int, max_pages: int,
-                 n_pools: int, score_width: int, value_width: int):
+                 n_pools: int, score_width: int, value_width: int,
+                 ring: int):
     """One grid cell = one sequence, walked a block of ``pages_a_block``
     pages at a time up to its length. The pools stay in HBM: a block's
     pages come by one DMA each and pool, into one of two VMEM buffers
@@ -121,16 +133,27 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, *refs,
     sequence (``next_ref``), which that cell then finds arriving. An
     idle slot starts and waits for nothing.
 
-    ``refs``: the ``n_pools`` pools, the output, a buffer a pool, the
-    semaphores and the softmax state. Two pools are keys and values.
-    One pool is both: scores over a row's whole width, values its first
-    ``value_width`` columns out of the same buffer, and the
-    probabilities go to that product in the pool's type (128 query
-    heads against one row make it a matrix product worth the MXU's
-    rate; in float32 it would take several passes)."""
+    With a ``ring`` (a window layer's pool) a fourth prefetched vector
+    gives each sequence's first visible position: the walk starts at
+    the block that holds it, fetches no page that lies wholly before
+    it, masks what lies before it inside its page, and finds logical
+    page ``p`` at the table's entry ``p % ring``; a length is then not
+    held to the table's width.
+
+    ``refs``: (the first positions,) the queries, the ``n_pools``
+    pools, the output, a buffer a pool, the semaphores and the softmax
+    state. Two pools are keys and values. One pool is both: scores over
+    a row's whole width, values its first ``value_width`` columns out of
+    the same buffer, and the probabilities go to that product in the
+    pool's type (128 query heads against one row make it a matrix
+    product worth the MXU's rate; in float32 it would take several
+    passes)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if ring:
+        first_ref, *refs = refs
+    q_ref, *refs = refs
     pools, o_ref = refs[:n_pools], refs[n_pools]
     bufs = refs[n_pools + 1:2 * n_pools + 1]
     sems, m_ref, l_ref, acc_ref, blocks_ref = refs[2 * n_pools + 1:]
@@ -139,14 +162,25 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, *refs,
     n_seqs = pl.num_programs(0)
     T = pages_a_block * page
 
+    def first_block(seq):
+        """The block a sequence's walk starts at."""
+        return first_ref[seq] // T if ring else 0
+
     def wave(what, seq, block, slot):
         """``what`` = "start" or "wait": the DMAs of the pages that
-        ``seq`` owns in its ``block``, into buffer ``slot``"""
+        ``seq`` owns (and still sees) in its ``block``, into buffer
+        ``slot``"""
         first = block * pages_a_block
-        owned = jnp.minimum(pl.cdiv(lens_ref[seq], page), max_pages)
+        if ring:
+            owned = pl.cdiv(lens_ref[seq], page)
+            seen = jnp.clip(first_ref[seq] // page - first, 0,
+                            pages_a_block)
+        else:
+            owned = jnp.minimum(pl.cdiv(lens_ref[seq], page), max_pages)
+            seen = 0
 
         def one(i, carry):
-            pid = table_ref[seq, first + i]
+            pid = table_ref[seq, (first + i) % ring if ring else first + i]
             rows = pl.ds(pl.multiple_of(i * page, page), page)
             for which, (pool, buf) in enumerate(zip(pools, bufs)):
                 getattr(pltpu.make_async_copy(
@@ -155,7 +189,7 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, *refs,
             return carry
 
         jax.lax.fori_loop(
-            0, jnp.clip(owned - first, 0, pages_a_block), one, None)
+            seen, jnp.clip(owned - first, 0, pages_a_block), one, None)
 
     @pl.when(b == 0)
     def _first():
@@ -163,10 +197,14 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, *refs,
 
         @pl.when(next_ref[0] < n_seqs)
         def _():
-            wave("start", next_ref[0], 0, 0)
+            wave("start", next_ref[0], first_block(next_ref[0]), 0)
 
-    seq_len = jnp.minimum(lens_ref[b], max_pages * page)
-    n_blocks = pl.cdiv(seq_len, T)
+    if ring:
+        seq_len = lens_ref[b]
+        n_blocks = pl.cdiv(seq_len, T) - first_block(b)
+    else:
+        seq_len = jnp.minimum(lens_ref[b], max_pages * page)
+        n_blocks = pl.cdiv(seq_len, T)
     done = blocks_ref[0]      # blocks walked so far: its parity is the buffer
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -176,12 +214,14 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, *refs,
         slot = (done + j) % 2
         last = j + 1 == n_blocks
         seq_next = jnp.where(last, next_ref[b + 1], b)
+        at = first_block(b) + j if ring else j
 
         @pl.when(seq_next < n_seqs)
         def _():
-            wave("start", seq_next, jnp.where(last, 0, j + 1), 1 - slot)
+            wave("start", seq_next,
+                 jnp.where(last, first_block(seq_next), at + 1), 1 - slot)
 
-        wave("wait", b, j, slot)
+        wave("wait", b, at, slot)
         q = q_ref[0]                                # [KV, G, D]
         k = k_buf[slot]                             # [KV, T, D]
         dtype = jnp.promote_types(q.dtype, k.dtype)
@@ -189,11 +229,14 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, *refs,
                        preferred_element_type=jnp.float32) / jnp.sqrt(
                            score_width * 1.0)       # [KV, G, T]
         # past the length a buffer holds what an earlier block left
-        # there, or nothing yet: a score there counts for nothing, and a
-        # value row there must not reach the product (0 x NaN)
+        # there, or nothing yet, and so it does before a window's first
+        # position: a score there counts for nothing, and a value row
+        # there must not reach the product (0 x NaN)
         def live(shape, axis):
-            return j * T + jax.lax.broadcasted_iota(
-                jnp.int32, shape, axis) < seq_len
+            pos = at * T + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+            if ring:
+                return (pos < seq_len) & (pos >= first_ref[b])
+            return pos < seq_len
 
         s = jnp.where(live((1, 1, T), 2), s, NEG_INF)
         if n_pools == 2:
@@ -223,9 +266,11 @@ def _read_kernel(table_ref, lens_ref, next_ref, q_ref, *refs,
 
 def _paged_read(q, pools, page_table, seq_lens, *, block_tokens: int,
                 interpret: bool, score_width: int, value_width: int,
-                name=None) -> jnp.ndarray:
+                name=None, first=None, ring: int = 0) -> jnp.ndarray:
     """The walk's ``pallas_call``: q [B,KV,G,D] against ``pools`` ([P,
-    KV,page,D] each; two are keys and values, one is both). Returns
+    KV,page,D] each; two are keys and values, one is both). With a
+    ``ring``, ``first`` [B] are the sequences' first visible positions
+    and ``page_table`` [B,ring] their rings. Returns
     [B,KV,G,value_width] float32."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -248,14 +293,19 @@ def _paged_read(q, pools, page_table, seq_lens, *, block_tokens: int,
     kernel = functools.partial(
         _read_kernel, page=page, pages_a_block=pages_a_block, max_pages=MP,
         n_pools=len(pools), score_width=score_width,
-        value_width=value_width)
+        value_width=value_width, ring=ring)
+    # page_table, seq_lens, next_live (and, of a ring, the first
+    # visible positions)
+    scalars = (table, lens, next_live)
+    if ring:
+        scalars += (jnp.clip(first, 0, lens).astype(jnp.int32),)
 
     def head_rows(width):
         return pl.BlockSpec((1, KV, G, width),
-                            lambda b, table, lens, nxt: (b, 0, 0, 0))
+                            lambda b, *scalars: (b, 0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,   # page_table, seq_lens, next_live
+        num_scalar_prefetch=len(scalars),
         grid=(B,),
         # the pools are read where they lie: left to the compiler, one
         # that fits VMEM may be fetched there whole, every step
@@ -278,25 +328,37 @@ def _paged_read(q, pools, page_table, seq_lens, *, block_tokens: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret, name=name,
-    )(table, lens, next_live, q, *pools)
+    )(*scalars, q, *pools)
 
 
-@functools.partial(jax.jit, static_argnames=("block_tokens", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("block_tokens", "interpret", "ring"))
 def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                     v_pages: jnp.ndarray, page_table: jnp.ndarray,
                     seq_lens: jnp.ndarray, *,
                     block_tokens: int = BLOCK_TOKENS,
-                    interpret: bool = False) -> jnp.ndarray:
+                    interpret: bool = False,
+                    first: Optional[jnp.ndarray] = None,
+                    ring: int = 0) -> jnp.ndarray:
     """Pallas flash-decoding over paged KV (see module docstring);
     interpret=True runs the kernel body off-TPU for testing. Jitted on
     its own, so that a decode program traces the kernel once and not
     once a layer, and the engine's six programs once between them
-    (tracing it costs what a whole layer's einsums do)."""
+    (tracing it costs what a whole layer's einsums do).
+
+    ``ring`` > 0 is a window layer's read: ``page_table`` [B,ring] is
+    each sequence's ring (logical page ``p`` lies at entry ``p %
+    ring``), ``first`` [B] its first visible position, and only
+    positions ``first <= t < seq_lens`` are fetched and scored. The
+    trace keeps that call's name, ``paged_window_read``. Without a
+    ring the program is the one it was before there were rings."""
     B, H, D = q.shape
     KV = k_pages.shape[1]
     out = _paged_read(q.reshape(B, KV, H // KV, D), (k_pages, v_pages),
                       page_table, seq_lens, block_tokens=block_tokens,
-                      interpret=interpret, score_width=D, value_width=D)
+                      interpret=interpret, score_width=D, value_width=D,
+                      first=first, ring=ring,
+                      name="paged_window_read" if ring else None)
     return out.reshape(B, H, D)
 
 
@@ -321,14 +383,17 @@ def paged_latent_attention(q: jnp.ndarray, pages: jnp.ndarray,
     return out.reshape(B, H, value_width)
 
 
-def paged_attention_auto(q, k_pages, v_pages, page_table, seq_lens):
+def paged_attention_auto(q, k_pages, v_pages, page_table, seq_lens,
+                         **window):
     """The read of every decode program: the Pallas kernel, at every
     geometry (PERF.md section 6, PR 30: it beats the gather at short
     contexts too). Off-TPU it runs in interpret mode so tests exercise
     the real kernel logic. A kernel that fails to lower raises: nothing
-    falls back to the gather."""
+    falls back to the gather. ``window``: a window layer's ``first``
+    and ``ring`` (``paged_attention``)."""
     return paged_attention(q, k_pages, v_pages, page_table, seq_lens,
-                           interpret=jax.default_backend() != "tpu")
+                           interpret=jax.default_backend() != "tpu",
+                           **window)
 
 
 # ----------------------------------------------------------------------
@@ -357,19 +422,23 @@ def _append_kernel(phys_ref, slot_ref, *refs):
 def append_token_kv(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                     k_new: jnp.ndarray, v_new: jnp.ndarray,
                     page_table: jnp.ndarray,
-                    seq_lens: jnp.ndarray
+                    seq_lens: jnp.ndarray, ring: int = 0
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``append_token`` for K and V in one call."""
     return append_token((k_pages, v_pages), (k_new, v_new), page_table,
-                        seq_lens)
+                        seq_lens, ring)
 
 
 def append_token(pools: Tuple[jnp.ndarray, ...],
                  news: Tuple[jnp.ndarray, ...], page_table: jnp.ndarray,
-                 seq_lens: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
+                 seq_lens: jnp.ndarray, ring: int = 0
+                 ) -> Tuple[jnp.ndarray, ...]:
     """Write one decode token's rows ``news`` ([B,KV,D] each) into each
     sequence's tail cell (page_table[b, seq_len // page], seq_len %
     page) of ``pools`` ([P,KV,page,D] each, of one shape), in place.
+    With a ``ring`` (a window layer: ``page_table`` [B,ring]) the
+    logical page wraps, ``(seq_len // page) % ring``, over the oldest
+    page, whose tokens no later position sees.
 
     A Pallas kernel over grid (B,), every pool in one call: the pools
     are input AND output of the same buffers (``input_output_aliases``)
@@ -409,7 +478,7 @@ def append_token(pools: Tuple[jnp.ndarray, ...],
     P, KV, page, D = pools[0].shape
     B, MP = page_table.shape
     n = len(pools)
-    logical = seq_lens // page
+    logical = (seq_lens // page) % ring if ring else seq_lens // page
     phys = jnp.take_along_axis(page_table,
                                jnp.minimum(logical, MP - 1)[:, None],
                                axis=1)[:, 0]                   # [B]

@@ -245,7 +245,7 @@ def _no_gathered_copy(text, slots, pages_a_seq, kv, page, d):
 
 
 def _attention_kernels(text, scope):
-    """The Pallas custom calls under a mixer's scope: the paged
+    """The Pallas custom calls under a scope: under a mixer's the paged
     attention kernel's (the append's lie under ``kv_append``)."""
     return [line for line in text.splitlines()
             if 'custom_call_target="tpu_custom_call"' in line
@@ -448,8 +448,11 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
             chip((slots,), jnp.int32), chip((slots,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert "ragged-dot" in text
-    # the one attention layer reads its pages through the one kernel
+    # the one attention layer reads its pages through the one kernel,
+    # appends through the other, and neither is a window layer's
     assert len(_attention_kernels(text, "gqa")) == 1
+    assert len(_attention_kernels(text, "kv_append")) == 1
+    assert "paged_window_read" not in text
     _no_gathered_copy(text, slots, pages_a_seq, 8, page, hd)
     m = compiled.memory_analysis()
     with capsys.disabled():
@@ -646,3 +649,185 @@ def test_latent_prefill_program(chip, monkeypatch, capsys, bucket, rows):
     _no_pool_moves(text, pool_dims)
     assert m.alias_size_in_bytes >= len(mcfg.layers) * 2 * 2561 * 128 * 640
     assert m.temp_size_in_bytes < 2 << 30
+
+
+# ----------------------------------------------------------------------
+# window layers (PR 33): the cell's programs at its own geometry, and
+# the shared kernels as they were for those who do not ask
+# ----------------------------------------------------------------------
+
+def _trinity_two_layers(chip):
+    """The benchmark's window configuration at every published width,
+    one window layer (the leading dense layer) and the period's full
+    layer with its experts (32 held of a router of 256), an eighth of
+    the vocabulary: (description, parameter tree as shapes on the
+    described chip, cache as shapes)."""
+    config = _benchmark_config(
+        "trinity-large-preview-serve-L5-ep8", num_hidden_layers=2,
+        layer_types=["sliding_attention", "full_attention"])
+    from benchmark import weights_trinity_large as W
+    from ray_tpu.models import decoder_forward
+    from ray_tpu.models.inference import InferenceConfig
+
+    mcfg = W.description(config)
+    params = jax.eval_shape(
+        lambda k: W.init_params(config, k, jnp.bfloat16), W.seed_key(1))
+    icfg = InferenceConfig(batch_size=32, page_size=128,
+                           max_pages_per_seq=136, num_pages=4353)
+    cache = jax.eval_shape(lambda: decoder_forward.init_cache(mcfg, icfg))
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: chip(x.shape, x.dtype), tree)
+    return mcfg, on_chip(params), on_chip(cache)
+
+
+def test_window_decode_chunk(chip, monkeypatch, capsys):
+    """The decode program of the window cell at its own geometry (32
+    slots, pages of 128: 4,353 in the full layer's pool with 136 a
+    sequence, 32 rings of 33 + 1 in the window layer's), 4 steps, pools
+    donated: one ``paged_window_read`` under ``win`` and one append
+    under ``win_append`` for the window layer, the plain read under
+    ``gqa`` and ``kv_append`` for the full one, no copy of either
+    pool. The compiler's analysis, not a chip reading."""
+    from ray_tpu.models import decoder_forward
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mcfg, params, cache = _trinity_two_layers(chip)
+    assert [e[0].shape for e in cache] == [(32 * 33 + 1, 8, 128, 128),
+                                           (4353, 8, 128, 128)]
+    compiled = jax.jit(
+        lambda p, t, cache, table, lens, live:
+        decoder_forward.decode_chunk_cached(
+            p, mcfg, t, cache, table, lens, live, n_steps=4),
+        donate_argnums=(2,)).lower(
+            params, chip((32,), jnp.int32), cache,
+            chip((32, 136), jnp.int32), chip((32,), jnp.int32),
+            chip((32,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[window decode chunk, 4 steps, 2 layers] arguments "
+              f"{m.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} GB")
+    reads = _attention_kernels(text, "win")
+    assert len(reads) == 1 and re.match(r"\s*%?paged_window_read", reads[0])
+    assert len(_attention_kernels(text, "win_append")) == 1
+    assert len(_attention_kernels(text, "gqa")) == 1
+    assert not re.match(r"\s*%?paged_window_read",
+                        _attention_kernels(text, "gqa")[0])
+    assert len(_attention_kernels(text, "kv_append")) == 1
+    for pool_dims in ((32 * 33 + 1, 8, 128, 128), (4353, 8, 128, 128)):
+        _no_pool_moves(text, pool_dims)
+    assert m.alias_size_in_bytes >= 2 * 2 * (1057 + 4353) * 8 * 128 * 128
+    assert m.temp_size_in_bytes < 1 << 30
+
+
+def test_window_prefill_launch_of_the_longest_bucket(chip, monkeypatch,
+                                                     capsys):
+    """A launch of 16,384 positions of the window cell (one window and
+    one full layer): the band through ``window_prefill_attention``
+    under ``win``, the full layer through the library's flash kernel
+    under ``gqa``, no [S,S] scores, no copy of a pool, and temporaries
+    that leave the chip room for the other three layers' weights (the
+    five-layer program: 2.3 GB beside 13.1 GB of arguments, my AOT
+    compile, PR 33)."""
+    from ray_tpu.models import decoder_forward
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mcfg, params, cache = _trinity_two_layers(chip)
+    bucket = 16384
+    compiled = jax.jit(
+        lambda p, cache, toks, plens, slots, pages, req:
+        decoder_forward.prefill_cached(p, mcfg, cache, toks, plens, slots,
+                                       pages, req),
+        donate_argnums=(1,)).lower(
+            params, cache, chip((1, bucket), jnp.int32),
+            chip((1,), jnp.int32), chip((1,), jnp.int32),
+            chip((1, bucket // 128), jnp.int32),
+            chip((1,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[window prefill b{bucket}, 2 layers] temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} GB")
+    band = _attention_kernels(text, "win")
+    assert len(band) == 1
+    assert re.match(r"\s*%?window_prefill_attention", band[0])
+    assert len(_attention_kernels(text, "gqa")) == 1
+    assert not re.search(rf"\[(?:\d+,)*{bucket},{bucket}\]", text)
+    # a launch's write is a scatter into the donated pool: a fusion that
+    # yields it, never a copy of it
+    for pool_dims in ((32 * 33 + 1, 8, 128, 128), (4353, 8, 128, 128)):
+        assert [n for n, op, _ in _pool_shaped(text, pool_dims)
+                if op in ("copy", "copy-start")] == []
+    assert m.temp_size_in_bytes < 3 << 30
+
+
+def test_route_topk_without_a_bias_lowers_as_it_did():
+    """``route_topk`` gained an optional selection bias; without one it
+    is the function it was, to the letter of its lowered text (the
+    body of PR 32's function stands here as the oracle)."""
+    from ray_tpu.ops.moe import route_topk
+
+    def as_it_was(x, w_router, top_k, scale=1.0):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "td,de->te", x.astype(jnp.float32),
+            w_router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        picked, ids = jax.lax.top_k(scores, top_k)
+        weights = picked / picked.sum(-1, keepdims=True)
+        return ids.astype(jnp.int32), (weights if scale == 1.0
+                                       else weights * scale)
+
+    x = jax.ShapeDtypeStruct((64, 128), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((128, 256), jnp.bfloat16)
+
+    def lowered(fn, *extra, **kw):
+        text = jax.jit(lambda x, w, *e: fn(x, w, 8, 2.5, *e, **kw)).lower(
+            x, w, *extra).as_text()
+        return re.sub(r"\bas_it_was\b|\broute_topk\b", "f", text)
+
+    assert lowered(route_topk) == lowered(as_it_was)
+    bias = jax.ShapeDtypeStruct((256,), jnp.float32)
+    assert lowered(route_topk, bias) != lowered(as_it_was)
+
+
+@pytest.mark.parametrize("model", ["dense", "latent"])
+def test_shared_kernels_are_as_they_were_for_those_who_do_not_ask(
+        chip, monkeypatch, model):
+    """The read and append kernels gained a ring and a first position
+    for window layers. A model without such a layer holds the Pallas
+    calls it held on the parent (PR 32), one read and one append a
+    layer under the scopes they had, none of them a window's, and the
+    read takes its three prefetched vectors and no fourth
+    (``test_hybrid_decode_chunk`` holds the same for the gated layer's
+    ``gqa``)."""
+    from ray_tpu.models import decoder_forward
+    from ray_tpu.models.decoder import describe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if model == "dense":
+        mcfg, params = _mistral_two_layers(16 * 48)
+        params = jax.tree_util.tree_map(lambda x: chip(x.shape, x.dtype),
+                                        params)
+        mcfg = describe(mcfg)
+        pool = chip((1537, 8, 16, 128), jnp.bfloat16)
+        cache, pages_a_seq = ((pool, pool),) * 2, 48
+        read, append = "attn", "kv_append"
+    else:
+        mcfg, params = _openpangu_two_layers(chip)
+        cache = tuple((chip((2561, 1, 128, 640), jnp.bfloat16),)
+                      for _ in mcfg.layers)
+        pages_a_seq, read, append = 80, "mla_absorb", "latent_append"
+    lowered = jax.jit(
+        lambda p, t, cache, table, lens, live:
+        decoder_forward.decode_chunk_cached(
+            p, mcfg, t, cache, table, lens, live, n_steps=2),
+        donate_argnums=(2,)).lower(
+            params, chip((32,), jnp.int32), cache,
+            chip((32, pages_a_seq), jnp.int32), chip((32,), jnp.int32),
+            chip((32,), jnp.bool_))
+    text = lowered.compile().as_text()
+    assert len(_attention_kernels(text, read)) == len(mcfg.layers)
+    assert len(_attention_kernels(text, append)) == len(mcfg.layers)
+    assert "paged_window_read" not in text
+    assert not _attention_kernels(text, "win") + _attention_kernels(text, "win_append")

@@ -150,3 +150,54 @@ def test_jits_and_runs_in_the_models_type(layer):
     want, _ = loop_over_experts(layer, 0, 20)
     assert y.dtype == jnp.bfloat16
     assert np.abs(np.asarray(y, np.float32) - want).max() < 0.1
+
+
+def test_eight_shares_with_a_selection_bias_add_up(layer):
+    """The window model's deployment at a small size: a router of 40
+    whose 4 picks are the largest of ``score + bias`` and are weighed
+    by their scores alone (normalised over the picked, scaled by
+    2.448), 8 chips of 5 experts each, the shared expert unscaled and
+    counted ONCE: the parts add up to the uncut layer, by a loop over
+    experts that knows no program; the bias changes the picks, and a
+    bias that also weighed would be another layer."""
+    p, x, k, scale = layer, layer["x"], 4, 2.448
+    bias = jnp.asarray(np.random.default_rng(1).normal(size=E) * 0.1,
+                       jnp.float32)
+    scores = np.asarray(jax.nn.sigmoid(x @ p["router"]))
+    top = np.argsort(-(scores + np.asarray(bias)), axis=1)[:, :k]
+    whole = np.zeros((x.shape[0], D))
+    for t in range(x.shape[0]):
+        norm = scores[t, top[t]].sum() + 1e-20
+        for e in top[t]:
+            whole[t] += scale * scores[t, e] / norm * np.asarray(swiglu(
+                x[t:t + 1], p["w_gate"][e], p["w_up"][e],
+                p["w_down"][e]))[0]
+    ids, w = route_topk(x, p["router"], k, scale, bias)
+    assert (np.sort(np.asarray(ids), 1) == np.sort(top, 1)).all()
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), scale, atol=1e-5)
+    plain_ids, _ = route_topk(x, p["router"], k, scale)
+    changed = (np.sort(np.asarray(ids), 1)
+               != np.sort(np.asarray(plain_ids), 1)).any(axis=1)
+    assert changed.mean() > 0.2             # the bias changes the picks
+    parts = [experts_held(x, ids, w, p["w_gate"][lo:lo + 5],
+                          p["w_up"][lo:lo + 5], p["w_down"][lo:lo + 5], lo)
+             for lo in range(0, E, 5)]
+    assert len(parts) == 8
+    sh = p["shared"]
+    shared = np.asarray(swiglu(x, sh["w_gate"], sh["w_up"], sh["w_down"]))
+    np.testing.assert_allclose(
+        np.asarray(sum(y for y, _ in parts)) + shared, whole + shared,
+        atol=1e-5)
+    assert sum(int(c.sum()) for _, c in parts) == 50 * k
+    # weights taken from score + bias would be another layer
+    biased = np.take_along_axis(scores + np.asarray(bias), np.asarray(ids), 1)
+    assert np.abs(scale * biased / biased.sum(-1, keepdims=True)
+                  - np.asarray(w)).max() > 1e-3
+
+
+def test_a_zero_bias_selects_what_no_bias_selects(layer):
+    ids, w = route_topk(layer["x"], layer["router"], K, 2.5)
+    ids0, w0 = route_topk(layer["x"], layer["router"], K, 2.5,
+                          jnp.zeros((E,), jnp.float32))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids0))
+    np.testing.assert_allclose(np.asarray(w), np.asarray(w0), rtol=1e-6)
