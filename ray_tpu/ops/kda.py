@@ -13,7 +13,7 @@ with key ``k`` (unit length), value ``v``, query ``q``, log decay
 Two forms of the same function:
 
 - ``kda_step``: one position for every slot of a decode batch.
-- ``kda_chunked``: a whole prompt, ``chunk`` positions at a time. Inside
+- ``kda_chunked``: a whole prompt, a chunk of positions at a time. Inside
   a chunk the updates are a unit lower-triangular system (the WY form):
   with ``G_t`` the decay accumulated from the chunk's start,
   ``A[i,j] = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)`` for j < i, and
@@ -24,6 +24,32 @@ Two forms of the same function:
   a channel may decay by any factor inside a chunk without an overflow
   (the usual ``K / exp(G)`` is never formed).
 
+``kda_chunked`` is ONE Pallas call (``kda_chunk_scan`` in a trace) whose
+grid walks the chunks of a row with every head's ``[dk, dv]`` state
+resident in VMEM from the first chunk to the last; q, k, v, g, beta are
+read in the ``[N,S,H,*]`` layout they arrive in and the output is
+written in it. How a chunk of 64 is made cheap (PERF.md section 6, PR
+34, has the table that chose the sizes):
+
+- the pairwise decay is taken element by element on the 16 x 16
+  DIAGONAL blocks only. A block under the diagonal is a matrix product
+  of ``K_i exp(G_i - G_b)`` against ``K_j exp(G_b - G_j)`` with ``b``
+  the boundary between the two spans (``j < b <= i``: both exponents
+  <= 0), neighbouring spans of 16, then of 32;
+- ``(I + A)^-1`` is built by merging neighbouring spans from single
+  rows up (``[[T1, 0], [-T2 A21 T1, T2]]``), two heads side by side in
+  the matrix unit's 128 columns; it is as stable as substitution (the
+  product ``(I - A)(I + A^2)(I + A^4)...`` is not at beta = 2);
+- everything up to there hangs on q, k, g, beta and not on the state,
+  so grid step t PREPARES chunk t while chunk t - 1, prepared a step
+  earlier and kept in VMEM scratch, goes through the three state
+  products: two independent streams of work in one basic block, the
+  vector unit's (the diagonal blocks) under the matrix unit's;
+- every product is float32 at ``Precision.HIGHEST``, state and operands
+  float32. (The cumulative sum is a product with ones and zeros: three
+  passes over ``g = hi + mid + lo``, an exact split, give what six
+  would.)
+
 A position with ``g = 0`` and ``beta = 0`` leaves the state as it is:
 that is how the rows of a padded bucket past their prompt's length are
 kept from touching it (``pad_mask``). The short causal convolution that
@@ -33,6 +59,7 @@ to the decode steps, are here too.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -98,63 +125,275 @@ def kda_step(q, k, v, g, beta, state):
     return jnp.einsum("bhk,bhkv->bhv", q, s, precision=_HI), s
 
 
-def kda_chunked(q, k, v, g, beta, state=None, *, chunk: int = 16):
-    """A row of positions through the recurrence, ``chunk`` at a time.
-    q, k, g [N,S,H,dk], v [N,S,H,dv], beta [N,S,H]; ``state``
-    [N,H,dk,dv] float32 (zeros when None). S need not be a multiple of
-    ``chunk``. Returns (o [N,S,H,dv] float32, final state). The chunk
-    of 16 is the fastest of 16, 32, 64 and of three variants with
-    sub-chunks on a v5e at 64 heads of 128 (6.97, 7.45, 11.25 ms for
-    2,048 positions; PERF.md, PR 27): the pairwise decay of a chunk is
-    float32 work for the vector unit that grows with the chunk."""
+# positions a grid step takes through the three state products, the
+# diagonal blocks whose pairwise decay is taken element by element, and
+# the heads a grid step holds (the table that chose them: PERF.md
+# section 6, PR 34)
+_CHUNK = 64
+_BLOCK = 16
+_HEADS = 8
+
+
+def _mm(a, b):
+    """a [h,m,k] @ b [h,k,n] a head, float32 at full precision."""
+    return jnp.einsum("hmk,hkn->hmn", a, b, precision=_HI,
+                      preferred_element_type=jnp.float32)
+
+
+def _prepare(q, k, g, beta, block: int, out: dict):
+    """What a chunk needs that does not hang on the state, for a few
+    heads at once: q, k, g [h,C,dk] and beta [h,C,1]; ``out`` receives
+    ``ke`` = K exp(G), ``qe`` = Q exp(G), ``kd`` = K exp(G_C - G)
+    [h,C,dk], ``dec`` = exp(G_C) [h,1,dk], ``inv`` = (I + A)^-1 and
+    ``qk`` = tril(Q K^T decayed) [h,C,C] of the docstring's WY form.
+    The heads go through each stage together, a batch of every array (a
+    head's stages hang on one another through the matrix unit's
+    latency, and the compiler keeps the order it is given). A generator
+    that stops after each stage, so that the stages of the chunk that
+    is going through the state can be laid between them."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    h, c, dk = q.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    # G, inclusive: a product with ones and zeros, so three passes of
+    # the matrix unit over g = hi + mid + lo (exact: 3 x 8 bits) do
+    # what six would
+    tril = jnp.broadcast_to((col <= row).astype(bf16), (h, c, c))
+    hi = g.astype(bf16)
+    rest = g - hi.astype(f32)
+    mid = rest.astype(bf16)
+    low = (rest - mid.astype(f32)).astype(bf16)
+    big = sum(jnp.einsum("hmk,hkn->hmn", tril, x, preferred_element_type=f32)
+              for x in (low, mid, hi))
+    yield
+    # the diagonal blocks: decay from j to i >= j a key channel, the
+    # exponent held at or under 0 BEFORE it is taken
+    nb = c // block
+    kk, qk = [], []
+    for m in range(nb):
+        km, qm, gm = (x[:, m * block:(m + 1) * block] for x in (k, q, big))
+        kj = km[:, None] * jnp.exp(
+            jnp.minimum(gm[:, :, None] - gm[:, None], 0.0))
+        kk.append(jnp.sum(km[:, :, None] * kj, -1))
+        qk.append(jnp.sum(qm[:, :, None] * kj, -1))
+        yield
+    same = row // block == col // block
+    kk, qk = (jnp.where(same, jnp.tile(jnp.concatenate(x, 1), (1, 1, nb)),
+                        0.0) for x in (kk, qk))
+    # the blocks under the diagonal, a pair of neighbouring spans at a
+    # time: rows of the later span decay from the boundary b between
+    # the two, columns of the earlier span decay up to it, so that
+    # exp(G_i - G_j) = exp(G_i - G_b) exp(G_b - G_j) with both <= 0
+    span = block
+    while span < c:
+        pairs = c // (2 * span)
+        later = (pos // span) % 2 == 1
+        bound = jnp.broadcast_to(
+            big.reshape(h, pairs, 2 * span, dk)[:, :, span - 1:span],
+            (h, pairs, 2 * span, dk)).reshape(h, c, dk)
+        e = jnp.exp(jnp.where(later, big - bound, bound - big))
+        ke = k * e
+        # the later rows alone go through the matrix unit
+        rows = jnp.concatenate(
+            [x.reshape(h, pairs, 2, span, dk)[:, :, 1].reshape(h, c // 2, dk)
+             for x in (ke, q * e)], 1)
+        p = jnp.einsum("hmk,hnk->hmn", rows, jnp.where(later, 0.0, ke),
+                       precision=_HI, preferred_element_type=f32)
+        p = p.reshape(h, 2, pairs, span, c)
+        p = jnp.concatenate([jnp.zeros_like(p), p], 3).reshape(h, 2, c, c)
+        pair = row // (2 * span) == col // (2 * span)
+        kk += jnp.where(pair, p[:, 0], 0.0)
+        qk += jnp.where(pair, p[:, 1], 0.0)
+        span *= 2
+        yield
+    a = jnp.where(col < row, beta * kk, 0.0)
+    # (I + A)^-1, pairs of neighbouring spans merged from single rows
+    # up: [[T1, 0], [-T2 A21 T1, T2]] (a pair of rows: [[1, 0], [-a, 1]]).
+    # The matrix unit's time goes by the rows it is fed, so as many
+    # heads as fill its 128 columns go side by side, [C, w C], against
+    # the block-diagonal [w C, w C] of the other factor
+    w = max(d for d in range(1, h + 1) if h % d == 0 and d * c <= max(128, c))
+    rw = jax.lax.broadcasted_iota(jnp.int32, (c, w * c), 0)
+    cw = jax.lax.broadcasted_iota(jnp.int32, (c, w * c), 1) % c
+    own = (jax.lax.broadcasted_iota(jnp.int32, (w * c, w * c), 0) // c
+           == jax.lax.broadcasted_iota(jnp.int32, (w * c, w * c), 1) // c)
+    diag = lambda x: jnp.where(own, jnp.tile(x, (1, w, 1)), 0.0)  # noqa: E731
+    a = jnp.concatenate([a[i * (h // w):(i + 1) * (h // w)]
+                         for i in range(w)], 2)
+    inv = (rw == cw).astype(f32) - jnp.where(
+        (rw % 2 == 1) & (cw == rw - 1), a, 0.0)
+    span = 2
+    while span < c:
+        under = ((rw // span) % 2 == 1) & ((cw // span) % 2 == 0) & (
+            rw // (2 * span) == cw // (2 * span))
+        part = _mm(jnp.where(under, a, 0.0), diag(inv))
+        yield
+        inv = inv - _mm(inv, diag(part))
+        yield
+        span *= 2
+    e = jnp.exp(big)
+    # G_C by a masked sum, not the slice big[:, c - 1:c]: a row taken
+    # from the last sublane of a tile and stored as a row of its own
+    # aborts the TPU compiler (jax 0.9.0)
+    last = jnp.sum(jnp.where(pos == c - 1, big, 0.0), 1, keepdims=True)
+    out.update(
+        ke=k * e, qe=q * e, kd=k * jnp.exp(last - big), dec=jnp.exp(last),
+        inv=jnp.concatenate([inv[:, :, i * c:(i + 1) * c]
+                             for i in range(w)], 0),
+        qk=jnp.where(col <= row, qk, 0.0))
+
+
+def _advance(v, beta, s0, kept, out: dict):
+    """A prepared chunk through the state, for a few heads at once: v
+    [h,C,dv], beta [h,C,1], s0 [h,dk,dv] and ``kept``, a function of
+    the name that reads what ``_prepare`` left of the chunk; ``out``
+    receives o [h,C,dv] and s1. A generator, as ``_prepare`` is."""
+    c = v.shape[1]
+    # (K e; Q e) share one load of the state
+    into = _mm(jnp.concatenate([kept("ke"), kept("qe")], 1), s0)
+    yield
+    u = _mm(kept("inv"), beta * (v - into[:, :c]))
+    yield
+    out["o"] = into[:, c:] + _mm(kept("qk"), u)
+    yield
+    out["s"] = (jnp.swapaxes(kept("dec"), 1, 2) * s0 + jnp.einsum(
+        "hck,hcv->hkv", kept("kd"), u, precision=_HI,
+        preferred_element_type=jnp.float32))
+
+
+_KEPT = ("ke", "qe", "kd", "dec", "inv", "qk")
+
+
+def _scan_kernel(q_ref, k_ref, g_ref, beta_next_ref, v_ref, beta_ref, s0_ref,
+                 o_ref, s_ref, *kept_refs, heads: int, block: int):
+    """Grid step t prepares chunk t and takes chunk t - 1, which step
+    t - 1 prepared, through the state: the two hang on nothing of one
+    another, so the compiler can fill the matrix unit's waits of the
+    one with the vector unit's work of the other. The first step's
+    second half works on what the scratch happens to hold and is thrown
+    away (its state by the select, its output by the next step, which
+    writes the same block); the last step's first half prepares the
+    last chunk again for nobody. A step holds every head and walks them
+    ``heads`` at a time; a head's rows of a block [C * H, d] lie H
+    apart."""
+    import jax.experimental.pallas as pl
+
+    t = pl.program_id(1)
+    kept = dict(zip(_KEPT, kept_refs))
+    n_heads = s_ref.shape[1]
+    chunk = beta_ref.shape[1]
+
+    @pl.when(t == 0)
+    def _start():
+        s_ref[...] = s0_ref[...]
+
+    def some_heads(i, carry):
+        first = i * heads
+        mine = pl.ds(first, heads)
+
+        def rows(h):
+            return (0, pl.ds(first + h, chunk, stride=n_heads), slice(None))
+
+        def of(ref):           # [C*H,d] -> the heads' [h,C,d]
+            return jnp.stack([ref[rows(h)] for h in range(heads)])
+
+        def column(ref):       # [C,H] -> the heads' [h,C,1]
+            x = ref[0]
+            lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+            return jnp.stack([
+                jnp.sum(jnp.where(lane == first + h, x, 0.0), 1,
+                        keepdims=True) for h in range(heads)])
+
+        prepared, done = {}, {}
+        s0 = s_ref[0, mine]
+        ahead = _prepare(of(q_ref), of(k_ref), of(g_ref),
+                         column(beta_next_ref), block, prepared)
+        behind = _advance(of(v_ref), column(beta_ref), s0,
+                          lambda name: kept[name][mine], done)
+        # a stage of the one after each stage of the other
+        for _ in ahead:
+            next(behind, None)
+        for _ in behind:
+            pass
+        for h in range(heads):
+            o_ref[rows(h)] = done["o"][h]
+        s_ref[0, mine] = jnp.where(t > 0, done["s"], s0)
+        for name in _KEPT:
+            kept[name][mine] = prepared[name]
+        return carry
+
+    jax.lax.fori_loop(0, n_heads // heads, some_heads, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _chunk_scan(q, k, v, g, beta, state, *, chunk: int, interpret: bool):
+    """``kda_chunked`` with a state. Jitted, so that the programs that
+    call it with the same shapes (a layer, a bucket) trace the kernel
+    once between them."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
     f32 = jnp.float32
     n, s, h, dk = q.shape
     dv = v.shape[-1]
+    q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
     pad = (-s) % chunk
     if pad:       # no-op positions: g = 0, beta = 0
         q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
                       for a in (q, k, v, g))
         beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
     nc = (s + pad) // chunk
+    heads = next(m for m in range(min(_HEADS, h), 0, -1) if h % m == 0)
 
-    def chunks(a):      # [N,S,H,...] -> [nc,N,H,C,...]
-        a = a.reshape((n, nc, chunk) + a.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+    # the operands are read where they lie: [N,S,H,d] is [N,S*H,d] as it
+    # stands in memory (H whole sublane tiles), a chunk of every head
+    # one block of C*H rows. A step reads the chunk it prepares and the
+    # chunk before it, which it takes through the state
+    def ahead(*block):
+        return pl.BlockSpec((1,) + block, lambda b, t: (
+            b, jnp.minimum(t, nc - 1), 0))
 
+    def behind(*block):
+        return pl.BlockSpec((1,) + block, lambda b, t: (
+            b, jnp.maximum(t - 1, 0), 0))
+
+    rows = lambda a: a.reshape(n, (s + pad) * h, -1)  # noqa: E731
+    whole = pl.BlockSpec((1, h, dk, dv), lambda b, t: (b, 0, 0, 0))
+    o, state = pl.pallas_call(
+        functools.partial(_scan_kernel, heads=heads,
+                          block=min(_BLOCK, chunk)),
+        grid=(n, nc + 1),
+        in_specs=[ahead(chunk * h, dk), ahead(chunk * h, dk),
+                  ahead(chunk * h, dk), ahead(chunk, h),
+                  behind(chunk * h, dv), behind(chunk, h), whole],
+        out_specs=[behind(chunk * h, dv), whole],
+        out_shape=[jax.ShapeDtypeStruct((n, (s + pad) * h, dv), f32),
+                   jax.ShapeDtypeStruct((n, h, dk, dv), f32)],
+        scratch_shapes=[pltpu.VMEM((h,) + shape, f32) for shape in (
+            (chunk, dk), (chunk, dk), (chunk, dk), (1, dk),
+            (chunk, chunk), (chunk, chunk))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=100 << 20),
+        interpret=interpret, name="kda_chunk_scan",
+    )(rows(q), rows(k), rows(g), beta, rows(v), beta, state.astype(f32))
+    return o.reshape(n, s + pad, h, dv)[:, :s], state
+
+
+def kda_chunked(q, k, v, g, beta, state=None, *, chunk: int = _CHUNK):
+    """A row of positions through the recurrence, one Pallas call whose
+    grid walks the chunks with the state of every head held in VMEM
+    (the module's docstring). q, k, g [N,S,H,dk], v [N,S,H,dv], beta
+    [N,S,H]; ``state`` [N,H,dk,dv] float32 (zeros when None). S need
+    not be a multiple of ``chunk``. Returns (o [N,S,H,dv] float32,
+    final state). On a v5e at 64 heads of 128, 2,048 positions with a
+    given state: 3.8 ms by the host's clock (2.7 ms of the device's
+    inside the engine's launches) where the ``lax.scan`` it replaced
+    took 6.9 (6.0 of the device's); chunk, block and heads by the table
+    of PERF.md section 6, PR 34."""
     if state is None:
-        state = jnp.zeros((n, h, dk, dv), f32)
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-
-    def body(s0, xs):
-        qc, kc, vc, gc, bc = (a.astype(f32) for a in xs)
-        bc = bc[..., 0]                                # [N,H,C]
-        big = jnp.cumsum(gc, axis=2)                   # G, [N,H,C,dk]
-        # decay from position j to position i >= j, a key channel;
-        # masked BEFORE the exponential so that none is positive
-        diff = big[:, :, :, None, :] - big[:, :, None, :, :]
-        decay = jnp.exp(jnp.where(lower[:, :, None], diff, -jnp.inf))
-        kj = kc[:, :, None, :, :] * decay
-        kk = jnp.sum(kc[:, :, :, None, :] * kj, axis=-1)
-        qk = jnp.sum(qc[:, :, :, None, :] * kj, axis=-1)
-        a = jnp.where(strict, bc[..., None] * kk, 0.0)
-        e = jnp.exp(big)
-        rhs = bc[..., None] * (vc - jnp.einsum(
-            "nhck,nhkv->nhcv", kc * e, s0, precision=_HI))
-        u = jax.lax.linalg.triangular_solve(
-            a + jnp.eye(chunk, dtype=f32), rhs, left_side=True,
-            lower=True, unit_diagonal=True)
-        o = (jnp.einsum("nhck,nhkv->nhcv", qc * e, s0, precision=_HI)
-             + jnp.einsum("nhij,nhjv->nhiv", qk, u, precision=_HI))
-        last = big[:, :, -1:, :]                       # G_C
-        s1 = (jnp.exp(last[:, :, 0, :, None]) * s0
-              + jnp.einsum("nhck,nhcv->nhkv", kc * jnp.exp(last - big),
-                           u, precision=_HI))
-        return s1, o
-
-    state, o = jax.lax.scan(
-        body, state.astype(f32),
-        (chunks(q), chunks(k), chunks(v), chunks(g),
-         chunks(beta[..., None])))
-    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2)      # [N,nc,C,H,dv]
-    return o.reshape(n, nc * chunk, h, dv)[:, :s], state
+        n, _s, h, dk = q.shape
+        state = jnp.zeros((n, h, dk, v.shape[-1]), jnp.float32)
+    return _chunk_scan(q, k, v, g, beta, state, chunk=chunk,
+                       interpret=jax.default_backend() != "tpu")

@@ -350,20 +350,35 @@ def test_dense_decode_chunk(chip, monkeypatch, capsys, num_pages,
     assert m.temp_size_in_bytes < relaid + (8 << 20)
 
 
-def test_kda_chunk_scan(chip):
+def test_kda_chunk_scan(chip, monkeypatch, capsys):
     """One segment of a delta-rule layer at the published widths of the
     second served family (64 heads, key and value width 128): 2,048
-    positions through the chunkwise scan. The pairwise decay of a chunk
-    ([64 heads, 16, 16, 128] float32) lives inside the scan's body and
-    nowhere else."""
+    positions through the chunk scan. It is ONE Pallas call under its
+    own name whose grid walks the chunks with the state in VMEM: no
+    ``while`` over chunks is left for XLA, the operands are read in the
+    [N,S,H,*] layout they arrive in (no chunk-major float32 copy of a
+    segment beside it), and what a chunk needs (the pairwise decay of
+    its diagonal blocks, the triangular inverse) lives inside the
+    kernel."""
     from ray_tpu.ops.kda import kda_chunked
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     qk = chip((1, 2048, 64, 128), jnp.float32)
     compiled, text = _compile(
         kda_chunked, qk, qk, qk, qk, chip((1, 2048, 64), jnp.float32),
         chip((1, 64, 128, 128), jnp.float32))
-    assert "while" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    m = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[kda chunk scan, 2,048 positions, 64 heads of 128] "
+              f"temporaries {m.temp_size_in_bytes / 1e6:.1f} MB")
+    assert text.count("tpu_custom_call") == 1 and "kda_chunk_scan" in text
+    assert "while" not in text
+    # the scan it replaced re-laid q, k, v, g and beta out chunk-major
+    assert "[128,1,64,16,128]" not in text and "[32,1,64,64,128]" not in text
+    # the scan held 2 GiB's bound; the kernel's operands are the
+    # caller's own and its output is written in place: no segment-sized
+    # copy (67 MB each) is left
+    assert m.temp_size_in_bytes < 64 << 20
 
 
 @pytest.mark.parametrize("tokens", [64, 8192])

@@ -13,24 +13,24 @@ from ray_tpu.ops import kda  # noqa: E402
 H, DK, DV = 3, 8, 6
 
 
-def inputs(seed, n, s, g_scale=3.0, beta_max=2.0):
+def inputs(seed, n, s, g_scale=3.0, beta_max=2.0, h=H):
     rng = np.random.default_rng(seed)
     f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
-    q = kda.l2norm(f(n, s, H, DK)) / np.sqrt(DK)
-    k = kda.l2norm(f(n, s, H, DK))
-    v = f(n, s, H, DV)
-    g = -jnp.asarray(rng.uniform(0, g_scale, (n, s, H, DK)), jnp.float32)
-    beta = jnp.asarray(rng.uniform(0, beta_max, (n, s, H)), jnp.float32)
+    q = kda.l2norm(f(n, s, h, DK)) / np.sqrt(DK)
+    k = kda.l2norm(f(n, s, h, DK))
+    v = f(n, s, h, DV)
+    g = -jnp.asarray(rng.uniform(0, g_scale, (n, s, h, DK)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0, beta_max, (n, s, h)), jnp.float32)
     return q, k, v, g, beta
 
 
 def recurrence(q, k, v, g, beta, state=None):
     """Token by token, float64: the definition."""
     q, k, v, g, beta = (np.asarray(a, np.float64) for a in (q, k, v, g, beta))
-    n, s = q.shape[:2]
-    st = (np.zeros((n, H, DK, DV)) if state is None
+    n, s, h = q.shape[:3]
+    st = (np.zeros((n, h, DK, DV)) if state is None
           else np.asarray(state, np.float64))
-    out = np.zeros((n, s, H, DV))
+    out = np.zeros((n, s, h, DV))
     for t in range(s):
         st = st * np.exp(g[:, t])[..., None]
         u = beta[:, t][..., None] * (
@@ -109,6 +109,62 @@ def test_a_given_state_is_carried_on():
     ro, rst = recurrence(q, k, v, g, beta, s0)
     np.testing.assert_allclose(np.asarray(o), ro, atol=5e-6)
     np.testing.assert_allclose(np.asarray(st), rst, atol=5e-6)
+
+
+@pytest.mark.parametrize("s", [1, 5, 13])
+def test_a_row_shorter_than_one_chunk(s):
+    """The kernel's own chunk (64): the row is padded with no-ops."""
+    x = inputs(40 + s, 2, s)
+    o, st = kda.kda_chunked(*x)
+    ro, rst = recurrence(*x)
+    np.testing.assert_allclose(np.asarray(o), ro, atol=5e-6)
+    np.testing.assert_allclose(np.asarray(st), rst, atol=5e-6)
+
+
+@pytest.mark.parametrize("lens", [(96, 33, 1), (64, 65, 128), (7, 100, 63)])
+def test_rows_of_different_lengths_in_one_call(lens):
+    """N > 1 rows at the kernel's own chunk, each with its own length
+    and its own given state: a row's chunks past its length (whole
+    no-op chunks among them) leave its state alone."""
+    s = 128
+    q, k, v, g, beta = inputs(sum(lens), 3, s)
+    s0 = jnp.asarray(np.random.default_rng(1).normal(size=(3, H, DK, DV)),
+                     jnp.float32)
+    gm, bm = kda.pad_mask(g, beta, jnp.asarray(lens))
+    o, st = kda.kda_chunked(q, k, v, gm, bm, s0)
+    for n, ln in enumerate(lens):
+        ro, rst = recurrence(*(a[n:n + 1, :ln] for a in (q, k, v, g, beta)),
+                             s0[n:n + 1])
+        np.testing.assert_allclose(np.asarray(o)[n, :ln], ro[0], atol=5e-6)
+        np.testing.assert_allclose(np.asarray(st)[n], rst[0], atol=5e-6)
+
+
+@pytest.mark.parametrize("g_scale", [0.02, 1.0])
+def test_a_segment_of_the_engine_at_four_heads(g_scale):
+    """2,048 positions, the engine's segment, through 32 chunks of the
+    kernel with the state carried in its scratch from the first to the
+    last; the slow decay keeps the early chunks' state alive to the
+    end."""
+    x = inputs(2048, 1, 2048, g_scale=g_scale, h=4)
+    o, st = kda.kda_chunked(*x)
+    ro, rst = recurrence(*x)
+    np.testing.assert_allclose(np.asarray(o), ro, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(st), rst, atol=2e-5)
+
+
+@pytest.mark.parametrize("h", [3, 5])
+def test_heads_are_read_where_they_lie(h):
+    """[N,S,H,*] goes to the kernel as it is, a head's rows H apart: a
+    head count that is no whole tile gives each head what it gets
+    alone."""
+    x = inputs(70 + h, 2, 70, h=h)
+    o, st = kda.kda_chunked(*x)
+    for i in range(h):
+        oi, sti = kda.kda_chunked(*(a[:, :, i:i + 1] for a in x))
+        np.testing.assert_allclose(np.asarray(o)[:, :, i:i + 1],
+                                   np.asarray(oi), atol=1e-6)
+        np.testing.assert_allclose(np.asarray(st)[:, i:i + 1],
+                                   np.asarray(sti), atol=1e-6)
 
 
 def test_short_convolution_and_its_tail():
