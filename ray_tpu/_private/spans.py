@@ -9,12 +9,19 @@ taken where the work happens. They go into ONE ring for the whole
 process, bounded, oldest dropped first, because whoever reads them (an
 operator's debugger, the benchmark's readers) comes after the object
 that wrote them is gone. Always on: a writer pays one tuple append and
-one ``TraceAnnotation`` a span, so it records a span per burst, launch
-or request, never per token or step.
+one ``TraceAnnotation`` a span, so it records a span per burst, launch,
+request or replica call, never per token or step. Who writes what: the
+inference engine its loop's rounds, prefill launches and requests
+(``engine.*``), a serve replica one ``replica.call`` a call it ran
+(``serve/core.py``), under the ``ident`` of the engine request the call
+served where the deployment named one.
 
 ``span`` is also a ``jax.profiler.TraceAnnotation``: while a profile is
 being taken the same span stands on the profiler's clock beside the
-device's operations.
+device's operations. ``record`` is not (its ends are known only once it
+is over), and jax is imported by the first ``span`` alone, so a replica
+whose deployment never touches jax does not load it for its calls'
+records.
 """
 
 from __future__ import annotations
@@ -22,8 +29,6 @@ from __future__ import annotations
 import collections
 import time
 from typing import Any, List, Optional, Tuple
-
-from jax.profiler import TraceAnnotation
 
 Span = Tuple[str, float, float, Any, Any, dict]
 
@@ -41,6 +46,8 @@ class span:
         self.name, self.ident, self.fields = name, ident, fields
 
     def __enter__(self) -> "span":
+        from jax.profiler import TraceAnnotation
+
         self._note = TraceAnnotation(self.name, **self.fields)
         self._note.__enter__()
         self.t0 = time.perf_counter()
@@ -52,10 +59,10 @@ class span:
         _RING.append((self.name, self.t0, t1, self.ident, None, self.fields))
 
 
-def record(name: str, t0: float, t1: float, ident: Any = None,
+def record(name: str, t0: float, t1: float, /, ident: Any = None,
            parent: Optional[tuple] = None, **fields: Any) -> None:
-    """A span whose ends lie in different threads: the caller took both
-    times itself."""
+    """A span whose ends the caller took itself: they lie in different
+    threads, or what the span says is known only once it is over."""
     _RING.append((name, t0, t1, ident, parent, fields))
 
 

@@ -100,11 +100,16 @@ _STREAM_END = object()
 
 class TokenStream:
     """Iterator over tokens as the engine produces them (per sync
-    burst), plus the final-list future for callers that want both."""
+    burst), plus the final-list future for callers that want both.
+    ``ident`` is the number of the engine's request, which its spans
+    carry. The queue holds one ``(perf_counter at the hand-out,
+    tokens)`` a hand-out, then ``_STREAM_END`` or the exception that
+    ended the request."""
 
-    def __init__(self, future: Future):
+    def __init__(self, future: Future, ident: int):
         self._q: "queue.Queue" = queue.Queue()
         self.future = future
+        self.ident = ident
 
     def __iter__(self):
         while True:
@@ -113,7 +118,7 @@ class TokenStream:
                 return
             if isinstance(item, BaseException):
                 raise item
-            yield from item  # one burst's new tokens
+            yield from item[1]  # one burst's new tokens
 
     def result(self, timeout: Optional[float] = None) -> List[int]:
         return self.future.result(timeout)
@@ -121,7 +126,8 @@ class TokenStream:
 
 class _Request:
     __slots__ = ("prompt", "max_new", "future", "out", "emitted", "stream",
-                 "streamed", "kv", "ident", "parent", "t_mark", "got_first")
+                 "streamed", "handouts", "kv", "ident", "parent", "t_mark",
+                 "got_first")
 
     def __init__(self, prompt: List[int], max_new: int, ident: int):
         self.prompt = prompt
@@ -138,17 +144,18 @@ class _Request:
         self.out: List[int] = []   # tokens synced to host
         self.emitted = 0           # tokens produced on device (>= len(out))
         self.stream: Optional[TokenStream] = None
-        self.streamed = 0          # tokens already pushed to the stream
+        self.streamed = 0          # tokens already handed out
+        self.handouts = 0          # times _hand_out had tokens to give
         # disaggregated handoff: (k [L,S,KV,D], v, first_token) host
         # arrays from a prefill replica's export; admission imports the
         # pages instead of running the prompt pass
         self.kv: Optional[Tuple[Any, Any, int]] = None
 
-    def end_span(self, name: str) -> None:
-        """The request's running span ends now, under ``name``, and the
-        next begins."""
-        now = time.perf_counter()
-        spans.record(name, self.t_mark, now, self.ident, self.parent)
+    def end_span(self, name: str, now: float, **fields: Any) -> None:
+        """The request's running span ends at ``now``, under ``name``
+        and with what it covered, and the next begins."""
+        spans.record(name, self.t_mark, now, ident=self.ident,
+                     parent=self.parent, **fields)
         self.t_mark = now
 
 
@@ -400,7 +407,7 @@ class InferenceEngine:
                 f"needs the monolithic engine")
         max_new = self._validate(prompt, max_new_tokens)
         req = _Request(list(prompt), max_new, next(self._idents))
-        stream = TokenStream(req.future)
+        stream = TokenStream(req.future, req.ident)
         req.stream = stream
         self._queue.put(req)
         self._wake.set()
@@ -452,7 +459,7 @@ class InferenceEngine:
             else max_new_tokens)
         req = _Request(prompt, max_new, next(self._idents))
         req.kv = (kv["k"], kv["v"], int(kv["first_token"]))
-        stream = TokenStream(req.future)
+        stream = TokenStream(req.future, req.ident)
         req.stream = stream
         if not emit_first:
             req.streamed = 1
@@ -625,7 +632,8 @@ class InferenceEngine:
             free_slot.pages = pages
             free_slot.seq_len = plen
             req.emitted = 1
-            req.end_span("engine.queue")
+            req.end_span("engine.queue", time.perf_counter(),
+                         prompt_tokens=plen, max_new=req.max_new)
             (imports if req.kv is not None else admits).append(
                 (free_slot, req, pages))
         for slot, req, pages in imports:
@@ -732,20 +740,26 @@ class InferenceEngine:
         self._hand_out(req)
 
     def _hand_out(self, req: _Request) -> None:
-        """Push what ``req.out`` has gained onto its stream, and close
-        the request's spans as it passes their ends."""
-        if req.stream is not None:
-            new = req.out[req.streamed:]
-            if new:
-                req.stream._q.put(new)
-            req.streamed += len(new)
-            if req.future.done():
-                req.stream._q.put(_STREAM_END)
+        """Push what ``req.out`` has gained onto its stream, stamped
+        with the time of this hand-out, and close the request's spans
+        as it passes their ends: the clock is read once, BEFORE the
+        put, so a span's end is its hand-out's stamp and no reader of
+        the stream can find a token younger than nothing."""
+        now = time.perf_counter()
+        new = len(req.out) - req.streamed
+        if new > 0:
+            req.handouts += 1
+            if req.stream is not None:
+                req.stream._q.put((now, req.out[req.streamed:]))
+            req.streamed += new
+        if req.stream is not None and req.future.done():
+            req.stream._q.put(_STREAM_END)
         if not req.got_first and req.out:
             req.got_first = True
-            req.end_span("engine.first_token")
+            req.end_span("engine.first_token", now, tokens=len(req.out))
         if req.future.done():
-            req.end_span("engine.decode")
+            req.end_span("engine.decode", now, tokens=len(req.out),
+                         handouts=req.handouts)
 
     def _maybe_finish(self, slot: _Slot) -> None:
         req = slot.req

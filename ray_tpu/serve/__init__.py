@@ -12,13 +12,15 @@ JSON HTTP ingress.
 from ray_tpu.serve.core import (AdmissionShedError,  # noqa: F401
                                 Application, AutoscalingConfig,
                                 Deployment, DeploymentHandle, deployment,
-                                get_app_handle, get_multiplexed_model_id,
-                                multiplexed, run, serving_stats, shutdown,
-                                start_grpc, start_http, status)
+                                get_app_handle, get_call_span_fields,
+                                get_multiplexed_model_id, multiplexed, run,
+                                serving_stats, shutdown, start_grpc,
+                                start_http, status)
 
 __all__ = [
     "deployment", "run", "shutdown", "status", "get_app_handle",
     "Deployment", "DeploymentHandle", "Application", "start_http",
     "AutoscalingConfig", "multiplexed", "get_multiplexed_model_id",
-    "start_grpc", "AdmissionShedError", "serving_stats",
+    "get_call_span_fields", "start_grpc", "AdmissionShedError",
+    "serving_stats",
 ]

@@ -8,13 +8,15 @@ handle.py (DeploymentHandle), _private/http_proxy.py (ingress).
 from __future__ import annotations
 
 import json
+import os
 import random
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import ray_tpu
 from ray_tpu import exceptions as rex
+from ray_tpu._private import spans, trace_plane
 
 _lock = threading.Lock()
 _controller: Optional["_Controller"] = None
@@ -142,6 +144,29 @@ def get_multiplexed_model_id() -> Optional[str]:
     return _current_model_id.get()
 
 
+# what the deployment says of the CALL being handled: the fields of the
+# ``replica.call`` span that _Replica.handle_request records when the
+# call ends
+_current_call_fields: "contextvars.ContextVar" = contextvars.ContextVar(
+    "ray_tpu_serve_call_fields", default=None)
+
+
+def get_call_span_fields() -> Dict[str, Any]:
+    """Inside a deployment method: the dict whose entries become fields
+    of this call's ``replica.call`` span (``_private/spans.py``);
+    ``ident`` names the request the call served, so that the span joins
+    the spans that request left elsewhere (an engine's ``engine.queue``
+    and so on). Outside a replica call: a dict that nothing reads."""
+    fields = _current_call_fields.get()
+    return {} if fields is None else fields
+
+
+def _routed_now() -> Tuple[int, float]:
+    """The router's stamp on a call it hands to a replica: this process
+    and its ``perf_counter``, which another process cannot read."""
+    return os.getpid(), time.perf_counter()
+
+
 def _with_model_id(gen, model_id):
     """Re-enter the multiplexed-model-id contextvar around each step of
     a streaming response, preserving laziness (see _Replica.handle_request)."""
@@ -262,15 +287,27 @@ class _Replica:
             hook()
 
     def handle_request(self, method: str, args, kwargs,
-                       model_id: Optional[str] = None):
-        target = (self.instance if method == "__call__"
-                  else getattr(self.instance, method))
-        if method == "__call__" and not callable(target):
-            raise TypeError("deployment is not callable; use "
-                            "handle.<method>.remote()")
-        fn = target if method != "__call__" else self.instance.__call__
+                       model_id: Optional[str] = None,
+                       routed: Optional[Tuple[int, float]] = None):
+        """Run one call of the deployment and leave its ``replica.call``
+        span: from here, on a replica thread, until the method returned
+        or raised. ``routed`` is the router's ``_routed_now()``; how
+        long the call waited for this thread (``waited_ms``) is written
+        only where that clock is this process's own."""
+        fields: Dict[str, Any] = {"method": method}
+        call_token = _current_call_fields.set(fields)
         token = _current_model_id.set(model_id)
+        t0 = time.perf_counter()
+        if routed is not None and routed[0] == os.getpid():
+            fields.update(t_routed=routed[1],
+                          waited_ms=1e3 * (t0 - routed[1]))
         try:
+            target = (self.instance if method == "__call__"
+                      else getattr(self.instance, method))
+            if method == "__call__" and not callable(target):
+                raise TypeError("deployment is not callable; use "
+                                "handle.<method>.remote()")
+            fn = target if method != "__call__" else self.instance.__call__
             result = fn(*args, **kwargs)
             import inspect as _inspect
             if _inspect.isgenerator(result):
@@ -286,7 +323,11 @@ class _Replica:
                 result = _with_model_id(result, model_id)
             return result
         finally:
+            t1 = time.perf_counter()
             _current_model_id.reset(token)
+            _current_call_fields.reset(call_token)
+            fields.setdefault("parent", trace_plane.current_parent())
+            spans.record("replica.call", t0, t1, **fields)
 
 
 class _ReplicaState:
@@ -649,8 +690,8 @@ class _DeploymentState:
                model_id: Optional[str] = None):
         state = self._pick(model_id)
         try:
-            ref = state.actor.handle_request.remote(method, args, kwargs,
-                                                    model_id)
+            ref = state.actor.handle_request.remote(
+                method, args, kwargs, model_id, _routed_now())
         except rex.ActorError:
             # replica died: release the reservation, replace it, retry
             # once on another
@@ -699,7 +740,8 @@ class _DeploymentState:
                         "sticky session's replica is gone")
                 state.ongoing += 1
         try:
-            ref = state.actor.handle_request.remote(method, args, kwargs)
+            ref = state.actor.handle_request.remote(
+                method, args, kwargs, None, _routed_now())
         except rex.ActorError:
             with self._lock:
                 state.ongoing = max(0, state.ongoing - 1)

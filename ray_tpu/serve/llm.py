@@ -83,24 +83,34 @@ class LLMDeployment:
             if now - last > self._STREAM_TTL_S:
                 self._streams.pop(sid, None)
 
-    def _register_stream(self, stream) -> str:
+    def _register_stream(self, stream, prompt_tokens: int,
+                         max_new: Optional[int]) -> str:
+        """Keep the stream under a new id, and say in this call's
+        ``replica.call`` span which engine request it opened."""
         import uuid
 
         sid = uuid.uuid4().hex
         self._streams[sid] = (stream, time.monotonic())
+        core.get_call_span_fields().update(
+            ident=stream.ident, stream=sid, prompt_tokens=prompt_tokens,
+            max_new=(self._engine.cfg.max_new_tokens if max_new is None
+                     else int(max_new)))
         return sid
 
     def start_stream(self, prompt: Sequence[int],
                      max_new_tokens: Optional[int] = None) -> str:
         self._sweep_streams()
         stream = self._engine.submit_stream(list(prompt), max_new_tokens)
-        return self._register_stream(stream)
+        return self._register_stream(stream, len(prompt), max_new_tokens)
 
     def next_tokens(self, stream_id: str,
                     timeout: float = 60.0) -> Dict[str, Any]:
         """Block until at least one token (or completion) is available,
         then drain everything currently buffered. Returns
-        {"tokens": [...], "done": bool}."""
+        {"tokens": [...], "done": bool}. Its ``replica.call`` span says
+        how long it was blocked (``blocked_ms``) and how old, when it
+        returns, the oldest token it returns is (``held_ms``, from the
+        engine's hand-out; None where it returns none)."""
         import queue as _q
 
         # sweep here too: a poll-only workload (clients that joined
@@ -116,8 +126,12 @@ class LLMDeployment:
 
         tokens: List[int] = []
         done = False
+        handed_out: Optional[float] = None   # the oldest token's stamp
+        t_poll = time.perf_counter()
+        t_got = None
         try:
             item = stream._q.get(timeout=timeout)
+            t_got = time.perf_counter()
             while True:
                 if isinstance(item, BaseException):
                     # a dead stream must not keep polling as alive
@@ -126,10 +140,18 @@ class LLMDeployment:
                 if item is None or item is _STREAM_END:
                     done = True
                     break
-                tokens.extend(item)
+                if handed_out is None:
+                    handed_out = item[0]
+                tokens.extend(item[1])
                 item = stream._q.get_nowait()
         except _q.Empty:
             pass
+        now = time.perf_counter()
+        core.get_call_span_fields().update(
+            ident=stream.ident, tokens=len(tokens), done=done,
+            blocked_ms=1e3 * ((t_got or now) - t_poll),
+            held_ms=(None if handed_out is None
+                     else 1e3 * (now - handed_out)))
         if done:
             self._streams.pop(stream_id, None)
         return {"tokens": tokens, "done": done}
@@ -247,7 +269,10 @@ class DecodeDeployment(LLMDeployment._cls):  # the undecorated class
             kv, max_new_tokens, emit_first=emit_first)
         if session_id is not None:
             self._cache_kv(session_id, kv)
-        return self._register_stream(stream)
+        return self._register_stream(stream, len(kv["prompt"]),
+                                     kv.get("max_new")
+                                     if max_new_tokens is None
+                                     else max_new_tokens)
 
     def start_stream_cached(self, session_id: str, prompt: Sequence[int],
                             max_new_tokens: Optional[int] = None
@@ -268,7 +293,7 @@ class DecodeDeployment(LLMDeployment._cls):  # the undecorated class
         stream = self._engine.submit_stream_from_kv(
             entry, resolved, emit_first=True)
         self.cached_replays += 1
-        return {"sid": self._register_stream(stream),
+        return {"sid": self._register_stream(stream, len(prompt), resolved),
                 "max_new": int(resolved)}
 
     def engine_stats(self) -> Dict[str, Any]:

@@ -329,6 +329,14 @@ def test_ring_grows_with_bursts_launches_and_requests_not_with_tokens(
         assert len(records) == (4 * stats["bursts"]
                                 + stats["prefill_launches"]
                                 + 3 * len(prompts))
+        # what the request spans say they cover grows with the answer,
+        # their number and the hand-outs they count do not (a replica's
+        # polls follow the hand-outs: test_request_path_spans.py)
+        decodes = named(records, "engine.decode")
+        assert [r[5]["tokens"] for r in decodes] == [max_new] * 3
+        assert all(1 <= r[5]["handouts"] <= 2 for r in decodes)
+        assert all(1 <= r[5]["tokens"] <= max_new
+                   for r in named(records, "engine.first_token"))
 
 
 @contextlib.contextmanager
