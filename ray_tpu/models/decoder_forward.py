@@ -319,7 +319,8 @@ def _decode_delta_rule(p, cfg: DecoderConfig, h, kept, page_table,
                        seq_lens, live):
     """One position a slot: h [B,Dm] (normed), ``kept`` the layer's
     (state, tail). Slots that are not ``live`` keep their state and
-    tail as they are."""
+    tail as they are: the kernel reads and writes the live slots'
+    state alone, in place."""
     a = p["DeltaRule_0"]
     state, tail = kept
     with jax.named_scope("kda"):
@@ -329,10 +330,9 @@ def _decode_delta_rule(p, cfg: DecoderConfig, h, kept, page_table,
         q, k, v, g, beta = _delta_rule_inputs(
             a, cfg, h, jax.nn.silu(mixed).reshape(
                 h.shape[0], cfg.dr_heads, cfg.dr_channels))
-        o, new_state = kda.kda_step(q, k, v, g, beta, state)
+        o, state = kda.kda_decode_step(q, k, v, g, beta, state, live)
         out = _delta_rule_output(a, cfg, h, o)
     with jax.named_scope("kda_state"):
-        state = jnp.where(live[:, None, None, None], new_state, state)
         tail = jnp.where(live[:, None, None], new_tail.astype(tail.dtype),
                          tail)
     return out, (state, tail)

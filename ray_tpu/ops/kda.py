@@ -10,9 +10,16 @@ with key ``k`` (unit length), value ``v``, query ``q``, log decay
     S  = S' + beta * k (v - k^T S')^T      # (I - beta k k^T) S' + beta k v^T
     o  = S^T q
 
-Two forms of the same function:
+Three forms of the same function:
 
-- ``kda_step``: one position for every slot of a decode batch.
+- ``kda_step``: one position for every slot of a decode batch, in XLA:
+  the plain recurrence the tests hold the kernels to.
+- ``kda_decode_step``: one position for the LIVE slots of a decode
+  batch, one Pallas call that reads and writes those slots' state in
+  place and touches no other slot's. What it asks of its caller: under
+  jit on a TPU the state is donated (or carried from a donated one);
+  ``live`` has one entry a slot of the state, so every slot id the
+  kernel derives from it lies inside the state.
 - ``kda_chunked``: a whole prompt, a chunk of positions at a time. Inside
   a chunk the updates are a unit lower-triangular system (the WY form):
   with ``G_t`` the decay accumulated from the chunk's start,
@@ -123,6 +130,101 @@ def kda_step(q, k, v, g, beta, state):
                                           precision=_HI))
     s = s + k[..., None] * u[..., None, :]
     return jnp.einsum("bhk,bhkv->bhv", q, s, precision=_HI), s
+
+
+# heads of one slot a grid step of ``kda_decode_step`` holds: a block
+# of 32 heads' state is 2 MB at 128 x 128, read and written in 5.1 us
+# at the v5e's 819 GB/s, against ~4,600 bundles of the body (bound
+# by the cross-lane unit, which broadcasts q, k and exp(g) along the
+# value axis), which the pipeline lays under the DMAs; 16 and 64 heads
+# measured no better (PERF.md section 6, PR 36)
+_DECODE_HEADS = 32
+
+
+def _decode_kernel(ids_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref,
+                   o_ref, s_out_ref):
+    """Grid step (i, j): heads block j of the i-th live slot through one
+    position, a head at a time on the vector unit in float32. q, k and
+    g come as rows [h, dk] and are wanted as columns along the state's
+    key axis, so they are transposed once a step."""
+    del ids_ref   # read by the index maps
+    qt, kt = q_ref[0].T, k_ref[0].T                   # [dk, h]
+    dec = jnp.exp(g_ref[0].T)
+    v, beta = v_ref[0], beta_ref[0, 0]                # [h, dv], [1, h]
+    rows = []
+    for h in range(s_ref.shape[1]):
+        s = s_ref[0, h] * dec[:, h:h + 1]
+        kc = kt[:, h:h + 1]
+        u = beta[:, h:h + 1] * (
+            v[h:h + 1] - jnp.sum(kc * s, 0, keepdims=True))
+        s = s + kc * u
+        s_out_ref[0, h] = s
+        rows.append(jnp.sum(qt[:, h:h + 1] * s, 0, keepdims=True))
+    o_ref[0] = jnp.concatenate(rows, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def _decode_step(q, k, v, g, beta, state, live, *, heads: int,
+                 interpret: bool):
+    """``kda_decode_step``. Jitted, so that the programs that call it
+    with the same shapes (a decode program's layers, the engine's chunk
+    sizes) trace the kernel once between them."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    b, h, dk, dv = state.shape
+    # a block of heads is whole sublane tiles of q, k, v, g, or all
+    hb = next(m for m in range(min(heads, h), 0, -1)
+              if h % m == 0 and (m == h or m % 8 == 0))
+    nh = h // hb
+    ids = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
+    n = jnp.sum(live, dtype=jnp.int32)
+
+    def spec(*block):
+        return pl.BlockSpec((1,) + block, lambda i, j, ids: (
+            ids[i], j) + (0,) * (len(block) - 1))
+
+    o, state = pl.pallas_call(
+        _decode_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n, nh),
+            in_specs=[spec(hb, dk), spec(hb, dk), spec(hb, dv), spec(hb, dk),
+                      spec(1, 1, hb), spec(hb, dk, dv)],
+            out_specs=[spec(hb, dv), spec(hb, dk, dv)]),
+        out_shape=[jax.ShapeDtypeStruct((b, h, dv), f32),
+                   pltpu.HBM(state.shape, f32)],
+        # operands count the prefetched ids
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+        interpret=interpret, name="kda_decode_step",
+    )(ids, *(a.astype(f32) for a in (q, k, v, g)),
+      beta.astype(f32).reshape(b, nh, 1, hb), state.astype(f32))
+    return jnp.where(live[:, None, None], o, 0.0), state
+
+
+def kda_decode_step(q, k, v, g, beta, state, live):
+    """``kda_step`` for the ``live`` [B] bool slots alone, the state
+    updated where it lies: one Pallas call (``kda_decode_step`` in a
+    trace) whose grid runs over (live slot, block of heads). The grid's
+    first bound is the live count, a traced scalar, and the live slots'
+    ids, compacted to the front, ride scalar prefetch: the state block
+    a step reads and writes is chosen through the id, and the state is
+    input and output of one buffer (``input_output_aliases``). A dead
+    slot has no grid step, so its state is neither read nor written.
+    Returns (o [B,H,dv] float32, zeros in the dead slots' rows; state).
+
+    What the caller owes, as for ``paged_attention.append_token``:
+    under jit on a TPU the state is donated, or carried from a donated
+    one, as in the engine's decode programs (the output, and through
+    the alias the input, are pinned to HBM; not donated, the compiler
+    copies the state first, and where the copy fits VMEM its memory
+    assignment aborts). Off the TPU the kernel runs in interpret
+    mode."""
+    return _decode_step(q, k, v, g, beta, state, live, heads=_DECODE_HEADS,
+                        interpret=jax.default_backend() != "tpu")
 
 
 # positions a grid step takes through the three state products, the

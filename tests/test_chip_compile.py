@@ -200,11 +200,11 @@ def test_engine_prefill_program(chip, bucket, rows, capsys):
     assert m.temp_size_in_bytes < 1 << 30
 
 
-def _pool_shaped(text, pool_dims):
+def _pool_shaped(text, pool_dims, dtype="bf16"):
     """The instructions of a compiled program's text whose result, or
-    one element of whose result tuple, has the bfloat16 pool's shape:
-    (name, opcode, the line)."""
-    shape = f"bf16[{','.join(map(str, pool_dims))}]"
+    one element of whose result tuple, has the pool's shape (bfloat16
+    unless ``dtype`` says otherwise): (name, opcode, the line)."""
+    shape = f"{dtype}[{','.join(map(str, pool_dims))}]"
     found = []
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
@@ -476,7 +476,8 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
               f"{m.temp_size_in_bytes / 1e9:.3f} GB, aliased "
               f"{m.alias_size_in_bytes / 1e9:.3f} GB")
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15e9
-    # pool and state are donated and updated in place
+    # pool and state are donated and updated in place (2.15 GB of
+    # pages, 0.81 GB of the delta-rule layers' state)
     assert m.alias_size_in_bytes > 2.9e9
     # the append writes its cells into the pool where it lies: no copy
     # of a pool in the text, nothing pool-shaped out of a fusion under
@@ -485,6 +486,18 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
     # analysis, not a chip reading)
     _no_pool_moves(text, (4097, 8, page, hd))
     assert m.temp_size_in_bytes < 1.9e9
+    # each delta-rule layer's step is the one kernel that reads and
+    # writes the live slots' state in place: nothing else yields a
+    # state, no fusion (the parent's `where` over all 64 slots, 0.85 ms
+    # a step and layer on the chip) and no copy
+    kernels = _attention_kernels(text, "kda")
+    assert len(kernels) == 3
+    assert all("kda_decode_step" in line for line in kernels)
+    moved = [(n, op) for n, op, _l in _pool_shaped(
+        text, (slots, h, hd, hd), "f32")
+        if op not in ("parameter", "get-tuple-element", "tuple", "while")]
+    assert moved and all(op == "custom-call" and n.startswith(
+        "kda_decode_step") for n, op in moved), moved
 
 
 def _benchmark_config(name, **over):

@@ -167,6 +167,40 @@ def test_heads_are_read_where_they_lie(h):
                                    np.asarray(sti), atol=1e-6)
 
 
+@pytest.mark.parametrize("live,h,heads", [
+    ((), 3, None), ((4,), 3, None), ((0, 2, 5, 6), 3, None),
+    (tuple(range(7)), 3, None), ((1, 3, 6), 16, 8)])
+def test_the_decode_kernel_touches_the_live_slots_alone(live, h, heads,
+                                                        monkeypatch):
+    """``kda_decode_step`` over 7 slots, four steps in a row, against
+    ``kda_step`` followed by the ``where`` it replaced: a live slot's
+    state and output to float32's rounding, a dead slot's state bitwise
+    as it was and its output zeros. The last case takes 16 heads in two
+    blocks of 8, so a slot is two grid steps."""
+    if heads:
+        monkeypatch.setattr(kda, "_DECODE_HEADS", heads)
+    b = 7
+    mask = jnp.asarray([i in live for i in range(b)])
+    rng = np.random.default_rng(len(live) * 10 + h)
+    state = jnp.asarray(rng.normal(size=(b, h, DK, DV)), jnp.float32)
+    want = state
+    step = jax.jit(kda.kda_decode_step)
+    for t in range(4):
+        q, k, v, g, beta = (a[:, 0] for a in inputs(100 * h + t, b, 1, h=h))
+        o, new = step(q, k, v, g, beta, state, mask)
+        ro, rs = kda.kda_step(q, k, v, g, beta, want)
+        want = jnp.where(mask[:, None, None, None], rs, want)
+        dead = ~np.asarray(mask)
+        np.testing.assert_array_equal(np.asarray(new)[dead],
+                                      np.asarray(state)[dead])
+        np.testing.assert_array_equal(np.asarray(o)[dead], 0.0)
+        np.testing.assert_allclose(np.asarray(new), np.asarray(want),
+                                   atol=2e-6)
+        np.testing.assert_allclose(
+            np.asarray(o)[~dead], np.asarray(ro)[~dead], atol=2e-6)
+        state = new
+
+
 def test_short_convolution_and_its_tail():
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.normal(size=(2, 12, 5)), jnp.float32)
