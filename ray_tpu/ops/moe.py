@@ -19,8 +19,10 @@ of ALL experts with weights normalised over the picked, gated (SwiGLU)
 experts, no capacity and no dropped token. A chip is told which experts
 it holds (a range of ids) and computes the part of the result that its
 own experts give, by grouped products over the picks sorted by expert
-(``jax.lax.ragged_dot``); picks that fall on experts held elsewhere add
-nothing here. On one chip the layer runs without its exchange.
+(``grouped_matmul``: one Pallas call, ``moe_grouped`` in a trace, that
+multiplies the held experts' rows and no others); picks that fall on
+experts held elsewhere add nothing here. On one chip the layer runs
+without its exchange.
 """
 
 from __future__ import annotations
@@ -169,20 +171,139 @@ def route_topk(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int,
                                    else weights * scale)
 
 
+# ----------------------------------------------------------------------
+# the held experts' grouped products: one Pallas call a matrix
+# ----------------------------------------------------------------------
+
+# the most bytes of one expert's matrix a grid step of ``grouped_matmul``
+# holds (double-buffered, so twice this in VMEM): a whole column block
+# of the contraction, so that the consecutive row tiles of one expert
+# find it in place and each touched expert is read once a call
+_RHS_BLOCK_BYTES = 16 << 20
+
+
+def grouped_tiling(m: int, k: int, n: int, itemsize: int
+                   ) -> Tuple[int, int]:
+    """(tm, tn) of ``grouped_matmul`` for rows [m, k] against experts
+    [E, k, n] of ``itemsize`` bytes: rows a tile by the number of rows
+    (a decode step's few hundred picks, of which a handful are local,
+    take one tile a touched expert; a prompt's block of tens of
+    thousands takes tiles of 256), columns the widest divisor of ``n``
+    in lanes of 128 (or ``n`` itself) whose ``[k, tn]`` block stays
+    within ``_RHS_BLOCK_BYTES``. The contraction is never split."""
+    tm = 128 if m <= 4096 else 256
+    fits = [c for c in range(n, 0, -1) if n % c == 0
+            and (c == n or c % 128 == 0)]
+    small = [c for c in fits if k * c * itemsize <= _RHS_BLOCK_BYTES]
+    return tm, (small or fits[-1:])[0]
+
+
+def _visits(sizes, m: int, tm: int):
+    """The grid's row visits for groups of ``sizes`` [E] laid one after
+    another from row 0: each non-empty group visits every tile of
+    ``tm`` rows that holds one of its rows, in order of group, so the
+    tiles come in order too and a tile shared by two groups is visited
+    twice in a row. Rows past the last group and empty groups have no
+    visit. Returns (the number of visits, a traced scalar; group and
+    tile of each visit, [m // tm + E - 1], the most there can be; first
+    and end row of each group, [E])."""
+    e = sizes.shape[0]
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    count = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    done = jnp.cumsum(count)
+    v = jnp.arange(m // tm + e - 1, dtype=jnp.int32)
+    group = jnp.minimum(jnp.sum(done[None, :] <= v[:, None], axis=1,
+                                dtype=jnp.int32), e - 1)
+    tile = jnp.clip(first[group] + v - (done - count)[group], 0,
+                    m // tm - 1)
+    return done[-1], group, tile, starts, ends
+
+
+def _grouped_kernel(group_ref, tile_ref, start_ref, end_ref, lhs_ref,
+                    rhs_ref, out_ref):
+    """Grid step (j, i): the i-th visit's rows against column block j of
+    its group's matrix. Rows of the tile that are not the group's keep
+    what the tile holds: another group's, written on the visit before,
+    or nothing anyone reads."""
+    import jax.experimental.pallas as pl
+
+    i = pl.program_id(1)
+    g = group_ref[i]
+    row = tile_ref[i] * out_ref.shape[0] + jax.lax.broadcasted_iota(
+        jnp.int32, out_ref.shape, 0)
+    mine = (row >= start_ref[g]) & (row < end_ref[g])
+    y = jnp.dot(lhs_ref[...], rhs_ref[...],
+                preferred_element_type=jnp.float32)
+    out_ref[...] = jnp.where(mine, y, out_ref[...].astype(jnp.float32)
+                             ).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
+def _moe_grouped(lhs, rhs, sizes, *, tm: int, tn: int, interpret: bool):
+    """``grouped_matmul`` at a static tiling. Jitted, so that the three
+    matrices of every expert layer of every program that calls it with
+    the same shapes trace the kernel once between them."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (m, k), n = lhs.shape, rhs.shape[2]
+    visits, group, tile, starts, ends = _visits(sizes, m, tm)
+    return pl.pallas_call(
+        _grouped_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(n // tn, visits),
+            in_specs=[
+                pl.BlockSpec((tm, k), lambda j, i, g, t, s, e: (t[i], 0)),
+                pl.BlockSpec((None, k, tn),
+                             lambda j, i, g, t, s, e: (g[i], 0, j))],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda j, i, g, t, s, e: (t[i], j))),
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=100 << 20),
+        interpret=interpret, name="moe_grouped",
+    )(group, tile, starts, ends, lhs, rhs)
+
+
+def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray, sizes: jnp.ndarray
+                   ) -> jnp.ndarray:
+    """``jax.lax.ragged_dot(lhs, rhs, sizes)`` on the rows that belong to
+    a group: lhs [m, k] holds group 0's ``sizes[0]`` rows, then group
+    1's, ...; rhs [E, k, n]; the result [m, n] is in lhs's type, the
+    products accumulated in float32. Rows past ``sum(sizes)`` belong to
+    no group and are not computed: what the result holds there is
+    undefined, and the caller masks them. One Pallas call (``moe_grouped``
+    in a trace) whose grid's bound is the number of row tiles that hold
+    a group's rows, a traced scalar; a touched group's ``[k, tn]`` block
+    is fetched once for all of its tiles, so a call reads the touched
+    experts' matrices once and no other. Off the TPU the kernel runs in
+    interpret mode."""
+    m, k = lhs.shape
+    tm, tn = grouped_tiling(m, k, rhs.shape[2], lhs.dtype.itemsize)
+    pad = -m % tm
+    out = _moe_grouped(jnp.pad(lhs, ((0, pad), (0, 0))) if pad else lhs,
+                       rhs, sizes, tm=tm, tn=tn,
+                       interpret=jax.default_backend() != "tpu")
+    return out[:m] if pad else out
+
+
 def _experts_held_block(x, ids, weights, valid, w_gate, w_up, w_down,
                         lo: int):
     t, k = ids.shape
     e = w_gate.shape[0]
     local = (ids >= lo) & (ids < lo + e) & valid[:, None]
     # a pick on an expert held elsewhere goes to group e, which sorts
-    # last and which the grouped product never reaches
+    # last and which the grouped products never reach
     group = jnp.where(local, ids - lo, e).reshape(-1)
     order = jnp.argsort(group, stable=True)
     sizes = jnp.zeros((e + 1,), jnp.int32).at[group].add(1)[:e]
     rows = x[order // k]                               # [T*k, d]
-    h = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
-         * jax.lax.ragged_dot(rows, w_up, sizes))
-    out = jax.lax.ragged_dot(h, w_down, sizes)
+    h = (jax.nn.silu(grouped_matmul(rows, w_gate, sizes))
+         * grouped_matmul(rows, w_up, sizes))
+    out = grouped_matmul(h, w_down, sizes)
     # back to the picks' own order; rows past the last group are
     # whatever the kernel left there, so they are masked, not scaled
     back = jnp.argsort(order)

@@ -382,12 +382,16 @@ def test_kda_chunk_scan(chip, monkeypatch, capsys):
 
 
 @pytest.mark.parametrize("tokens", [64, 8192])
-def test_experts_grouped_products(chip, tokens):
+def test_experts_grouped_products(chip, tokens, monkeypatch):
     """The held experts' part of an expert layer (40 of 320 held, top-8,
     width 1,280 at d 4,096) for a decode step's 64 slots and for a
-    block of a long prompt: the grouped products are the TPU's own
-    ragged-dot kernel, not a dense product a group."""
+    block of a long prompt: the three grouped products are the Pallas
+    kernel ``moe_grouped``, whose grid visits the held experts' rows
+    alone, not XLA's ragged dot over every pick nor a dense product a
+    group."""
     from ray_tpu.ops.moe import experts_held, route_topk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def layer(x, router, w_gate, w_up, w_down):
         ids, weights = route_topk(x, router, 8)
@@ -398,7 +402,12 @@ def test_experts_grouped_products(chip, tokens):
         layer, chip((tokens, 4096), jnp.bfloat16),
         chip((4096, 320), jnp.bfloat16), w, w,
         chip((40, 1280, 4096), jnp.bfloat16))
-    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert "ragged-dot" not in text
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " = " in line]
+    assert len(kernels) == 3
+    assert all(line.split("=")[0].strip(" %").startswith("moe_grouped")
+               for line in kernels)
     # the sorted copies of a block's picks stay a small part of the chip
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
 
@@ -462,7 +471,7 @@ def test_hybrid_decode_chunk(chip, monkeypatch, capsys):
             chip((slots, pages_a_seq), jnp.int32),
             chip((slots,), jnp.int32), chip((slots,), jnp.bool_)).compile()
     text = compiled.as_text()
-    assert "ragged-dot" in text
+    assert "moe_grouped" in text and "ragged-dot" not in text
     # the one attention layer reads its pages through the one kernel,
     # appends through the other, and neither is a window layer's
     assert len(_attention_kernels(text, "gqa")) == 1
@@ -555,7 +564,7 @@ def test_hybrid_prefill_launch_of_a_long_bucket(chip, monkeypatch, capsys):
                 on_chip(engine._cache), on_chip(engine._dev_toks)).compile()
             text = compiled.as_text()
             assert text.startswith(f"HloModule jit_engine_prefill_b{bucket}")
-            assert "ragged-dot" in text and "tpu_custom_call" in text
+            assert "moe_grouped" in text and "ragged-dot" not in text
             temporaries[rows] = compiled.memory_analysis().temp_size_in_bytes
     finally:
         engine.shutdown()

@@ -201,3 +201,124 @@ def test_a_zero_bias_selects_what_no_bias_selects(layer):
                           jnp.zeros((E,), jnp.float32))
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids0))
     np.testing.assert_allclose(np.asarray(w), np.asarray(w0), rtol=1e-6)
+
+
+# ----------------------------------------------------------------------
+# the grouped products: one Pallas call over the held groups' rows
+# ----------------------------------------------------------------------
+
+def _picks(case, rng):
+    """ids [T, 8] of one of four routings onto 40 held experts (ids
+    0-39; the others are held elsewhere)."""
+    if case == "decode":        # 64 slots, 3 local picks in 2 experts
+        ids = np.full((64, K), 100)
+        ids[5, 2], ids[40, 7], ids[63, 0] = 7, 7, 28
+        return ids
+    if case == "no_local":      # a prompt's block, every pick elsewhere
+        return rng.integers(40, 320, size=(1024, K))
+    if case == "all_local":
+        return np.stack([rng.permutation(E)[:K] for _ in range(1024)])
+    return np.full((1024, K), 17)        # every pick on one expert
+
+
+CASES = ["decode", "no_local", "all_local", "one_expert"]
+
+
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_grouped_products_against_ragged_dot_over_the_held_groups(
+        case, dtype):
+    """The kernel against ``jax.lax.ragged_dot`` on the rows of the held
+    groups, the picks sorted by expert as the layer sorts them; rows
+    past the last group hold NaN, which no held row may see."""
+    from ray_tpu.ops.moe import grouped_matmul
+
+    rng = np.random.default_rng(3)
+    ids = _picks(case, rng).reshape(-1)
+    m = ids.size
+    sizes = jnp.asarray(np.bincount(ids[ids < E], minlength=E), jnp.int32)
+    held = int(sizes.sum())
+    lhs = rng.normal(size=(m, D))
+    lhs[held:] = np.nan
+    lhs = jnp.asarray(lhs, dtype)
+    rhs = jnp.asarray(rng.normal(size=(E, D, F)) / 4, dtype)
+    got = np.asarray(grouped_matmul(lhs, rhs, sizes), np.float32)[:held]
+    want = np.asarray(jax.lax.ragged_dot(lhs, rhs, sizes),
+                      np.float32)[:held]
+    assert grouped_matmul(lhs, rhs, sizes).dtype == dtype
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want,
+                               rtol=1e-2 if dtype == jnp.bfloat16 else 1e-6,
+                               atol=1e-5)
+
+
+def _poisoned(monkeypatch):
+    """The kernel, with NaN written into every row past the last held
+    group of what it returns: rows it leaves undefined."""
+    from ray_tpu.ops import moe
+
+    real = moe.grouped_matmul
+
+    def poisoned(lhs, rhs, sizes):
+        out = real(lhs, rhs, sizes)
+        past = jnp.arange(out.shape[0]) >= sizes.sum()
+        return jnp.where(past[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_held_experts_on_the_kernel_path_with_a_poisoned_tail(
+        layer, case, monkeypatch):
+    """``experts_held`` through the kernel, against the sum over each
+    token's held picks computed directly; the kernel's rows past the
+    last held group are NaN and must not reach ``y``. The long cases
+    go in two blocks."""
+    _poisoned(monkeypatch)
+    rng = np.random.default_rng(4)
+    ids = _picks(case, rng)
+    t = ids.shape[0]
+    x = jnp.asarray(rng.normal(size=(t, D)), jnp.float32)
+    w = jnp.asarray(rng.random(size=(t, K)), jnp.float32)
+    y, counts = experts_held(x, jnp.asarray(ids, jnp.int32), w,
+                             layer["w_gate"], layer["w_up"],
+                             layer["w_down"], 0, block_tokens=512)
+    on = ids < E
+    e = np.where(on, ids, 0)
+    g = np.einsum("td,tkdf->tkf", x, np.asarray(layer["w_gate"])[e])
+    u = np.einsum("td,tkdf->tkf", x, np.asarray(layer["w_up"])[e])
+    h = np.asarray(jax.nn.silu(g)) * u
+    out = np.einsum("tkf,tkfd->tkd", h, np.asarray(layer["w_down"])[e])
+    want = (out * (np.asarray(w) * on)[:, :, None]).sum(1)
+    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.bincount(ids[on], minlength=E))
+
+
+def test_the_layer_runs_the_kernel_and_no_ragged_dot(layer):
+    jaxpr = str(jax.make_jaxpr(lambda p: held(p, 0, 20))(layer))
+    assert "moe_grouped" in jaxpr and "ragged_dot" not in jaxpr
+
+
+@pytest.mark.parametrize("name, m, d, f", [
+    ("solar decode", 512, 4096, 1280), ("solar prefill", 65536, 4096, 1280),
+    ("openpangu decode", 256, 7680, 2048),
+    ("openpangu prefill", 32768, 7680, 2048),
+    ("trinity decode", 128, 3072, 3072),
+    ("trinity prefill", 65536, 3072, 3072)])
+def test_tiling_for_each_configuration(name, m, d, f):
+    """Both matrices' tilings at the three served configurations'
+    widths, a decode step's rows and a prompt block's: whole row tiles,
+    whole lane-aligned column blocks, the contraction in one block of
+    at most 16 MiB, the row tile by the rows alone (so the three
+    products of a block visit the same tiles), and a decode step's
+    rows in tiles of 128."""
+    from ray_tpu.ops.moe import grouped_tiling
+
+    tilings = [grouped_tiling(m, k, n, 2) for k, n in ((d, f), (f, d))]
+    for (tm, tn), (k, n) in zip(tilings, ((d, f), (f, d))):
+        assert m % tm == 0 and n % tn == 0 and tn % 128 == 0
+        assert k * tn * 2 <= 16 << 20
+        assert tn >= 1024                 # few column blocks a matrix
+    assert tilings[0][0] == tilings[1][0] == (128 if m <= 512 else 256)
