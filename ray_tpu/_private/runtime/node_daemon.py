@@ -217,6 +217,13 @@ _OUTBOX_TAGS = frozenset((
     "w", "worker_died", "pulled", "log", "util",
     "local_lease", "local_retry", "p2p_done", "p2p_fallback", "fault"))
 
+# how long a local submission whose only connected worker is its own
+# submitter waits for a sibling that is still booting (a fresh node's
+# workers connect one by one, seconds apart) before it runs the child
+# on the submitter's process, where a crash takes the parent down with
+# it
+_SIBLING_BOOT_WAIT_S = 10.0
+
 
 class _WorkerSlot:
     __slots__ = ("num", "proc", "conn", "ctrl", "pid", "returns",
@@ -522,6 +529,9 @@ class NodeDaemon:
         self.inline_max = inline_max
         self._slots: Dict[int, _WorkerSlot] = {}
         self._lock = threading.Lock()
+        # notified whenever a worker connects or a slot goes away, for
+        # _submit_after_boot's wait on a booting sibling
+        self._slots_changed = threading.Condition(self._lock)
         self._shutdown = False
         self._head_address = tuple(head_address)
         self._head_authkey = head_authkey
@@ -738,8 +748,9 @@ class NodeDaemon:
             # still sit buffered on its pipe: wait for the reader to
             # drain to EOF so a finished task is never retried
             slot.reader_done.wait(1.0)
-        with self._lock:
+        with self._slots_changed:
             gone = self._slots.pop(slot.num, None)
+            self._slots_changed.notify_all()
         if gone is not None and not self._shutdown:
             from ray_tpu._private import log_plane
 
@@ -826,7 +837,9 @@ class NodeDaemon:
                 conn.close()
                 continue
             if kind == "task":
-                slot.conn = conn
+                with self._slots_changed:
+                    slot.conn = conn
+                    self._slots_changed.notify_all()
                 threading.Thread(target=self._worker_reader,
                                  args=(slot,), daemon=True,
                                  name=f"ray_tpu_node_reader_{num}").start()
@@ -1384,6 +1397,37 @@ class NodeDaemon:
                                   len(s.returns)))
         return cands[0]
 
+    def _sibling_booting(self, submitter: _WorkerSlot) -> bool:
+        """Whether a worker of this node is started but not connected
+        yet while no live sibling of ``submitter`` is connected. The
+        caller holds ``self._lock``."""
+        def alive(s):
+            return s.proc is not None and s.proc.poll() is None
+
+        slots = list(self._slots.values())
+        return (any(s.conn is None and alive(s) for s in slots)
+                and not any(s.num != submitter.num and s.conn is not None
+                            and s.actor_bin is None and alive(s)
+                            for s in slots))
+
+    def _submit_after_boot(self, slot: _WorkerSlot, req_id: int,
+                           args: tuple) -> None:
+        """``_maybe_local_submit`` once a sibling of the submitter has
+        connected, or none is booting any more, or
+        ``_SIBLING_BOOT_WAIT_S`` has passed; on a thread of its own, so
+        that the submitter's reader goes on serving its other
+        traffic."""
+        deadline = time.monotonic() + _SIBLING_BOOT_WAIT_S
+        with self._slots_changed:
+            while self._sibling_booting(slot):
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    break
+                self._slots_changed.wait(left)
+        out = self._maybe_local_submit(slot, req_id, args, waited=True)
+        if out is not None:
+            self._send_head(("w", slot.num, out))
+
     def _refs_resident(self, refs) -> bool:
         """Every arg ObjectRef's bytes provably on this node: sealed
         in the local arena, or listed in the head-pushed object-
@@ -1402,7 +1446,8 @@ class NodeDaemon:
         return True
 
     def _maybe_local_submit(self, slot: _WorkerSlot, req_id: int,
-                            args: tuple) -> Optional[tuple]:
+                            args: tuple, waited: bool = False
+                            ) -> Optional[tuple]:
         """LocalScheduler admission: a worker-originated nested
         submission whose demand fits this node is leased HERE — ids
         minted locally, the lease journaled at the head through the
@@ -1460,6 +1505,17 @@ class NodeDaemon:
             if not arg_refs or not self._refs_resident(arg_refs):
                 return spill("refs")
         target = self._pick_local_slot(slot)
+        with self._lock:
+            booting = self._sibling_booting(slot)
+        if target is slot and booting and not waited:
+            # a fresh node's workers connect seconds apart: the child
+            # waits for a sibling rather than run on its submitter,
+            # where a crash would take the parent down with it
+            threading.Thread(target=self._submit_after_boot,
+                             args=(slot, req_id, args), daemon=True,
+                             name=f"ray_tpu_node_boot_wait_{slot.num}"
+                             ).start()
+            return None
         if target is None:
             return spill("no_slot")
         from ray_tpu._private.runtime.worker_process import fn_id_of

@@ -4,7 +4,8 @@
 every layer is the same. The models people serve mix kinds: a softmax
 attention layer, then three with a recurrent state, experts in every
 feed-forward, an untied head. ``DecoderConfig`` names, for each layer,
-its mixer (``attention``, ``window``, ``delta_rule`` or ``latent``) and
+its mixer (``attention``, ``window``, ``delta_rule``, ``latent`` or
+``conv``) and
 its feed-forward (``dense`` or ``experts``), and beside them what the
 kinds need; a model may mix feed-forward kinds as well as mixers. The
 dense decoder is the one-kind case: ``describe`` lowers a
@@ -39,6 +40,12 @@ root, beside ``embedding``, ``final_norm/scale`` and, untied,
   [r], w_kvb [r,H,n+v_dim], wo [H,v_dim,d]}``; a head's keys are ``[the
   first n columns of w_kvb over the normed latent | the ONE rotated
   p-wide key all heads share]``, its values the other ``v_dim``;
+- mixer ``conv`` (a gated short convolution; K = ``conv_taps``):
+  ``ShortConv_0/{w_in [d,3,d], conv [K,d], w_out [d,d]}``; ``w_in``'s
+  three blocks are B, C and x in that order, and the mixer is ``(C *
+  conv(B * x)) w_out`` with a causal depthwise convolution whose
+  ``conv[K-1]`` multiplies the current position (ops/short_conv.py).
+  It keeps the last K-1 rows of ``B * x`` a slot and nothing else;
 - feed-forward ``dense``: ``MLP_0/{w_gate, w_up [d,f], w_down [f,d]}``;
 - feed-forward ``experts`` (ops/moe.py): ``MoE_0/{router [d,E], w_gate,
   w_up [E_held,d,fe], w_down [E_held,fe,d]}``, with a shared expert
@@ -54,7 +61,7 @@ from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 
-MIXERS = ("attention", "window", "delta_rule", "latent")
+MIXERS = ("attention", "window", "delta_rule", "latent", "conv")
 FFNS = ("dense", "experts")
 
 
@@ -106,6 +113,8 @@ class DecoderConfig:
     nope_dim: int = 0
     rope_dim: int = 0
     v_dim: int = 0
+    # conv layers: taps of the gated short convolution
+    conv_taps: int = 3
     # four norms a layer: each branch is normed again before its
     # residual add
     sandwich_norm: bool = False
@@ -120,6 +129,8 @@ class DecoderConfig:
     d_shared: int = 0
     routed_scale: float = 1.0
     router_bias: bool = False           # picks by score + MoE_0/bias
+    # what a biased router adds under the sum of the picked scores
+    router_eps: float = 1e-20
 
     def __post_init__(self):
         if self.window_layers and self.window <= 0:
@@ -173,11 +184,27 @@ class DecoderConfig:
         return -(-(self.kv_rank + self.rope_dim) // 128) * 128
 
     @property
+    def kv_width(self) -> int:
+        """What an attention or window layer's pools keep of a head, in
+        numbers: the head's keys or values padded with zeros to whole
+        lanes of 128, as ``latent_width`` pads a latent row (the chip's
+        paged read and append take rows of whole lanes; its compiler
+        refuses a row of 64). A head of 128 is kept as it is."""
+        return -(-self.head_dim // 128) * 128
+
+    @property
     def state_layers(self) -> Tuple[int, ...]:
-        """Layers that keep a recurrent state and a convolution tail a
-        slot."""
+        """Layers that keep a state of their own a slot: a recurrent
+        state and a convolution tail (``delta_rule``), a convolution
+        tail alone (``conv``)."""
         return tuple(i for i, l in enumerate(self.layers)
-                     if l.mixer == "delta_rule")
+                     if l.mixer in ("delta_rule", "conv"))
+
+    @property
+    def conv_layers(self) -> Tuple[int, ...]:
+        """Layers whose mixer is the gated short convolution."""
+        return tuple(i for i, l in enumerate(self.layers)
+                     if l.mixer == "conv")
 
     @property
     def moe_layers(self) -> Tuple[int, ...]:

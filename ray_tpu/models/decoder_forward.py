@@ -14,7 +14,9 @@ pages a slot, which are the slot's own: a token at position ``t`` lies
 in ring page ``(t // page_size) % ring``, so the layer's cache stops
 growing at the window), ``(state, tail)`` a slot for a delta-rule
 layer, ``(latent_pages,)`` for a latent layer (one pool [P,1,page,W]
-whose row a token is the normed latent and the rotated shared key).
+whose row a token is the normed latent and the rotated shared key),
+``(tail,)`` a slot for a conv layer (the last K-1 rows of ``B * x``
+before the slot's next position, float32).
 What a kind of mixer keeps and does is one entry
 of ``MIXERS_BY_KIND`` (``init``, ``write``, ``prefill``, ``decode``,
 and whether what it keeps is keys and values a position that a caller
@@ -48,6 +50,7 @@ from ray_tpu.ops.paged_attention import (append_token, append_token_kv,
                                          write_prefill_kv,
                                          write_prefill_pages)
 from ray_tpu.ops.rope import rope
+from ray_tpu.ops.short_conv import conv_tail, short_conv, short_conv_step
 
 # a row longer than this never materialises its [S,S] scores
 _SCORES_MAX_SEQ = 512
@@ -80,7 +83,8 @@ def _experts(m, cfg: DecoderConfig, x, valid):
     with jax.named_scope("moe_route"):
         ids, weights = route_topk(t, m["router"], cfg.experts_per_token,
                                   cfg.routed_scale,
-                                  m["bias"] if cfg.router_bias else None)
+                                  m["bias"] if cfg.router_bias else None,
+                                  cfg.router_eps)
     with jax.named_scope("moe_experts"):
         block = _EXPERT_BLOCK_NUMBERS // (cfg.experts_per_token
                                           * cfg.d_model)
@@ -289,8 +293,7 @@ def _prefill_delta_rule(a, cfg: DecoderConfig, h, plens):
         state, tail = carry
         hs, start = xs
         flat, w = _delta_rule_projections(a, cfg, hs)
-        mixed = jax.nn.silu(kda.short_conv(flat.astype(jnp.float32), w,
-                                           tail))
+        mixed = jax.nn.silu(short_conv(flat.astype(jnp.float32), w, tail))
         q, k, v, g, beta = _delta_rule_inputs(
             a, cfg, hs, mixed.reshape(n, seg, cfg.dr_heads,
                                       cfg.dr_channels))
@@ -310,8 +313,7 @@ def _prefill_delta_rule(a, cfg: DecoderConfig, h, plens):
         out = jnp.moveaxis(out, 0, 1).reshape(n, s + pad, d)[:, :s]
         # what the convolution of position plens needs: the
         # projections of the row's last K-1 inputs
-        tail, _ = _delta_rule_projections(
-            a, cfg, kda.conv_tail(h, plens, taps))
+        tail, _ = _delta_rule_projections(a, cfg, conv_tail(h, plens, taps))
         return out, state, tail
 
 
@@ -325,8 +327,7 @@ def _decode_delta_rule(p, cfg: DecoderConfig, h, kept, page_table,
     state, tail = kept
     with jax.named_scope("kda"):
         flat, w = _delta_rule_projections(a, cfg, h)
-        mixed, new_tail = kda.short_conv_step(
-            flat.astype(jnp.float32), w, tail)
+        mixed, new_tail = short_conv_step(flat.astype(jnp.float32), w, tail)
         q, k, v, g, beta = _delta_rule_inputs(
             a, cfg, h, jax.nn.silu(mixed).reshape(
                 h.shape[0], cfg.dr_heads, cfg.dr_channels))
@@ -494,6 +495,13 @@ def _write_latent(cfg, entry, kept, slots, pages, plens):
 # window (in a ring a slot); the delta rule
 # ----------------------------------------------------------------------
 
+def _lanes(x, width: int):
+    """x [..., D] padded with zeros to ``width`` on its last axis."""
+    pad = width - x.shape[-1]
+    return x if not pad else jnp.pad(
+        x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+
+
 def window_ring(cfg: DecoderConfig, page_size: int) -> int:
     """Pages a sequence's ring has in a window layer's pool: as many as
     ``cfg.window`` consecutive positions can straddle."""
@@ -541,27 +549,34 @@ def _decode_attention(p, cfg: DecoderConfig, h, kept, page_table, seq_lens,
         window = {"first": seq_lens + 1 - cfg.window, "ring": ring}
     with jax.named_scope(scope):
         q, k, v = _attention_qkv(a, cfg, mixer, h, seq_lens)
+    width = k_pages.shape[-1]
+    if width != cfg.head_dim:
+        # the pools hold the heads padded to whole lanes: zeros in q and
+        # k add nothing to a score, zeros in v come out and are cut
+        q, k, v = (_lanes(t, width) for t in (q, k, v))
+        window["score_width"] = cfg.head_dim
     with jax.named_scope("win_append" if ring else "kv_append"):
         k_pages, v_pages = append_token_kv(k_pages, v_pages, k, v,
                                            page_table, seq_lens, ring)
     with jax.named_scope(scope):
         out = paged_attention_auto(q, k_pages, v_pages, page_table,
                                    seq_lens + 1, **window)
-        out = _attention_out(a, cfg, h, out)
+        out = _attention_out(a, cfg, h, out[..., :cfg.head_dim])
     return out, (k_pages, v_pages)
 
 
 def _init_attention(cfg: DecoderConfig, icfg):
-    pool = (icfg.num_pages, cfg.n_kv_heads, icfg.page_size, cfg.head_dim)
+    pool = (icfg.num_pages, cfg.n_kv_heads, icfg.page_size, cfg.kv_width)
     return jnp.zeros(pool, cfg.dtype), jnp.zeros(pool, cfg.dtype)
 
 
 def _write_attention(cfg, entry, kept, slots, pages, plens):
     k_pages, v_pages = entry
+    k, v = (_lanes(t, k_pages.shape[-1]) for t in kept)
     with jax.named_scope("kv_append"):
         for r in range(pages.shape[0]):
             k_pages, v_pages = write_prefill_kv(
-                k_pages, v_pages, kept[0][r], kept[1][r], pages[r])
+                k_pages, v_pages, k[r], v[r], pages[r])
     return k_pages, v_pages
 
 
@@ -570,7 +585,7 @@ def _init_window(cfg: DecoderConfig, icfg):
     ring - 1``) and, last, a page for what a launch writes and nothing
     reads."""
     pool = (icfg.batch_size * window_ring(cfg, icfg.page_size) + 1,
-            cfg.n_kv_heads, icfg.page_size, cfg.head_dim)
+            cfg.n_kv_heads, icfg.page_size, cfg.kv_width)
     return jnp.zeros(pool, cfg.dtype), jnp.zeros(pool, cfg.dtype)
 
 
@@ -598,10 +613,11 @@ def _write_window(cfg, entry, kept, slots, pages, plens):
     n_pages, page = pages.shape[1], k_pages.shape[2]
     where = window_prefill_pages(cfg, k_pages.shape[0], page, n_pages,
                                  slots, plens)
+    k, v = (_lanes(t, k_pages.shape[-1]) for t in kept)
     with jax.named_scope("win_append"):
         for r in range(where.shape[0]):
             k_pages, v_pages = write_prefill_kv(
-                k_pages, v_pages, kept[0][r], kept[1][r], where[r])
+                k_pages, v_pages, k[r], v[r], where[r])
     return k_pages, v_pages
 
 
@@ -617,8 +633,63 @@ def _init_delta_rule(cfg: DecoderConfig, icfg):
                        cfg.dr_heads * cfg.dr_channels), cfg.dtype))
 
 
-def _write_delta_rule(cfg, entry, kept, slots, pages, plens):
-    with jax.named_scope("kda_state"):
+# ----------------------------------------------------------------------
+# the gated short convolution: (C * conv(B * x)) w_out, a tail a slot
+# ----------------------------------------------------------------------
+
+def _conv_gates(a, cfg: DecoderConfig, h):
+    """h [..., d] (normed) -> (z = B * x, C), float32 [..., d]: the
+    input projection's three blocks B, C, x, the first and last
+    multiplied."""
+    bcx = jnp.einsum("...d,dkc->...kc", h, a["w_in"].astype(cfg.dtype))
+    bcx = bcx.astype(jnp.float32)
+    return bcx[..., 0, :] * bcx[..., 2, :], bcx[..., 1, :]
+
+
+def _conv_out(a, cfg: DecoderConfig, c, y):
+    """C * y (float32) through the output projection."""
+    return (c * y).astype(cfg.dtype) @ a["w_out"].astype(cfg.dtype)
+
+
+def _prefill_conv(p, cfg: DecoderConfig, h, positions, plens):
+    """The mixer over a bucket: h [N,S,Dm] (normed) -> (out [N,S,Dm],
+    (tail [N,K-1,Dm],)). Each row starts from zeros; its tail is the
+    last K-1 rows of ``B * x`` before position ``plens``, zeros before
+    position 0 for a prompt shorter than that."""
+    a = p["ShortConv_0"]
+    with jax.named_scope("conv"):
+        z, c = _conv_gates(a, cfg, h)
+        y = short_conv(z, a["conv"].astype(jnp.float32))
+        return _conv_out(a, cfg, c, y), (conv_tail(z, plens, cfg.conv_taps),)
+
+
+def _decode_conv(p, cfg: DecoderConfig, h, kept, page_table, seq_lens,
+                 live):
+    """One position a slot: h [B,Dm] (normed) against the slot's tail.
+    Slots that are not ``live`` keep their tails as they are."""
+    a = p["ShortConv_0"]
+    (tail,) = kept
+    with jax.named_scope("conv"):
+        z, c = _conv_gates(a, cfg, h)
+        y, new_tail = short_conv_step(
+            z, a["conv"].astype(jnp.float32), tail,
+            precision=jax.lax.Precision.HIGHEST)
+        out = _conv_out(a, cfg, c, y)
+    with jax.named_scope("conv_state"):
+        if live is not None:
+            new_tail = jnp.where(live[:, None, None], new_tail, tail)
+    return out, (new_tail,)
+
+
+def _init_conv(cfg: DecoderConfig, icfg):
+    return (jnp.zeros((icfg.batch_size, cfg.conv_taps - 1, cfg.d_model),
+                      jnp.float32),)
+
+
+def _write_slots(cfg, entry, kept, slots, pages, plens, *, scope: str):
+    """Each row's state to slot ``slots[r]`` whole (a dummy row's slot
+    is out of bounds and its scatter is dropped)."""
+    with jax.named_scope(scope):
         return tuple(held.at[slots].set(new)
                      for held, new in zip(entry, kept))
 
@@ -654,11 +725,15 @@ MIXERS_BY_KIND = {
         functools.partial(_prefill_attention_kind, mixer="window"),
         functools.partial(_decode_attention, mixer="window"),
         "a ring of the last positions' keys and values a slot"),
-    "delta_rule": _Mixer(_init_delta_rule, _write_delta_rule,
+    "delta_rule": _Mixer(_init_delta_rule,
+                         functools.partial(_write_slots, scope="kda_state"),
                          _prefill_delta_rule_kind, _decode_delta_rule,
                          "recurrent state"),
     "latent": _Mixer(_init_latent, _write_latent, _prefill_latent,
                      _decode_latent, "latent rows"),
+    "conv": _Mixer(_init_conv,
+                   functools.partial(_write_slots, scope="conv_state"),
+                   _prefill_conv, _decode_conv, "a convolution tail"),
 }
 
 
@@ -725,6 +800,19 @@ def _sum_counts(counts):
     return sum(counts[1:], counts[0]) if counts else None
 
 
+def _step_counts(counts):
+    """What a decode step counts of the expert layers' picks a held
+    expert (a list by layer, None for a layer without experts): the
+    picks summed over the layers, then how many held experts got at
+    least one pick, summed over the layers; [E_held + 1] int32, or
+    None."""
+    counts = [c for c in counts if c is not None]
+    if not counts:
+        return None
+    touched = sum(jnp.sum(c > 0, dtype=jnp.int32) for c in counts)
+    return jnp.concatenate([_sum_counts(counts), touched[None]])
+
+
 def _prefill_hidden(params, cfg: DecoderConfig, tokens, plens=None,
                     rows=None):
     """tokens [N,S] (padded to a bucket) -> (hidden [N,S,Dm] before the
@@ -758,7 +846,8 @@ def init_cache(cfg: DecoderConfig, icfg) -> tuple:
     """The empty cache of ``cfg`` for an engine of ``icfg`` (its
     ``num_pages``, ``page_size`` and ``batch_size``): a tuple with one
     entry a layer. An attention layer keeps ``(k_pages, v_pages)``, each
-    [num_pages, KV, page_size, D] in the model's dtype; a delta-rule
+    [num_pages, KV, page_size, ``cfg.kv_width``] in the model's dtype; a
+    conv layer ``(tail,)`` [B, K-1, d] in float32; a delta-rule
     layer keeps, for each slot, ``(state, tail)``: its state [B, H, dk,
     dv] in float32 and the last K-1 inputs of its short convolution
     [B, K-1, channels]; a latent layer keeps ``(latent_pages,)``
@@ -792,7 +881,9 @@ def import_kv(cfg: DecoderConfig, cache, k_seq, v_seq, pages):
     cache = list(cache)
     with jax.named_scope("kv_append"):
         for j, i in enumerate(cfg.kv_layers):
-            cache[i] = write_prefill_kv(*cache[i], k_seq[j], v_seq[j], pages)
+            width = cache[i][0].shape[-1]
+            cache[i] = write_prefill_kv(*cache[i], _lanes(k_seq[j], width),
+                                        _lanes(v_seq[j], width), pages)
     return tuple(cache)
 
 
@@ -802,7 +893,7 @@ def decode_step_cached(params, cfg: DecoderConfig, tokens, cache, page_table,
     last prompt token per slot), ``seq_lens`` [B] the cache length
     before the token, ``live`` [B] bool the slots that hold a request
     (None: every slot). Returns (next_logits [B,V] f32, cache, picks a
-    held expert or None)."""
+    held expert and experts touched, ``_step_counts``, or None)."""
     x = _embed(params, cfg, tokens)                            # [B, Dm]
     new_cache, counts = [], []
     for i, (spec, kept) in enumerate(zip(cfg.layers, cache)):
@@ -811,14 +902,14 @@ def decode_step_cached(params, cfg: DecoderConfig, tokens, cache, page_table,
         new_cache.append(kept)
         counts.append(c)
     logits = _head(params, cfg, x, "bd,vd->bv")
-    return logits, tuple(new_cache), _sum_counts(counts)
+    return logits, tuple(new_cache), _step_counts(counts)
 
 
 def decode_chunk_cached(params, cfg: DecoderConfig, tokens, cache, page_table,
                         seq_lens, live, *, n_steps: int):
     """n_steps greedy decode steps in ONE jitted program (lax.scan with
     argmax feedback). Returns (tokens [n_steps, B] int32, next_tokens
-    [B], next_lens [B], cache, picks a held expert over the chunk's
+    [B], next_lens [B], cache, ``_step_counts`` summed over the chunk's
     steps or None): the feedback state comes back as DEVICE arrays so
     the engine can chain chunks without a host round trip: chunks
     pipeline asynchronously and the host syncs only when a burst
@@ -833,7 +924,7 @@ def decode_chunk_cached(params, cfg: DecoderConfig, tokens, cache, page_table,
             total = total + counts
         return (nxt, kept, lens + 1, total), nxt
 
-    total = (jnp.zeros((cfg.n_experts_held,), jnp.int32)
+    total = (jnp.zeros((cfg.n_experts_held + 1,), jnp.int32)
              if cfg.moe_layers else None)
     (toks, cache, lens, total), outs = jax.lax.scan(
         body, (tokens, cache, seq_lens, total), None, length=n_steps)

@@ -231,6 +231,9 @@ class InferenceEngine:
         # tokens, live slots' decode steps): all, and by held expert
         self._moe_picks_total = 0
         self._moe_load = np.zeros(mcfg.n_experts_held, np.int64)
+        # held experts that got a pick, summed over the decode steps and
+        # the expert layers
+        self._moe_experts_touched = 0
         # single-prompt bucketed prompt pass for prefill_export;
         # compiles lazily per bucket on first use
         self._export_jits = {
@@ -499,7 +502,10 @@ class InferenceEngine:
         tokens that counted (every prompt token, every decode step of a
         live slot; ``experts_per_token`` a token and expert layer),
         ``moe_picks_local`` those that fell on an expert held here and
-        were computed, ``moe_load_by_expert`` the same by held expert.
+        were computed, ``moe_load_by_expert`` the same by held expert,
+        and ``moe_experts_touched`` sums, over the decode steps and the
+        expert layers, the held experts that got at least one pick (each
+        such expert's matrices are read once a step and layer).
         ``decode_ctx_tokens_live`` sums, over the steps, the live slots'
         context when their burst began, and ``decode_window_tokens_live``
         the same with each slot's context clipped to the window (what a
@@ -508,8 +514,9 @@ class InferenceEngine:
         holds for all slots (``batch_size`` rings of ``window /
         page_size + 1`` pages) and ``window_pages_live`` the pages of
         it that hold the live slots' tokens now.
-        ``state_bytes`` is what the recurrent layers keep for all slots
-        and ``pool_tokens`` the tokens the page pool can hold;
+        ``state_bytes`` is what the layers with a state of their own a
+        slot (recurrent state, convolution tail) keep for all slots and
+        ``pool_tokens`` the tokens the page pool can hold;
         ``latent_bytes_per_token`` is what the latent layers together
         hold for a token (their rows as they lie in memory, padded to
         whole lanes) and ``latent_pool_bytes`` their pools whole."""
@@ -550,6 +557,7 @@ class InferenceEngine:
                 "moe_picks_total": self._moe_picks_total,
                 "moe_picks_local": int(self._moe_load.sum()),
                 "moe_load_by_expert": self._moe_load.tolist(),
+                "moe_experts_touched": self._moe_experts_touched,
                 "state_bytes": sum(
                     a.nbytes for i in self.mcfg.state_layers
                     for a in jax.tree_util.tree_leaves(self._cache[i])),
@@ -697,6 +705,10 @@ class InferenceEngine:
                         useful_rows=len(group),
                         prompt_tokens=prompt_tokens) as launch:
             launch.fields["prompt_lens"] = prompt_lens
+            if self.mcfg.conv_layers:
+                # slots whose convolution tails the launch replaced, in
+                # each conv layer
+                launch.fields["conv_tails_written"] = len(group)
             if self._ring:
                 # positions a window layer's ring got: the pages that
                 # hold a prompt's last ``window`` positions, whole
@@ -846,9 +858,15 @@ class InferenceEngine:
             kept = self._deliver(active, pending, firsts, flat)
             deliver.fields["kept_tokens"] = kept
             if self.mcfg.moe_layers:
+                # each chunk's picks a held expert, then its experts
+                # touched, close the fetch
+                held = len(self._moe_load)
+                rows = flat[len(flat) - len(pending) * (held + 1):].reshape(
+                    len(pending), held + 1)
                 deliver.fields.update(self._count_picks(
-                    flat[len(flat) - len(pending) * len(self._moe_load):],
-                    len(active) * steps))
+                    rows[:, :held], len(active) * steps))
+                touched = int(rows[:, held].sum())
+                burst.fields["moe_experts_touched"] = touched
         with self._lock:      # a burst's counts land together
             self._bursts += 1
             self._decode_steps += steps
@@ -856,6 +874,8 @@ class InferenceEngine:
             self._decode_ctx_tokens += sum(live_ctx) * steps
             self._decode_window_tokens += live_window * steps
             self._decode_tokens_kept += kept
+            self._moe_experts_touched += burst.fields.get(
+                "moe_experts_touched", 0)
 
     def _count_picks(self, counts: np.ndarray, tokens: int
                      ) -> Dict[str, int]:

@@ -61,16 +61,20 @@ A position with ``g = 0`` and ``beta = 0`` leaves the state as it is:
 that is how the rows of a padded bucket past their prompt's length are
 kept from touching it (``pad_mask``). The short causal convolution that
 precedes the recurrence, and the tail of inputs it hands from a prompt
-to the decode steps, are here too.
+to the decode steps, are ``ops/short_conv.py``'s (a second mixer runs
+them too) and are importable from here.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops.short_conv import (conv_tail, short_conv,  # noqa: F401
+                                    short_conv_step)
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -78,37 +82,6 @@ _HI = jax.lax.Precision.HIGHEST
 def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
-
-
-def short_conv(x: jnp.ndarray, w: jnp.ndarray,
-               tail: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Causal depthwise convolution over positions. x [N,S,C], w [K,C]
-    (``w[K-1]`` multiplies the current position), ``tail`` [N,K-1,C] the
-    inputs before position 0 (zeros when None). Returns [N,S,C]."""
-    n, s, c = x.shape
-    k = w.shape[0]
-    if tail is None:
-        tail = jnp.zeros((n, k - 1, c), x.dtype)
-    xp = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    return sum(xp[:, i:i + s] * w[i].astype(x.dtype) for i in range(k))
-
-
-def conv_tail(x: jnp.ndarray, lens: jnp.ndarray, k: int) -> jnp.ndarray:
-    """The last ``k - 1`` inputs before position ``lens[n]`` of each row
-    of x [N,S,C] (zeros before position 0): what the convolution of
-    position ``lens[n]`` needs of the prompt. Returns [N,k-1,C]."""
-    n, _s, c = x.shape
-    xp = jnp.concatenate([jnp.zeros((n, k - 1, c), x.dtype), x], axis=1)
-    idx = lens[:, None] + jnp.arange(k - 1)[None, :]      # in xp's frame
-    return jnp.take_along_axis(xp, idx[:, :, None], axis=1)
-
-
-def short_conv_step(x: jnp.ndarray, w: jnp.ndarray, tail: jnp.ndarray
-                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One position: x [B,C], tail [B,K-1,C] -> (y [B,C], new tail)."""
-    win = jnp.concatenate([tail.astype(x.dtype), x[:, None]], axis=1)
-    y = jnp.einsum("bkc,kc->bc", win, w.astype(x.dtype))
-    return y, win[:, 1:]
 
 
 def pad_mask(g: jnp.ndarray, beta: jnp.ndarray, lens: jnp.ndarray

@@ -146,8 +146,8 @@ def moe_ffn_sharded(tokens, w_router, w_in, w_out, mesh,
 # ----------------------------------------------------------------------
 
 def route_topk(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int,
-               scale: float = 1.0, bias: Optional[jnp.ndarray] = None
-               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+               scale: float = 1.0, bias: Optional[jnp.ndarray] = None,
+               eps: float = 1e-20) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x [T,d], w_router [d,E] -> (ids [T,k] int32, weights [T,k] f32).
     The router runs in float32 whatever the model's type: a near-tie
     between the k-th and the next expert decides which experts a token
@@ -156,7 +156,8 @@ def route_topk(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int,
     nothing). ``bias`` [E] SELECTS and never weighs: the k picked are
     the largest of ``score + bias``, their weights come from the scores
     alone (a load-balancing bias that training moves; such a router
-    guards its denominator with 1e-20)."""
+    guards its denominator with ``eps``: 1e-20 in Trinity-Large's
+    router, 1e-6 in LFM2's)."""
     scores = jax.nn.sigmoid(jnp.einsum(
         "td,de->te", x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -166,7 +167,7 @@ def route_topk(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int,
     else:
         _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
         picked = jnp.take_along_axis(scores, ids, axis=-1)
-        weights = picked / (picked.sum(-1, keepdims=True) + 1e-20)
+        weights = picked / (picked.sum(-1, keepdims=True) + eps)
     return ids.astype(jnp.int32), (weights if scale == 1.0
                                    else weights * scale)
 

@@ -331,15 +331,15 @@ def _paged_read(q, pools, page_table, seq_lens, *, block_tokens: int,
     )(*scalars, q, *pools)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_tokens", "interpret", "ring"))
+@functools.partial(jax.jit, static_argnames=(
+    "block_tokens", "interpret", "ring", "score_width"))
 def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                     v_pages: jnp.ndarray, page_table: jnp.ndarray,
                     seq_lens: jnp.ndarray, *,
                     block_tokens: int = BLOCK_TOKENS,
                     interpret: bool = False,
                     first: Optional[jnp.ndarray] = None,
-                    ring: int = 0) -> jnp.ndarray:
+                    ring: int = 0, score_width: int = 0) -> jnp.ndarray:
     """Pallas flash-decoding over paged KV (see module docstring);
     interpret=True runs the kernel body off-TPU for testing. Jitted on
     its own, so that a decode program traces the kernel once and not
@@ -351,13 +351,17 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     ring``), ``first`` [B] its first visible position, and only
     positions ``first <= t < seq_lens`` are fetched and scored. The
     trace keeps that call's name, ``paged_window_read``. Without a
-    ring the program is the one it was before there were rings."""
+    ring the program is the one it was before there were rings.
+
+    ``score_width`` divides the scores by its root in place of D's:
+    that of heads whose rows the pools hold padded with zeros to whole
+    lanes (``DecoderConfig.kv_width``)."""
     B, H, D = q.shape
     KV = k_pages.shape[1]
     out = _paged_read(q.reshape(B, KV, H // KV, D), (k_pages, v_pages),
                       page_table, seq_lens, block_tokens=block_tokens,
-                      interpret=interpret, score_width=D, value_width=D,
-                      first=first, ring=ring,
+                      interpret=interpret, score_width=score_width or D,
+                      value_width=D, first=first, ring=ring,
                       name="paged_window_read" if ring else None)
     return out.reshape(B, H, D)
 
@@ -390,7 +394,8 @@ def paged_attention_auto(q, k_pages, v_pages, page_table, seq_lens,
     contexts too). Off-TPU it runs in interpret mode so tests exercise
     the real kernel logic. A kernel that fails to lower raises: nothing
     falls back to the gather. ``window``: a window layer's ``first``
-    and ``ring`` (``paged_attention``)."""
+    and ``ring``, and a padded head's ``score_width``
+    (``paged_attention``)."""
     return paged_attention(q, k_pages, v_pages, page_table, seq_lens,
                            interpret=jax.default_backend() != "tpu",
                            **window)

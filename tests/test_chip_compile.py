@@ -868,3 +868,140 @@ def test_shared_kernels_are_as_they_were_for_those_who_do_not_ask(
     assert len(_attention_kernels(text, append)) == len(mcfg.layers)
     assert "paged_window_read" not in text
     assert not _attention_kernels(text, "win") + _attention_kernels(text, "win_append")
+
+
+def _lfm2_stage(chip):
+    """The benchmark's LFM2 stage at every published width and at its
+    cell's geometry (9 layers: 7 conv, 2 full with 64 of 64 experts,
+    the whole vocabulary; 64 slots, 1,537 pages of 128): (description,
+    parameter tree as shapes on the described chip, cache as
+    shapes)."""
+    config = _benchmark_config("lfm2-24b-a2b-serve-L9")
+    from benchmark import weights_lfm2 as W
+    from ray_tpu.models import decoder_forward
+    from ray_tpu.models.inference import InferenceConfig
+
+    mcfg = W.description(config)
+    params = jax.eval_shape(
+        lambda k: W.init_params(config, k, jnp.bfloat16), W.seed_key(1))
+    icfg = InferenceConfig(batch_size=64, page_size=128,
+                           max_pages_per_seq=24, num_pages=1537)
+    cache = jax.eval_shape(lambda: decoder_forward.init_cache(mcfg, icfg))
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: chip(x.shape, x.dtype), tree)
+    return mcfg, on_chip(params), on_chip(cache)
+
+
+def test_short_conv_decode_chunk(chip, monkeypatch, capsys):
+    """The decode program of the LFM2 cell, 32 steps, whole: its two
+    full layers read and append through the paged kernels with heads of
+    64 padded to a lane of 128 in the pools (the kernels refuse a row
+    of 64 for the chip), the seven conv layers' products under
+    ``conv`` and their tails under ``conv_state``, the experts through
+    ``moe_grouped``; arguments and temporaries fit the chip, pools and
+    tails donated, no copy of a pool."""
+    from ray_tpu.models import decoder_forward
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mcfg, params, cache = _lfm2_stage(chip)
+    pool_dims = (1537, 8, 128, 128)
+    assert [e[0].shape for i, e in enumerate(cache)
+            if i in mcfg.kv_layers] == [pool_dims] * 2
+    assert [e[0].shape for i, e in enumerate(cache)
+            if i in mcfg.conv_layers] == [(64, 2, 2048)] * 7
+    compiled = jax.jit(
+        lambda p, t, cache, table, lens, live:
+        decoder_forward.decode_chunk_cached(
+            p, mcfg, t, cache, table, lens, live, n_steps=32),
+        donate_argnums=(2,)).lower(
+            params, chip((64,), jnp.int32), cache, chip((64, 24), jnp.int32),
+            chip((64,), jnp.int32), chip((64,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[short-conv decode chunk, 32 steps, 9 layers] arguments "
+              f"{m.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} GB")
+    assert "moe_grouped" in text and "ragged-dot" not in text
+    assert len(_attention_kernels(text, "attn")) == 2
+    assert len(_attention_kernels(text, "kv_append")) == 2
+    assert re.search(r'op_name="[^"]*/conv/', text)
+    assert re.search(r'op_name="[^"]*/conv_state/', text)
+    _no_pool_moves(text, pool_dims)
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15e9
+    assert m.alias_size_in_bytes >= 4 * 1537 * 8 * 128 * 128 * 2
+
+
+def test_short_conv_prefill_launch_of_the_longest_bucket(chip, monkeypatch,
+                                                         capsys):
+    """A launch of 2,048 positions of the LFM2 cell (one row, the
+    engine's rule for its longest bucket), whole: the conv layers'
+    products under ``conv``, the tails written under ``conv_state``,
+    the experts through ``moe_grouped``, no [S,S] scores of the whole
+    row, no copy of a pool, and arguments and temporaries that fit the
+    chip."""
+    from ray_tpu.models import decoder_forward
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mcfg, params, cache = _lfm2_stage(chip)
+    bucket = 2048
+    compiled = jax.jit(
+        lambda p, cache, toks, plens, slots, pages, req:
+        decoder_forward.prefill_cached(p, mcfg, cache, toks, plens, slots,
+                                       pages, req),
+        donate_argnums=(1,)).lower(
+            params, cache, chip((1, bucket), jnp.int32),
+            chip((1,), jnp.int32), chip((1,), jnp.int32),
+            chip((1, bucket // 128), jnp.int32),
+            chip((1,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[short-conv prefill b{bucket}, 9 layers] temporaries "
+              f"{m.temp_size_in_bytes / 1e9:.3f} GB")
+    assert "moe_grouped" in text and "ragged-dot" not in text
+    assert re.search(r'op_name="[^"]*/conv/', text)
+    assert re.search(r'op_name="[^"]*/conv_state/', text)
+    # the whole row's scores of the 32 heads ([2048,2048] alone is a
+    # row's hidden state: the width is 2,048 too)
+    assert not re.search(rf"\[(?:\d+,)*32,{bucket},{bucket}\]", text)
+    assert [n for n, op, _ in _pool_shaped(text, (1537, 8, 128, 128))
+            if op in ("copy", "copy-start")] == []
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15e9
+
+
+def test_route_topk_at_the_default_eps_lowers_as_it_did():
+    """``route_topk`` gained the denominator's ``eps`` of a biased
+    router (1e-20 before; LFM2's 1e-6). At the default it is the
+    function it was, with a bias and without, to the letter of its
+    lowered text (the body of the function with its constant 1e-20
+    stands here as the oracle); 1e-6 lowers to another."""
+    from ray_tpu.ops.moe import route_topk
+
+    def as_it_was(x, w_router, top_k, scale=1.0, bias=None):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "td,de->te", x.astype(jnp.float32),
+            w_router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        if bias is None:
+            picked, ids = jax.lax.top_k(scores, top_k)
+            weights = picked / picked.sum(-1, keepdims=True)
+        else:
+            _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+            picked = jnp.take_along_axis(scores, ids, axis=-1)
+            weights = picked / (picked.sum(-1, keepdims=True) + 1e-20)
+        return ids.astype(jnp.int32), (weights if scale == 1.0
+                                       else weights * scale)
+
+    x = jax.ShapeDtypeStruct((64, 128), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((128, 64), jnp.bfloat16)
+    bias = jax.ShapeDtypeStruct((64,), jnp.float32)
+
+    def lowered(fn, *extra, **kw):
+        text = jax.jit(lambda x, w, *e: fn(x, w, 4, 1.0, *e, **kw)).lower(
+            x, w, *extra).as_text()
+        return re.sub(r"\bas_it_was\b|\broute_topk\b", "f", text)
+
+    assert lowered(route_topk) == lowered(as_it_was)
+    assert lowered(route_topk, bias) == lowered(as_it_was, bias)
+    assert lowered(route_topk, bias, eps=1e-6) != lowered(as_it_was, bias)

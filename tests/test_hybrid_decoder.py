@@ -79,7 +79,7 @@ def test_the_description_of_the_dense_decoder():
     with pytest.raises(ValueError, match="DecoderConfig"):
         describe(TransformerConfig(moe=True))
     with pytest.raises(ValueError, match="unknown layer kinds"):
-        LayerSpec("conv", "dense")
+        LayerSpec("selective_scan", "dense")
     with pytest.raises(ValueError, match="experts_held"):
         DecoderConfig(vocab_size=8, d_model=8, n_heads=1, n_kv_heads=1,
                       head_dim=8, layers=(LayerSpec("attention", "experts"),),
@@ -283,7 +283,8 @@ def test_the_cache_has_one_entry_a_layer(served, model):
     assert len(cache) == len(mcfg.layers) == 4
     assert all(len(entry) == 2 for entry in cache)
     for pool in cache[0]:
-        assert pool.shape == (40, 2, 4, 16) and pool.dtype == jnp.float32
+        # heads of 16, padded with zeros to whole lanes of 128
+        assert pool.shape == (40, 2, 4, 128) and pool.dtype == jnp.float32
     for i in mcfg.state_layers:
         state, tail = cache[i]
         assert state.shape == (3, 4, 16, 16) and state.dtype == jnp.float32
@@ -296,7 +297,7 @@ def test_the_cache_has_one_entry_a_layer(served, model):
         a.nbytes for i in mcfg.state_layers for a in cache[i])
     dense = forward.init_cache(describe(TransformerConfig.tiny()), eng.cfg)
     assert [tuple(a.shape for a in entry) for entry in dense] == \
-        [((40, 2, 4, 16),) * 2] * 2
+        [((40, 2, 4, 128),) * 2] * 2
 
 
 @pytest.mark.parametrize("mode", ["prefill", "decode"])

@@ -450,8 +450,9 @@ def test_the_engine_serves_it_counts_it_and_a_successor_sees_nothing(model):
     eng = InferenceEngine(params, mcfg, ICFG)
     try:
         pools = [e[0].shape for e in eng._cache]
-        assert pools[:2] == [(2 * RING + 1, 2, PAGE, 8)] * 2
-        assert pools[2] == (25, 2, PAGE, 8)
+        # heads of 8, padded with zeros to whole lanes of 128
+        assert pools[:2] == [(2 * RING + 1, 2, PAGE, 128)] * 2
+        assert pools[2] == (25, 2, PAGE, 128)
         out = eng.generate(long_prompt, max_new_tokens=16)
         rows = np.asarray([long_prompt + out], np.int32)
         gaps, _ = ref.served_token_gaps(
